@@ -42,6 +42,7 @@ import numpy as np
 
 from benchmarks.common import row, timed
 from repro.api import ExecutorSpec, Session, device_features
+from repro.compile_cache import enable_compile_cache
 from repro.core.hgnn import HGNNConfig
 from repro.kernels.seg_sum import pack_edge_blocks, pack_edge_blocks_reference
 from repro.pipeline import SemanticGraphCache
@@ -169,6 +170,7 @@ def bench_gfp(scale: float = 1.0, model_scale_cap: Optional[float] = None
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("scale", nargs="?", type=float, default=1.0)
     ap.add_argument("out_json", nargs="?", default="BENCH_gfp.json")

@@ -62,6 +62,7 @@ import numpy as np
 
 from benchmarks.common import row
 from repro.api import ExecutorSpec, ServePolicy, Session, device_features
+from repro.compile_cache import enable_compile_cache
 from repro.core.hgnn import HGNNConfig
 from repro.pipeline import FrontendPipeline, PipelineConfig, SemanticGraphCache
 from repro.serve import (DeadlineExceeded, FaultInjector, HGNNRequest,
@@ -109,8 +110,7 @@ def bench_pipeline(scale: float = 0.25) -> List[str]:
 
         # --- device backend, cold (fresh cache so SGB really runs) ---
         dev = FrontendPipeline(
-            PipelineConfig(planner="ctt", backend="device",
-                           kernel_backend="interpret"),
+            PipelineConfig(planner="ctt", backend="device"),
             cache=SemanticGraphCache())
         res_dev, us_dev = _run_once(dev, ds, targets, scale)
         st = res_dev.sgb.device_stats or {}
@@ -453,6 +453,7 @@ def bench_shard(scale: float = 0.25) -> Tuple[List[str], Dict[str, float]]:
 
 
 def main() -> None:
+    enable_compile_cache()
     scale = float(sys.argv[1]) if len(sys.argv) > 1 else 0.25
     out_json = sys.argv[2] if len(sys.argv) > 2 else None
     print("name,us_per_call,derived")

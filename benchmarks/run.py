@@ -9,9 +9,10 @@ import sys
 import time
 
 
-def main() -> None:
+def main() -> int:
     from benchmarks import extra, paper_figures as pf
     from benchmarks.pipeline_bench import bench_pipeline
+    from repro.compile_cache import enable_compile_cache
 
     benches = [
         pf.bench_sgb_scaling,      # Fig. 2
@@ -29,7 +30,9 @@ def main() -> None:
         bench_pipeline,           # frontend pipeline: host/device/cached
     ]
     only = sys.argv[1] if len(sys.argv) > 1 else None
+    enable_compile_cache()
     print("name,us_per_call,derived")
+    failed = []
     for bench in benches:
         if only and only not in bench.__name__:
             continue
@@ -37,10 +40,14 @@ def main() -> None:
         try:
             for line in bench():
                 print(line, flush=True)
-        except Exception as e:  # noqa: BLE001 — keep the suite running
+        except Exception as e:  # noqa: BLE001 — run the rest, then fail
+            failed.append(bench.__name__)
             print(f"{bench.__name__},0.0,ERROR:{type(e).__name__}:{e}")
         print(f"# {bench.__name__} took {time.time() - t0:.1f}s", flush=True)
+    if failed:
+        print(f"# failed: {', '.join(failed)}", file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

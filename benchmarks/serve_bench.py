@@ -40,6 +40,7 @@ import numpy as np
 
 from benchmarks.traces import TraceConfig, generate_trace, load_config
 from repro.api import ExecutorSpec, ServePolicy, Session
+from repro.compile_cache import enable_compile_cache
 from repro.core.hgnn import HGNNConfig
 from repro.hetero import GraphDelta, make_dataset
 from repro.serve import DeadlineExceeded, FaultInjector, HGNNRequest, HGNNServeEngine
@@ -227,6 +228,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     """CLI: replay a committed trace config, print the headline numbers,
     and (optionally) write the ``serve_trace/v1`` point for the gate.
     """
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("trace_config", help="serve_trace_config/v1 JSON (workload + policy)")
     ap.add_argument("out_json", nargs="?", help="where to write the serve_trace/v1 point")

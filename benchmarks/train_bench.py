@@ -35,6 +35,7 @@ import numpy as np
 
 from benchmarks.common import row
 from repro.api import ExecutorSpec, Session, device_features
+from repro.compile_cache import enable_compile_cache
 from repro.core.hgnn import HGNNConfig
 from repro.pipeline import SemanticGraphCache
 from repro.train import propagated_feature_labels, semi_supervised_masks
@@ -121,6 +122,7 @@ def bench_train(scale: float, epochs: int, datasets: List[str]
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("scale", nargs="?", type=float, default=0.15)
     ap.add_argument("out_json", nargs="?", default="BENCH_train.json")

@@ -14,9 +14,12 @@ import argparse
 import time
 
 from repro.api import ExecutorSpec, Session, device_features
+from repro.compile_cache import enable_compile_cache
 from repro.core.hgnn import HGNNConfig
 from repro.hetero import make_dataset
 from repro.train import propagated_feature_labels, semi_supervised_masks
+
+enable_compile_cache()
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--steps", type=int, default=100)

@@ -8,9 +8,12 @@ import argparse
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, reduced
 from repro.models.lm import LM
 from repro.serve.engine import Request, ServeEngine
+
+enable_compile_cache()
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--arch", default="smollm-135m")

@@ -12,9 +12,12 @@ import sys
 import numpy as np
 
 from repro.api import ExecutorSpec, ServePolicy, Session, device_features
+from repro.compile_cache import enable_compile_cache
 from repro.core.hgnn import HGNNConfig
 from repro.hetero import GraphDelta, make_dataset
 from repro.serve import HGNNRequest, HGNNServeEngine
+
+enable_compile_cache()
 
 scale = float(sys.argv[1]) if len(sys.argv) > 1 else 0.5
 

@@ -36,6 +36,7 @@ from repro.distributed.hgnn import (ShardedHGNNExecutor, ShardPlan,
                                     build_shard_plan)
 from repro.hetero.delta import GraphDelta
 from repro.hetero.graph import HetGraph
+from repro.kernels.backend import use_interpret
 from repro.pipeline.cache import SemanticGraphCache
 from repro.pipeline.frontend import (DeltaResult, FrontendPipeline,
                                      FrontendResult)
@@ -225,8 +226,8 @@ class CompiledHGNN:
                         self._shard_exec = ShardedHGNNExecutor(
                             self.model, self.graphs, self.shard_plan,
                             devices=self._devices,
-                            interpret=self.spec.na_kernel_backend
-                            != "pallas")
+                            interpret=use_interpret(
+                                self.spec.kernel_backend))
             return self._shard_exec.forward(params, features)
         if self._forward is None:
             with self._build_lock:
@@ -237,7 +238,7 @@ class CompiledHGNN:
                         return self.model.execute(
                             p, f, self.graphs,
                             na_executor=spec.na_executor,
-                            kernel_backend=spec.na_kernel_backend)
+                            kernel_backend=spec.kernel_backend)
 
                     self._forward = jax.jit(fwd)
         return self._forward(params, features)
@@ -320,7 +321,7 @@ class CompiledHGNN:
                         return self.model.fusion_betas(
                             p, f, self.graphs,
                             na_executor=spec.na_executor,
-                            kernel_backend=spec.na_kernel_backend)
+                            kernel_backend=spec.kernel_backend)
 
                     self._beta_fn = jax.jit(beta_fn)
         betas = self._beta_fn(params, features)
@@ -393,7 +394,7 @@ class CompiledHGNN:
                         return self.model.execute_subset(
                             p, f, self.graphs, padded_ids,
                             na_executor=spec.na_executor,
-                            kernel_backend=spec.na_kernel_backend)
+                            kernel_backend=spec.kernel_backend)
 
                     self._forward_subset = jax.jit(fwd_subset)
         n = int(ids.shape[0])
@@ -424,7 +425,7 @@ class CompiledHGNN:
                         return self.model.execute_dependency_subset(
                             p, f, self.graphs, dep, b,
                             na_executor=spec.na_executor,
-                            kernel_backend=spec.na_kernel_backend)
+                            kernel_backend=spec.kernel_backend)
 
                     self._forward_dep = jax.jit(fwd_dep)
         out = self._forward_dep(params, features, betas, sub.arrays)
@@ -448,7 +449,7 @@ class CompiledHGNN:
                         return self.model.execute_loss(
                             p, f, self.graphs, y, mask=m,
                             na_executor=spec.na_executor,
-                            kernel_backend=spec.na_kernel_backend)
+                            kernel_backend=spec.kernel_backend)
 
                     self._loss = jax.jit(loss_fn)
         if mask is None:

@@ -14,7 +14,8 @@ frozen, hashable declaration, validated at construction:
     is rejected, and the default (``pack=None``) resolves to whatever the
     executor needs;
   * the banded NA path runs kernels only, so ``kernel_backend="jnp"``
-    (legal for the SGB device composer) is rejected with it;
+    (legal for the SGB device composer) is rejected with it, and
+    ``kernel_backend="pallas"`` is rejected on a host with no TPU;
   * the banded layout IS the restructurer's schedule, so
     ``restructure=False`` is rejected with it.
 
@@ -27,12 +28,13 @@ import dataclasses
 import math
 from typing import Optional, Tuple
 
+from repro.kernels.backend import resolve as resolve_backend
 from repro.pipeline.frontend import PipelineConfig
 
 _PLANNERS = ("naive", "ctt", "ctt_cache", "ctt_dp")
 _SGB_BACKENDS = ("host", "device")
 _NA_EXECUTORS = ("jnp", "banded")
-_KERNEL_BACKENDS = ("interpret", "pallas", "jnp")
+_KERNEL_BACKENDS = (None, "interpret", "pallas", "jnp")
 _SHARD_MODES = ("none", "relation", "edge_block")
 
 
@@ -43,6 +45,8 @@ class ExecutorSpec:
     ``kernel_backend`` is shared by the two kernel consumers: the SGB
     device composer (``interpret`` | ``pallas`` | ``jnp``) and the banded
     NA executor (``interpret`` | ``pallas`` — kernels only, validated).
+    ``None`` (the default) runs the platform's backend: compiled Pallas on
+    a TPU, interpret mode elsewhere (``repro.kernels.backend``).
     ``pack=None`` means "whatever ``na_executor`` needs" and is resolved
     to a concrete bool at construction, so a constructed spec always
     states its packing policy.
@@ -59,7 +63,7 @@ class ExecutorSpec:
     planner: str = "ctt"
     sgb_backend: str = "host"
     na_executor: str = "jnp"
-    kernel_backend: str = "interpret"
+    kernel_backend: Optional[str] = None
     restructure: bool = True
     degree_order: bool = True
     affinity: str = "barycenter"
@@ -91,8 +95,10 @@ class ExecutorSpec:
             if self.kernel_backend == "jnp":
                 raise ValueError(
                     "na_executor='banded' runs kernels only: "
-                    "kernel_backend must be 'interpret' or 'pallas' "
+                    "kernel_backend must be None, 'interpret' or 'pallas' "
                     "('jnp' is an SGB-composer-only backend)")
+        if self.kernel_backend == "pallas":
+            resolve_backend("pallas")  # raises on a host with no TPU
         if self.pack and not self.restructure:
             raise ValueError(
                 "pack=True requires restructure=True (PackedEdges blocks "
@@ -115,13 +121,6 @@ class ExecutorSpec:
             object.__setattr__(self, "mesh_shape", shape)
         if self.pack is None:
             object.__setattr__(self, "pack", self.na_executor == "banded")
-
-    @property
-    def na_kernel_backend(self) -> str:
-        """The kernel backend the NA executor consumes.  ``"jnp"`` is an
-        SGB-composer-only value (``HGNN.execute`` rejects it), so the NA
-        side of such a spec falls back to the interpret kernels."""
-        return "interpret" if self.kernel_backend == "jnp" else self.kernel_backend
 
     def pipeline_config(self) -> PipelineConfig:
         """Lower the spec onto the frontend engine's config.

@@ -30,6 +30,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import use_interpret
 from repro.kernels.ops import na_attention_packed
 from repro.kernels.seg_sum import PackedEdges, seg_sum_na
 
@@ -92,10 +93,10 @@ def na_mean_banded(
     packed: PackedEdges,
     h_src: jax.Array,  # (N_src, D) features in the packing's banded numbering
     deg: jax.Array,  # (N_dst,) in-degrees in the packing's dst numbering
-    backend: str = "interpret",
+    backend: Optional[str] = None,
 ) -> jax.Array:
     """RGCN-style NA on the banded Pallas kernel (dst rows banded too)."""
-    summed = seg_sum_na(packed, h_src, interpret=backend != "pallas")
+    summed = seg_sum_na(packed, h_src, interpret=use_interpret(backend))
     return summed / jnp.maximum(deg, 1.0)[:, None]
 
 
@@ -109,7 +110,7 @@ def na_attention_banded(
     a_dst: jax.Array,
     edge_bias: Optional[jax.Array] = None,
     leaky_slope: float = 0.2,
-    backend: str = "interpret",
+    backend: Optional[str] = None,
 ) -> jax.Array:
     """GAT-style NA on the fused device-resident kernel path.
 

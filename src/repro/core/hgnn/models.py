@@ -29,6 +29,7 @@ from repro.core.hgnn.layers import (
     semantic_fusion_beta,
 )
 from repro.hetero.graph import HetGraph, Relation
+from repro.kernels.backend import resolve as resolve_backend
 from repro.kernels.seg_sum import PackedEdges
 
 
@@ -228,7 +229,7 @@ class HGNN:
         graphs: List[SemanticGraphBatch],
         *,
         na_executor: str = "jnp",
-        kernel_backend: str = "interpret",
+        kernel_backend: Optional[str] = None,
         betas_out: Optional[List] = None,
     ) -> Dict[str, jax.Array]:
         """Run every FP -> NA -> SF layer; returns the final per-type
@@ -255,7 +256,8 @@ class HGNN:
                        permuted once per layer into the renumbered banded
                        layout and NA outputs permuted back, so FP/SF and
                        the returned logits keep global vertex numbering.
-        ``kernel_backend`` ("interpret" | "pallas") only applies to the
+        ``kernel_backend`` ("interpret" | "pallas" | None for the
+        platform's, see ``repro.kernels.backend``) only applies to the
         banded path.
 
         Both executors are differentiable: the banded NA kernels carry
@@ -268,10 +270,9 @@ class HGNN:
         cfg = self.cfg
         if na_executor not in ("jnp", "banded"):
             raise ValueError(f"unknown na_executor {na_executor!r}")
-        if kernel_backend not in ("interpret", "pallas"):
-            raise ValueError(f"unknown kernel_backend {kernel_backend!r} "
-                             "(the banded path runs kernels only)")
         banded = na_executor == "banded"
+        if banded:
+            kernel_backend = resolve_backend(kernel_backend)
         for g in graphs:
             if banded != isinstance(g, BandedBatch):
                 raise TypeError(
@@ -348,7 +349,7 @@ class HGNN:
         graphs: List[SemanticGraphBatch],
         *,
         na_executor: str = "jnp",
-        kernel_backend: str = "interpret",
+        kernel_backend: Optional[str] = None,
     ) -> List[Dict[str, jax.Array]]:
         """Per-layer SF attention weights from one full forward.
 
@@ -376,7 +377,7 @@ class HGNN:
         betas: List[Dict[str, jax.Array]],
         *,
         na_executor: str = "jnp",
-        kernel_backend: str = "interpret",
+        kernel_backend: Optional[str] = None,
     ) -> jax.Array:
         """FP -> NA -> SF over an induced k-hop dependency subgraph.
 
@@ -397,10 +398,9 @@ class HGNN:
         cfg = self.cfg
         if na_executor not in ("jnp", "banded"):
             raise ValueError(f"unknown na_executor {na_executor!r}")
-        if kernel_backend not in ("interpret", "pallas"):
-            raise ValueError(f"unknown kernel_backend {kernel_backend!r} "
-                             "(the banded path runs kernels only)")
         banded = na_executor == "banded"
+        if banded:
+            kernel_backend = resolve_backend(kernel_backend)
         gather = dep["gather"]
         h: Dict[str, jax.Array] = {}
         for t in self.num_vertices:
@@ -463,7 +463,7 @@ class HGNN:
         graphs: List[SemanticGraphBatch],
         *,
         na_executor: str = "jnp",
-        kernel_backend: str = "interpret",
+        kernel_backend: Optional[str] = None,
     ) -> jax.Array:
         """Full GFP stage; returns logits for ``cfg.target_type`` vertices.
 
@@ -488,7 +488,7 @@ class HGNN:
         node_ids: jax.Array,
         *,
         na_executor: str = "jnp",
-        kernel_backend: str = "interpret",
+        kernel_backend: Optional[str] = None,
     ) -> jax.Array:
         """Logits for an explicit subset of ``cfg.target_type`` vertices.
 
@@ -512,7 +512,7 @@ class HGNN:
     def execute_loss(self, params, features, graphs, labels: jax.Array,
                      mask: Optional[jax.Array] = None, *,
                      na_executor: str = "jnp",
-                     kernel_backend: str = "interpret") -> jax.Array:
+                     kernel_backend: Optional[str] = None) -> jax.Array:
         """Masked cross-entropy over ``cfg.target_type`` vertices
         (semi-supervised node classification).  Differentiable on both NA
         executors: ``jax.grad`` of this loss on the banded executor

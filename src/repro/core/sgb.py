@@ -207,17 +207,19 @@ class DeviceComposer:
     sorted-merge join's — the two backends differ only in *where* the
     composition runs.
 
-    ``kernel_backend``: "pallas" (TPU), "interpret" (kernel body on CPU),
-    or "jnp" (dense oracle — fastest CPU validation path).
+    ``kernel_backend``: None (the platform's kernel backend, see
+    ``repro.kernels.backend``), "pallas" (TPU), "interpret" (kernel body
+    in the Pallas interpreter), or "jnp" (dense oracle — fastest CPU
+    validation path).
     """
 
     def __init__(
         self,
         graph: HetGraph,
-        kernel_backend: str = "interpret",
+        kernel_backend: Optional[str] = None,
         preloaded: Optional[Dict[str, Relation]] = None,
     ):
-        if kernel_backend not in ("pallas", "interpret", "jnp"):
+        if kernel_backend not in (None, "pallas", "interpret", "jnp"):
             raise ValueError(f"unknown kernel_backend {kernel_backend!r}")
         self.graph = graph
         self.kernel_backend = kernel_backend
@@ -274,7 +276,7 @@ def execute_plan(
     graph: HetGraph,
     plan: Plan,
     backend: str = "host",
-    kernel_backend: str = "interpret",
+    kernel_backend: Optional[str] = None,
     preloaded: Optional[Dict[str, Relation]] = None,
 ) -> SGBResult:
     """Run every composition step; count exact MACs/bytes.
@@ -479,7 +481,7 @@ def build_semantic_graphs(
     targets: Sequence[str],
     planner: str = "ctt",
     backend: str = "host",
-    kernel_backend: str = "interpret",
+    kernel_backend: Optional[str] = None,
 ) -> SGBResult:
     """One-call SGB stage: plan + execute. ``planner`` in {naive, ctt, ctt_dp}."""
     plan = make_plan(graph, targets, planner=planner)

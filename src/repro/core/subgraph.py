@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.backend import use_interpret
 from repro.kernels.seg_sum import _first_touch_flags, _seg_sum_call
 
 
@@ -444,7 +445,7 @@ class DependencyExtractor:
 
 # ------------------------------------------------------- banded NA compute --
 def na_mean_subset_banded(packed, dg: Dict, h_src: jax.Array,
-                          backend: str = "interpret") -> jax.Array:
+                          backend: Optional[str] = None) -> jax.Array:
     """RGCN-style NA over one sliced banded graph (closure-local in/out).
 
     The blocked arrays are *traced* operands of ``_seg_sum_call`` (only
@@ -458,7 +459,7 @@ def na_mean_subset_banded(packed, dg: Dict, h_src: jax.Array,
     out = _seg_sum_call(
         dg["band"], dg["dtile"], dg["first"], dg["srcl"], dg["dstl"],
         dg["weight"], hb, num_dst_tiles=num_tiles, src_band=sb,
-        dst_tile_rows=td, interpret=backend != "pallas")
+        dst_tile_rows=td, interpret=use_interpret(backend))
     deg = jnp.zeros((num_tiles * td,), jnp.float32).at[dg["e_dst"]].add(
         dg["e_valid"])
     z = out / jnp.maximum(deg, 1.0)[:, None]
@@ -470,7 +471,7 @@ def na_attention_subset_banded(packed, dg: Dict, h_src: jax.Array,
                                a_dst: jax.Array,
                                edge_bias: Optional[jax.Array] = None,
                                leaky_slope: float = 0.2,
-                               backend: str = "interpret") -> jax.Array:
+                               backend: Optional[str] = None) -> jax.Array:
     """GAT-style NA over one sliced banded graph.
 
     Edge softmax runs as jnp segment stats over the sliced flat edge map
@@ -500,5 +501,5 @@ def na_attention_subset_banded(packed, dg: Dict, h_src: jax.Array,
     out = _seg_sum_call(
         dg["band"], dg["dtile"], dg["first"], dg["srcl"], dg["dstl"],
         wblk, hb, num_dst_tiles=num_tiles, src_band=sb,
-        dst_tile_rows=td, interpret=backend != "pallas")
+        dst_tile_rows=td, interpret=use_interpret(backend))
     return out[dg["dst_pick"]] * dg["pick_valid"][:, None]

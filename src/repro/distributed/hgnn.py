@@ -45,11 +45,12 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core.hgnn.layers import feature_projection, semantic_fusion_beta
 from repro.core.hgnn.models import HGNN, BandedBatch
+from repro.kernels.backend import use_interpret
 from repro.kernels.edge_softmax import edge_softmax_stats_blocks
 from repro.kernels.seg_sum import seg_sum_blocks, shard_blocked
 from repro.launch.mesh import make_mesh_for
@@ -275,11 +276,11 @@ def _stack_device_blocks(
     graphs: Sequence[BandedBatch],
     plan: ShardPlan,
     geom: _Geometry,
-) -> Dict[str, jax.Array]:
+) -> Dict[str, np.ndarray]:
     """Per-device block streams, offset into the shared space and padded.
 
-    Returns ``(ndev, nb_max, ...)`` stacked arrays ready to be shard_map
-    operands with ``P("dev")`` specs.  Padding blocks target the extra
+    Returns ``(ndev, nb_max, ...)`` stacked host arrays, the shard_map
+    operands of the ``P("dev")`` specs.  Padding blocks target the extra
     garbage tile with ``first=1`` (each one re-zeros rows nothing reads)
     and carry zero weights / all-invalid slots, so they contribute
     nothing to real tiles or softmax stats.
@@ -327,7 +328,7 @@ def _stack_device_blocks(
         full["valid"] = (slot < full["count"][:, None]).astype(np.float32)
         for k, v in full.items():
             stacked.setdefault(k, []).append(v)
-    return {k: jnp.asarray(np.stack(v)) for k, v in stacked.items()}
+    return {k: np.stack(v) for k, v in stacked.items()}
 
 
 class ShardedHGNNExecutor:
@@ -350,13 +351,14 @@ class ShardedHGNNExecutor:
         *,
         devices: Optional[Sequence] = None,
         mesh: Optional[jax.sharding.Mesh] = None,
-        interpret: bool = True,
+        interpret: Optional[bool] = None,
     ):
         """Bind ``model`` + its banded batches to ``plan`` over a mesh.
 
         ``mesh`` must be 1-D with axis ``"dev"``; when absent one is
         made from ``devices`` (default: all of ``jax.devices()``,
-        truncated to the plan's device count).
+        truncated to the plan's device count).  ``interpret=None`` runs
+        the platform's kernel backend (``repro.kernels.backend``).
         """
         if mesh is None:
             devs = list(jax.devices()) if devices is None else list(devices)
@@ -371,9 +373,13 @@ class ShardedHGNNExecutor:
         self.graphs = list(graphs)
         self.plan = plan
         self.mesh = mesh
-        self.interpret = bool(interpret)
+        self.interpret = use_interpret() if interpret is None else bool(interpret)
         self.geometry = _build_geometry(self.graphs)
-        self._blocks = _stack_device_blocks(self.graphs, plan, self.geometry)
+        # each device's stream lives on that device, placed once
+        self._blocks = jax.device_put(
+            _stack_device_blocks(self.graphs, plan, self.geometry),
+            NamedSharding(mesh, P(_AXIS)),
+        )
         self._fn = None
         self._traces = 0
         self._lock = threading.Lock()
@@ -508,16 +514,16 @@ class ShardedHGNNExecutor:
             head = params["head"]
             logits = h[cfg.target_type] @ head["w"] + head["b"]
             # replicated result; a broadcast leading axis satisfies the
-            # check_rep=False requirement that out_specs mention the mesh
+            # check_vma=False requirement that out_specs mention the mesh
             # axis (the caller reads shard 0)
             return logits[None]
 
-        sharded = shard_map(
+        sharded = jax.shard_map(
             body,
             mesh=self.mesh,
             in_specs=(P(), P(), P(_AXIS)),
             out_specs=P(_AXIS),
-            check_rep=False,
+            check_vma=False,
         )
 
         def fwd(params, features, blocks):

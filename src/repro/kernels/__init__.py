@@ -16,5 +16,6 @@ LM-zoo hot spots:
 
 Every kernel has a pure-jnp oracle in ``ref.py`` and a jit'd public wrapper
 in ``ops.py``.  Kernels are TPU-targeted (pl.pallas_call + BlockSpec VMEM
-tiling) and validated on CPU with ``interpret=True``.
+tiling) and validated on CPU in Pallas interpret mode; ``backend.py`` picks
+the mode from the platform.
 """

@@ -16,7 +16,7 @@ feature aggregation then uses kernels/seg_sum.py with alpha as weights.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -24,7 +24,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.seg_sum import PackedEdges
+from repro.kernels.backend import use_interpret
+from repro.kernels.seg_sum import PackedEdges, block_rows
 
 _NEG = -1e30
 
@@ -32,7 +33,7 @@ _NEG = -1e30
 def _stats_kernel(
     dtile_ref, first_ref,  # scalar-prefetch
     logit_ref, dstl_ref, valid_ref,  # (1, EB)
-    m_ref, s_ref,  # (1, TD) accumulators
+    m_ref, s_ref,  # (TD, 1) accumulators: one row per destination
     *, eb: int, td: int,
 ):
     i = pl.program_id(0)
@@ -42,23 +43,24 @@ def _stats_kernel(
         m_ref[...] = jnp.full_like(m_ref, _NEG)
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    logit = logit_ref[0, :]
-    dstl = dstl_ref[0, :].astype(jnp.int32)  # host arrays are int16
-    valid = valid_ref[0, :] > 0
-    scat = jax.lax.broadcasted_iota(jnp.int32, (td, eb), 0) == dstl[None, :]
-    eff = scat & valid[None, :]
-    masked = jnp.where(eff, logit[None, :], _NEG)  # (TD, EB)
-    blockmax = jnp.max(masked, axis=1)  # (TD,)
-    m_old = m_ref[0, :]
+    logit = logit_ref[...]  # (1, EB)
+    dstl = dstl_ref[...].astype(jnp.int32)  # host arrays are int16
+    valid = valid_ref[...] > 0
+    scat = jax.lax.broadcasted_iota(jnp.int32, (td, eb), 0) == dstl
+    eff = scat & valid  # (TD, EB): edge e lands on row t
+    masked = jnp.where(eff, logit, _NEG)
+    blockmax = jnp.max(masked, axis=1, keepdims=True)  # (TD, 1)
+    m_old = m_ref[...]
     m_new = jnp.maximum(m_old, blockmax)
     # guard: exp(-inf - -inf) -> use 0 scale when m_old was -inf
     scale = jnp.where(m_old > _NEG / 2, jnp.exp(m_old - m_new), 0.0)
-    # per-edge exp(l - m_new[dst]) via one-hot gather of m_new
-    m_e = jnp.einsum("te,t->e", eff.astype(jnp.float32), m_new)
+    # per-edge m_new[dst] and per-row sums as masked reductions over the
+    # one-hot: exact in f32, where an MXU pass would round to bf16
+    m_e = jnp.sum(jnp.where(eff, m_new, 0.0), axis=0, keepdims=True)  # (1, EB)
     ex = jnp.where(valid, jnp.exp(logit - m_e), 0.0)
-    s_add = eff.astype(jnp.float32) @ ex  # (TD,)
-    s_ref[0, :] = s_ref[0, :] * scale + s_add
-    m_ref[0, :] = m_new
+    s_add = jnp.sum(jnp.where(eff, ex, 0.0), axis=1, keepdims=True)  # (TD, 1)
+    s_ref[...] = s_ref[...] * scale + s_add
+    m_ref[...] = m_new
 
 
 @functools.partial(
@@ -68,35 +70,33 @@ def _stats_call(dst_tile, first, logits, dst_local, valid,
                 num_dst_tiles, dst_tile_rows, interpret):
     nb, eb = logits.shape
     td = dst_tile_rows
+    row = pl.BlockSpec((None, 1, eb), lambda i, t, f: (i, 0, 0))
+    col = pl.BlockSpec((td, 1), lambda i, t, f: (t[i], 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((1, eb), lambda i, t, f: (i, 0)),
-            pl.BlockSpec((1, eb), lambda i, t, f: (i, 0)),
-            pl.BlockSpec((1, eb), lambda i, t, f: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, td), lambda i, t, f: (t[i], 0)),
-            pl.BlockSpec((1, td), lambda i, t, f: (t[i], 0)),
-        ],
+        in_specs=[row, row, row],
+        out_specs=[col, col],
     )
     kern = functools.partial(_stats_kernel, eb=eb, td=td)
-    return pl.pallas_call(
+    m, s = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((num_dst_tiles, td), jnp.float32),
-            jax.ShapeDtypeStruct((num_dst_tiles, td), jnp.float32),
+            jax.ShapeDtypeStruct((num_dst_tiles * td, 1), jnp.float32),
+            jax.ShapeDtypeStruct((num_dst_tiles * td, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(dst_tile, first, logits, dst_local, valid)
+        name="na_softmax_stats",
+    )(dst_tile, first, block_rows(logits), block_rows(dst_local),
+      block_rows(valid))
+    return m.reshape(num_dst_tiles, td), s.reshape(num_dst_tiles, td)
 
 
 def edge_softmax_stats(
     packed: PackedEdges,
     logits_blocked: jax.Array,  # (nb, EB) f32 blocked layout (np or device)
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Per-destination (m, s); rows never touched get m=-1e30, s=0.
 
@@ -106,6 +106,8 @@ def edge_softmax_stats(
     every block of a destination tile, including non-consecutive revisits:
     ``first_in_tile`` means first touch ever (see kernels/seg_sum.py).
     """
+    if interpret is None:
+        interpret = use_interpret()
     td = packed.dst_tile_rows
     num_dst_tiles = max(1, -(-packed.num_dst // td))
     # count-derived validity, NOT the weights: zero-weight edges still
@@ -128,7 +130,7 @@ def edge_softmax_stats(
 
 def edge_softmax_stats_blocks(
     dst_tile, first, logits_blocked, dst_local, valid, *,
-    num_dst_tiles: int, dst_tile_rows: int, interpret: bool = True,
+    num_dst_tiles: int, dst_tile_rows: int, interpret: Optional[bool] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Raw blocked-stream stats kernel entry over explicit block arrays.
 
@@ -142,6 +144,8 @@ def edge_softmax_stats_blocks(
     blocks must carry all-invalid slots so they leave their target tile's
     stats at the (-1e30, 0) init.
     """
+    if interpret is None:
+        interpret = use_interpret()
     return _stats_call(dst_tile, first, logits_blocked, dst_local, valid,
                        num_dst_tiles, dst_tile_rows, interpret)
 

@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backend import use_interpret
+
 _NEG = -1e30
 
 
@@ -117,8 +119,10 @@ def flash_attention(
     scale: Optional[float] = None,
     bq: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
+    if interpret is None:
+        interpret = use_interpret()
     b, hq, s, dh = q.shape
     _, hkv, t, _ = k.shape
     assert hq % hkv == 0

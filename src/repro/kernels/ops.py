@@ -1,14 +1,12 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 Each op dispatches on ``backend``:
-  * "pallas"     — pl.pallas_call targeting TPU (interpret=False);
-  * "interpret"  — same kernel body executed in Python on CPU (validation);
-  * "jnp"        — the pure-jnp oracle (used by the dry-run so that XLA's
-                   cost_analysis sees the FLOPs; Pallas custom-calls are
-                   opaque to it).
-
-Default is "interpret" in this CPU container; launch/train.py flips to
-"pallas" when jax.default_backend() == "tpu".
+  * None         — the platform's kernel backend (the default): compiled
+                   Pallas on a TPU, interpret mode anywhere else
+                   (``repro.kernels.backend``);
+  * "pallas"     — pl.pallas_call compiled for the TPU (an error off-TPU);
+  * "interpret"  — the same kernel body in the Pallas interpreter;
+  * "jnp"        — the pure-jnp oracle (``ref.py``).
 """
 from __future__ import annotations
 
@@ -19,13 +17,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ref as _ref
+from repro.kernels.backend import use_interpret
 from repro.kernels.edge_softmax import edge_softmax_stats
 from repro.kernels.flash_attention import flash_attention as _fa
 from repro.kernels.seg_sum import PackedEdges, pack_edge_blocks, seg_sum_na
 from repro.kernels.spgemm_bsr import compose_dense_blocked
 from repro.kernels.ssd_scan import ssd_scan as _ssd
-
-DEFAULT_BACKEND = "interpret"
 
 # Attention sharding hint, set by the launch layer under a mesh context:
 #   None    — no constraints (single-device tests/benches)
@@ -97,16 +94,12 @@ def _attn_shard(q, k, v):
     return q, k, v
 
 
-def _interpret(backend: str) -> bool:
-    return backend != "pallas"
-
-
 def attention(
     q: jax.Array, k: jax.Array, v: jax.Array,
     causal: bool = True,
     window: Optional[int] = None,
     softcap: Optional[float] = None,
-    backend: str = DEFAULT_BACKEND,
+    backend: Optional[str] = None,
     bq: int = 128,
     bk: int = 128,
 ) -> jax.Array:
@@ -143,20 +136,20 @@ def attention(
             o = _constrain(o, (P.UNCONSTRAINED, None, "model", P.UNCONSTRAINED))
         return o
     return _fa(q, k, v, causal=causal, window=window, softcap=softcap,
-               bq=bq, bk=bk, interpret=_interpret(backend))
+               bq=bq, bk=bk, interpret=use_interpret(backend))
 
 
 def ssd(
     x: jax.Array, a_log: jax.Array, b_coef: jax.Array, c_coef: jax.Array,
     chunk: int = 64,
-    backend: str = DEFAULT_BACKEND,
+    backend: Optional[str] = None,
 ) -> jax.Array:
     """Mamba2 SSD scan (B, S, H, P)."""
     if backend == "jnp":
         # chunked-vectorized path: static HLO, full FLOP visibility
         c = chunk if x.shape[1] % chunk == 0 else 1
         return _ref.ssd_chunked(x, a_log, b_coef, c_coef, chunk=c)
-    return _ssd(x, a_log, b_coef, c_coef, chunk=chunk, interpret=_interpret(backend))
+    return _ssd(x, a_log, b_coef, c_coef, chunk=chunk, interpret=use_interpret(backend))
 
 
 def na_aggregate(
@@ -165,7 +158,7 @@ def na_aggregate(
     h: jax.Array,
     num_dst: int,
     weight: Optional[np.ndarray] = None,
-    backend: str = DEFAULT_BACKEND,
+    backend: Optional[str] = None,
     packed: Optional[PackedEdges] = None,
 ) -> jax.Array:
     """Neighbor aggregation: out[d] = sum_{(s,d) in E} w * h[s]."""
@@ -175,7 +168,7 @@ def na_aggregate(
         packed = pack_edge_blocks(src, dst, int(h.shape[0]), num_dst, weight=weight)
     elif weight is not None:
         packed = packed.with_weights(np.asarray(weight, np.float32))
-    return seg_sum_na(packed, h, interpret=_interpret(backend))
+    return seg_sum_na(packed, h, interpret=use_interpret(backend))
 
 
 def _build_attention_packed_vjp(packed: PackedEdges, interpret: bool):
@@ -261,7 +254,7 @@ def na_attention_packed(
     h: jax.Array,  # (N_src, D) features in the packing's src numbering
     dst: Optional[jax.Array] = None,  # kept for API compat; the packing's
     # own edge map is authoritative for per-edge destination ids
-    backend: str = DEFAULT_BACKEND,
+    backend: Optional[str] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Device-resident fused attention NA over a cached packing.
 
@@ -276,7 +269,7 @@ def na_attention_packed(
     """
     assert backend != "jnp", "na_attention_packed is the kernel path"
     del dst  # derived from the packing (identical by construction)
-    fn = attention_packed_vjp(packed, _interpret(backend))
+    fn = attention_packed_vjp(packed, use_interpret(backend))
     return fn(jnp.asarray(edge_logits, jnp.float32), h)
 
 
@@ -286,7 +279,7 @@ def na_attention_aggregate(
     edge_logits: np.ndarray,
     h: jax.Array,
     num_dst: int,
-    backend: str = DEFAULT_BACKEND,
+    backend: Optional[str] = None,
     packed: Optional[PackedEdges] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Edge-softmax attention NA; returns (aggregated, alpha).
@@ -306,14 +299,14 @@ def na_attention_aggregate(
 
 
 def compose_boolean(
-    a_dense: np.ndarray, b_dense: np.ndarray, backend: str = DEFAULT_BACKEND
+    a_dense: np.ndarray, b_dense: np.ndarray, backend: Optional[str] = None
 ):
     """Boolean adjacency product (SGB composition) via block-sparse SpGEMM."""
     if backend == "jnp":
         out = _ref.spgemm_ref(jnp.asarray(a_dense, jnp.float32),
                               jnp.asarray(b_dense, jnp.float32))
         return np.asarray(out), {}
-    return compose_dense_blocked(a_dense, b_dense, interpret=_interpret(backend))
+    return compose_dense_blocked(a_dense, b_dense, interpret=use_interpret(backend))
 
 
 def compose_boolean_padded(
@@ -321,7 +314,7 @@ def compose_boolean_padded(
     b: np.ndarray,  # (Kp, Np) 0/1, tile-padded
     a_occ: np.ndarray,
     b_occ: np.ndarray,
-    backend: str = DEFAULT_BACKEND,
+    backend: Optional[str] = None,
 ) -> Tuple[np.ndarray, np.ndarray, dict]:
     """SGB composition over pre-padded operands with cached occupancy —
     the device executor's chain primitive (see ``core.sgb.DeviceComposer``).
@@ -334,4 +327,4 @@ def compose_boolean_padded(
                             jnp.asarray(b, jnp.float32))))
         return out, tile_occupancy(out), {}
     return compose_padded_blocked(a, b, a_occ, b_occ,
-                                  interpret=_interpret(backend))
+                                  interpret=use_interpret(backend))

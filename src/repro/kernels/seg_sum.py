@@ -44,6 +44,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backend import use_interpret
+
 # Edge-block geometry.  VMEM at defaults (fp32): gather one-hot 256x512x4 =
 # 512 KB, scatter one-hot 128x256x4 = 128 KB, feature band 512xD, out tile
 # 128xD — comfortably inside ~16 MB VMEM for D <= 1024.
@@ -515,7 +517,7 @@ def splice_pack_edge_blocks(
 
 def _na_kernel(
     band_ref, dtile_ref, first_ref,  # scalar-prefetch (SMEM)
-    srcl_ref, dstl_ref, w_ref, h_ref,  # VMEM inputs
+    srcl_ref, dstl_ref, w_ref, h_ref,  # VMEM inputs; per-block rows are (1, EB)
     out_ref,  # VMEM output tile (TD, D)
     *, eb: int, band: int, td: int,
 ):
@@ -528,11 +530,26 @@ def _na_kernel(
     srcl = srcl_ref[0, :].astype(jnp.int32)  # host arrays are int16
     dstl = dstl_ref[0, :].astype(jnp.int32)
     w = w_ref[0, :]
+    # HIGHEST: the one-hots are exact at any precision, but the MXU's
+    # default f32 pass rounds the feature operand to bf16
+    hi = jax.lax.Precision.HIGHEST
     sel = srcl[:, None] == jax.lax.broadcasted_iota(jnp.int32, (eb, band), 1)
-    gathered = sel.astype(jnp.float32) @ h_ref[...].astype(jnp.float32)
+    gathered = jnp.dot(sel.astype(jnp.float32), h_ref[...].astype(jnp.float32),
+                       precision=hi)
     scat = jax.lax.broadcasted_iota(jnp.int32, (td, eb), 0) == dstl[None, :]
-    contrib = scat.astype(jnp.float32) @ (gathered * w[:, None])
+    contrib = jnp.dot(scat.astype(jnp.float32), gathered * w[:, None],
+                      precision=hi)
     out_ref[...] += contrib.astype(out_ref.dtype)
+
+
+def block_rows(x: jax.Array) -> jax.Array:
+    """(nb, EB) per-block array -> the (nb, 1, EB) layout the kernels tile.
+
+    Mosaic needs a block's last two dims to be multiples of (8, 128) or
+    the whole array dims; a (1, EB) block of an (nb, EB) array is neither,
+    while a (1, EB) block of (nb, 1, EB) is the whole trailing pair.
+    """
+    return x.reshape(x.shape[0], 1, x.shape[-1])
 
 
 @functools.partial(
@@ -544,13 +561,12 @@ def _seg_sum_call(
 ):
     nb, eb = src_local.shape
     d = h.shape[1]
+    row = pl.BlockSpec((None, 1, eb), lambda i, b, t, f: (i, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(nb,),
         in_specs=[
-            pl.BlockSpec((1, eb), lambda i, b, t, f: (i, 0)),
-            pl.BlockSpec((1, eb), lambda i, b, t, f: (i, 0)),
-            pl.BlockSpec((1, eb), lambda i, b, t, f: (i, 0)),
+            row, row, row,
             pl.BlockSpec((src_band, d), lambda i, b, t, f: (b[i], 0)),
         ],
         out_specs=pl.BlockSpec((dst_tile_rows, d), lambda i, b, t, f: (t[i], 0)),
@@ -561,7 +577,9 @@ def _seg_sum_call(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_dst_tiles * dst_tile_rows, d), h.dtype),
         interpret=interpret,
-    )(band, dst_tile, first, src_local, dst_local, weight, h)
+        name="na_seg_sum",
+    )(band, dst_tile, first, block_rows(src_local), block_rows(dst_local),
+      block_rows(weight), h)
 
 
 def _build_banded_matvec(packed: PackedEdges, interpret: bool,
@@ -631,7 +649,7 @@ def banded_matvec_vjp(packed: PackedEdges, interpret: bool,
 def seg_sum_na(
     packed: PackedEdges,
     h: jax.Array,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     weights: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Weighted NA aggregation; returns (num_dst, D).  Differentiable in
@@ -641,8 +659,11 @@ def seg_sum_na(
     device-resident (nb, EB) blocked array (see
     ``PackedEdges.scatter_blocks``) — the attention path feeds per-layer
     alpha this way without re-materializing host-side blocks; its
-    cotangent flows back through the blocked layout.
+    cotangent flows back through the blocked layout.  ``interpret=None``
+    runs the platform's kernel backend (``repro.kernels.backend``).
     """
+    if interpret is None:
+        interpret = use_interpret()
     band_units = int(packed.band.max()) + 1 if packed.num_blocks else 1
     n_src_pad = max(band_units * packed.src_band, packed.num_src)
     if h.shape[0] < n_src_pad:
@@ -668,7 +689,7 @@ def seg_sum_na(
 def seg_sum_blocks(
     band, dst_tile, first, src_local, dst_local, weight, h, *,
     num_dst_tiles: int, src_band: int = SRC_BAND,
-    dst_tile_rows: int = DST_TILE, interpret: bool = True,
+    dst_tile_rows: int = DST_TILE, interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Raw blocked-stream NA kernel entry over explicit block arrays.
 
@@ -683,6 +704,8 @@ def seg_sum_blocks(
     tiles holding uninitialized memory (callers mask, exactly like
     ``seg_sum_na``'s epilogue).
     """
+    if interpret is None:
+        interpret = use_interpret()
     return _seg_sum_call(band, dst_tile, first, src_local, dst_local,
                          weight, h, num_dst_tiles, src_band, dst_tile_rows,
                          interpret)

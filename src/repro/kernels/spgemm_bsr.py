@@ -14,13 +14,15 @@ Grid: (Mt, Nt, Kt), k innermost accumulating into the output tile.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.backend import use_interpret
 
 TILE = 128
 
@@ -57,8 +59,10 @@ def spgemm_bsr(
     b: jax.Array,  # (K, N) 0/1
     a_occ: jax.Array,  # (Mt*Kt,) int32 tile occupancy
     b_occ: jax.Array,  # (Kt*Nt,) int32
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
+    if interpret is None:
+        interpret = use_interpret()
     m, k = a.shape
     _, n = b.shape
     mt, kt, nt = m // TILE, k // TILE, n // TILE
@@ -101,7 +105,7 @@ def compose_padded_blocked(
     b: np.ndarray,  # (Kp, Np) 0/1, tile-padded
     a_occ: np.ndarray,  # (Mt*Kt,) int32
     b_occ: np.ndarray,  # (Kt*Nt,) int32
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Tuple[np.ndarray, np.ndarray, dict]:
     """Compose pre-padded operands; returns (padded result, its occupancy,
     pruning stats).
@@ -131,7 +135,7 @@ def compose_padded_blocked(
 
 
 def compose_dense_blocked(
-    a_dense: np.ndarray, b_dense: np.ndarray, interpret: bool = True
+    a_dense: np.ndarray, b_dense: np.ndarray, interpret: Optional[bool] = None
 ) -> Tuple[np.ndarray, dict]:
     """Boolean compose via the kernel; returns (result, pruning stats)."""
     m0, k0 = a_dense.shape

@@ -19,11 +19,14 @@ bounded by 1 — no rescaling pass needed (unlike attention).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.backend import use_interpret
 
 
 def _ssd_kernel(
@@ -69,8 +72,10 @@ def ssd_scan(
     b_coef: jax.Array,  # (B, S, G, N)
     c_coef: jax.Array,  # (B, S, G, N)
     chunk: int = 64,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
+    if interpret is None:
+        interpret = use_interpret()
     bsz, s, h, p = x.shape
     g, n = b_coef.shape[2], b_coef.shape[3]
     assert s % chunk == 0, "pad sequence to a chunk multiple"

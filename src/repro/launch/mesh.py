@@ -89,5 +89,11 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_debug_mesh(data: int = 1, model: int = 1):
-    """Tiny mesh over however many devices the host actually has."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    """Tiny ``(data, model)`` mesh over the host's first devices.
+
+    Built through :func:`make_mesh_for`, so its axes are Auto-typed and
+    ``with_sharding_constraint`` accepts them (``jax.make_mesh`` builds
+    Explicit axes by default).
+    """
+    return make_mesh_for(jax.devices()[: data * model], ("data", "model"),
+                         shape=(data, model))

@@ -49,7 +49,8 @@ class PipelineConfig:
 
     planner: str = "ctt"  # naive | ctt | ctt_cache | ctt_dp
     backend: str = "host"  # SGB executor: host | device
-    kernel_backend: str = "interpret"  # device compose: pallas|interpret|jnp
+    # device compose: None (the platform's) | pallas | interpret | jnp
+    kernel_backend: Optional[str] = None
     restructure: bool = True
     degree_order: bool = True
     affinity: str = "barycenter"

@@ -36,15 +36,13 @@ from repro.train.optim import (
 
 
 def _resolve_executor(
-    executor: Optional[Any], na_backend: str, kernel_backend: str
-) -> Tuple[str, str]:
+    executor: Optional[Any], na_backend: str, kernel_backend: Optional[str]
+) -> Tuple[str, Optional[str]]:
     """An executor spec (``repro.api.ExecutorSpec``, duck-typed so this
     module stays import-independent of the api layer) wins over the
-    legacy string kwargs.  The NA-facing kernel backend is used when the
-    spec exposes one (``kernel_backend="jnp"`` is SGB-composer-only)."""
+    legacy string kwargs."""
     if executor is not None:
-        kb = getattr(executor, "na_kernel_backend", executor.kernel_backend)
-        return executor.na_executor, kb
+        return executor.na_executor, executor.kernel_backend
     return na_backend, kernel_backend
 
 
@@ -157,7 +155,7 @@ def make_train_step(
     weight_decay: float = 0.0,
     clip_norm: Optional[float] = None,
     na_backend: str = "jnp",
-    kernel_backend: str = "interpret",
+    kernel_backend: Optional[str] = None,
     executor: Optional[Any] = None,
 ) -> Callable[..., Tuple[HGNNTrainState, jax.Array]]:
     """Build the jitted train step ``(state, features, labels, mask) ->
@@ -205,7 +203,7 @@ def make_eval_fn(
     graphs: List[Any],
     *,
     na_backend: str = "jnp",
-    kernel_backend: str = "interpret",
+    kernel_backend: Optional[str] = None,
     executor: Optional[Any] = None,
 ) -> Callable[..., jax.Array]:
     """Jitted masked accuracy ``(params, features, labels, mask) -> ()``."""
@@ -237,7 +235,7 @@ def fit(
     lr: float = 3e-3,
     weight_decay: float = 0.0,
     na_backend: str = "jnp",
-    kernel_backend: str = "interpret",
+    kernel_backend: Optional[str] = None,
     executor: Optional[Any] = None,
     epoch_callback: Optional[Callable[[int, float], None]] = None,
     ckpt_dir: Optional[str] = None,

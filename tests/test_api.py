@@ -70,6 +70,70 @@ def test_spec_rejects_unknown_enums(field, value):
         ExecutorSpec(**{field: value})
 
 
+def test_spec_kernel_backend_follows_platform():
+    """The default kernel backend is the platform's (interpret mode on
+    this CPU host); naming 'pallas' where JAX has no TPU is an error at
+    construction, never a silent fallback to the interpreter."""
+    from repro.kernels.backend import platform_backend, resolve
+
+    assert ExecutorSpec().kernel_backend is None
+    assert resolve(None) == platform_backend()
+    if jax.default_backend() != "tpu":
+        assert platform_backend() == "interpret"
+        with pytest.raises(RuntimeError, match="needs a TPU"):
+            ExecutorSpec(na_executor="banded", kernel_backend="pallas")
+        with pytest.raises(RuntimeError, match="needs a TPU"):
+            resolve("pallas")
+    with pytest.raises(ValueError, match="unknown kernel backend"):
+        resolve("jnp")
+
+
+_CACHE_CHILD = """
+import sys
+import jax
+import jax.numpy as jnp
+from repro import compile_cache
+if sys.argv[1] != "-":  # stand-in for <checkout>/.jax_cache
+    compile_cache.CHECKOUT_CACHE_DIR = compile_cache.Path(sys.argv[1])
+print(compile_cache.enable_compile_cache())
+print(jax.config.jax_compilation_cache_dir)
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+jax.jit(lambda x: x * 2 + 1).lower(jnp.ones(3)).compile()
+"""
+
+
+@pytest.mark.parametrize("env_dir", [None, "from-env"])
+def test_compile_cache_dir_is_env_or_checkout(tmp_path, env_dir):
+    """$JAX_COMPILATION_CACHE_DIR wins and the helper sets no other path;
+    without it the cache goes to the fixed in-checkout directory.  A
+    child process compiles, because JAX reads the variable at import."""
+    import os
+    import subprocess
+    import sys
+
+    from repro import compile_cache
+
+    checkout = compile_cache.CHECKOUT_CACHE_DIR
+    assert checkout.name == ".jax_cache"
+    assert (checkout.parent / "src" / "repro" / "compile_cache.py").is_file()
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = os.pathsep.join(
+        [str(checkout.parent / "src"), env.get("PYTHONPATH", "")])
+    if env_dir is None:
+        want, arg = tmp_path / "checkout-cache", str(tmp_path / "checkout-cache")
+    else:
+        want, arg = tmp_path / env_dir, "-"
+        env["JAX_COMPILATION_CACHE_DIR"] = str(want)
+    out = subprocess.run([sys.executable, "-c", _CACHE_CHILD, arg], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.split() == [str(want), str(want)]
+    assert any(p.name.startswith("jit__lambda") for p in want.iterdir())
+    assert sorted(p.name for p in tmp_path.iterdir()) == [want.name]
+
+
 def test_spec_lowers_to_pipeline_config():
     pc = ExecutorSpec(na_executor="banded").pipeline_config()
     assert pc.pack and pc.restructure and pc.renumbered
@@ -77,11 +141,12 @@ def test_spec_lowers_to_pipeline_config():
 
 
 def test_device_sgb_jnp_compose_spec_runs_end_to_end(sessions):
-    """kernel_backend='jnp' is legal for the SGB device composer; the NA
-    side of such a spec must fall back to a backend HGNN.execute accepts
-    (a compiled model from it runs, matching the host-spec result)."""
+    """kernel_backend='jnp' is legal for the SGB device composer; the jnp
+    NA executor never consults the kernel backend, so the spec reaches
+    the model unchanged (no remap to another kernel backend) and a
+    compiled model from it runs, matching the host-spec result."""
     spec = ExecutorSpec(sgb_backend="device", kernel_backend="jnp")
-    assert spec.na_kernel_backend == "interpret"
+    assert not hasattr(spec, "na_kernel_backend")
     graph = sessions["graphs"]["acm_small"]
     targets, target_type = WORKLOADS["acm_small"]
     cfg = _cfg("rgcn", target_type, num_layers=1)

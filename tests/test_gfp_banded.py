@@ -206,6 +206,40 @@ def test_first_in_tile_survives_nonconsecutive_revisit():
     assert not np.allclose(np.asarray(out_bad), np.asarray(want), atol=1e-3)
 
 
+def test_out_of_order_revisits_on_platform_backend():
+    """Many non-consecutive dst-tile revisits through the platform's kernel
+    backend: interpret mode here, compiled Mosaic kernels on a TPU, where
+    an output tile left the accumulator when its block index changed and
+    must still hold the earlier visits' sums when it comes back."""
+    ns, nd, ne, run = 1536, 1024, 2400, 40
+    src = RNG.integers(0, ns, ne)
+    dst = RNG.integers(0, nd, ne)
+    o = np.lexsort((src, dst))
+    runs = RNG.permutation(-(-ne // run))  # shuffle dst-sorted runs of edges
+    o = np.concatenate([o[r * run:(r + 1) * run] for r in runs])
+    src, dst = src[o], dst[o]
+    packed = pack_edge_blocks(src, dst, ns, nd)
+    tiles = packed.dst_tile
+    moved = tiles[1:] != tiles[:-1]
+    seen_before = [t in set(tiles[:i]) for i, t in enumerate(tiles)]
+    assert int(np.sum(moved & np.array(seen_before[1:]))) > 20
+
+    h = jnp.asarray(RNG.standard_normal((ns, 16)), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        out = seg_sum_na(packed, h)
+        logits = (RNG.standard_normal(ne) * 2).astype(np.float32)
+        out_a, alpha = ops.na_attention_packed(packed, logits, h, dst)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(ref.seg_sum_na_ref(src, dst, h, nd)),
+                               atol=1e-4)
+    want_a, alpha_ref = ops.na_attention_aggregate(src, dst, logits, h, nd,
+                                                   backend="jnp")
+    np.testing.assert_allclose(np.asarray(alpha), np.asarray(alpha_ref),
+                               atol=1e-5)
+    np.testing.assert_allclose(np.asarray(out_a)[:nd], np.asarray(want_a),
+                               atol=1e-4)
+
+
 # ------------------------------------------------------- ops-level paths --
 def test_na_attention_aggregate_accepts_cached_packed():
     ns, nd, ne = 300, 150, 1200
