@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.api.spec import ExecutorSpec
 from repro.core.hgnn.models import HGNN, HGNNConfig
 from repro.core.subgraph import DependencyExtractor, DependencySubset
@@ -69,6 +70,12 @@ def canonical_node_ids(node_ids, num_target: int, *,
             f"{ctx}: id {lo if lo < 0 else hi} out of bounds "
             f"(valid range [0, {num_target}))")
     return arr.astype(np.int32, copy=False)
+
+
+def _abstract(tree):
+    """Shapes and dtypes of a pytree of arrays, for ``jit(...).lower``."""
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), jnp.result_type(x)), tree)
 
 
 def device_features(graph: HetGraph) -> Dict[str, jax.Array]:
@@ -187,6 +194,10 @@ class CompiledHGNN:
         self._build_lock = threading.Lock()
         self._loss = None
         self._accuracy = None
+        # abstract (params, features) of the first forward call, for
+        # forward_executable(); recorded once, when the forward is built
+        self._forward_shapes = None
+        obs.register(self)
 
     # ------------------------------------------------------- conveniences --
     @property
@@ -228,6 +239,7 @@ class CompiledHGNN:
                             devices=self._devices,
                             interpret=use_interpret(
                                 self.spec.kernel_backend))
+                        self._forward_shapes = _abstract((params, features))
             return self._shard_exec.forward(params, features)
         if self._forward is None:
             with self._build_lock:
@@ -241,7 +253,50 @@ class CompiledHGNN:
                             kernel_backend=spec.kernel_backend)
 
                     self._forward = jax.jit(fwd)
+                    self._forward_shapes = _abstract((params, features))
         return self._forward(params, features)
+
+    @property
+    def forward_built(self) -> bool:
+        """Whether :meth:`forward` has been called (and so jitted)."""
+        return self._forward_shapes is not None
+
+    def forward_executable(self):
+        """The compiled program of :meth:`forward` at the argument shapes
+        of its first call, or None before that call.  It compiles again
+        (the persistent compile cache serves it), so it is for
+        inspection after the fact: ``repro.obs.forward_scopes`` reads its
+        HLO.
+
+        Example::
+
+            compiled.forward(params, feats)
+            text = compiled.forward_executable().as_text()
+        """
+        if self._forward_shapes is None:
+            return None
+        if self._shard_exec is not None:
+            return self._shard_exec.lower(*self._forward_shapes).compile()
+        return self._forward.lower(*self._forward_shapes).compile()
+
+    def packing_counts(self) -> Dict[str, Dict]:
+        """Per metapath, the banded packing's ``edges``, ``blocks``,
+        ``slots`` (blocks x edges per block) and ``fill`` (edges over
+        slots); empty on the jnp executor.
+
+        Example::
+
+            c = compiled.packing_counts()["MAM"]
+            fill = c["edges"] / c["slots"]
+        """
+        out = {}
+        for g in self.graphs:
+            pk = getattr(g, "packed", None)
+            if pk is not None:
+                out[g.metapath] = {"edges": pk.num_edges,
+                                   "blocks": pk.num_blocks,
+                                   "slots": pk.num_slots, "fill": pk.fill}
+        return out
 
     @property
     def subset_traces(self) -> int:

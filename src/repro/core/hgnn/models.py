@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.hgnn.layers import (
     feature_projection,
     na_attention,
@@ -286,59 +287,63 @@ class HGNN:
             else:
                 h[t] = jnp.ones((n, 1), jnp.float32)  # featureless placeholder
 
-        for lp in params["layers"]:
+        for li, lp in enumerate(params["layers"]):
             # --- FP ---
-            hp = {
-                t: jax.nn.relu(feature_projection(lp["fp"][t]["w"], lp["fp"][t]["b"], x))
-                for t, x in h.items()
-            }
+            hp = {}
+            for t, x in h.items():
+                with obs.scope(obs.fp_scope(li, t)):
+                    hp[t] = jax.nn.relu(feature_projection(
+                        lp["fp"][t]["w"], lp["fp"][t]["b"], x))
             # --- NA per semantic graph ---
             z_by_dst: Dict[str, List[jax.Array]] = {}
             for g in graphs:
-                na_p = lp["na"][g.metapath]
-                h_src = hp[g.src_type] @ na_p["w_rel"]
-                edge_bias = None
-                if cfg.model == "shgn":
-                    eb = lp["edge_emb"][g.edge_type_id] @ lp["a_edge"]
-                    edge_bias = eb  # scalar broadcast over edges
-                if banded:
-                    hb = h_src[g.src_gather]
-                    if cfg.model == "rgcn":
-                        zb = na_mean_banded(g.packed, hb, g.deg,
-                                            backend=kernel_backend)
+                with obs.scope(obs.na_scope(li, g.metapath)):
+                    na_p = lp["na"][g.metapath]
+                    h_src = hp[g.src_type] @ na_p["w_rel"]
+                    edge_bias = None
+                    if cfg.model == "shgn":
+                        eb = lp["edge_emb"][g.edge_type_id] @ lp["a_edge"]
+                        edge_bias = eb  # scalar broadcast over edges
+                    if banded:
+                        hb = h_src[g.src_gather]
+                        if cfg.model == "rgcn":
+                            zb = na_mean_banded(g.packed, hb, g.deg,
+                                                backend=kernel_backend)
+                        else:
+                            zb = na_attention_banded(
+                                hb, hp[g.dst_type][g.dst_gather],
+                                g.src_banded, g.dst_banded, g.packed,
+                                na_p["a_src"], na_p["a_dst"],
+                                edge_bias=edge_bias, backend=kernel_backend,
+                            )
+                        z = zb[g.dst_scatter]  # banded -> global dst order
+                    elif cfg.model == "rgcn":
+                        z = na_mean(h_src, g.src, g.dst, g.num_dst)
                     else:
-                        zb = na_attention_banded(
-                            hb, hp[g.dst_type][g.dst_gather],
-                            g.src_banded, g.dst_banded, g.packed,
-                            na_p["a_src"], na_p["a_dst"],
-                            edge_bias=edge_bias, backend=kernel_backend,
+                        z = na_attention(
+                            h_src, hp[g.dst_type], g.src, g.dst, g.num_dst,
+                            na_p["a_src"], na_p["a_dst"], edge_bias=edge_bias,
                         )
-                    z = zb[g.dst_scatter]  # banded -> global dst order
-                elif cfg.model == "rgcn":
-                    z = na_mean(h_src, g.src, g.dst, g.num_dst)
-                else:
-                    z = na_attention(
-                        h_src, hp[g.dst_type], g.src, g.dst, g.num_dst,
-                        na_p["a_src"], na_p["a_dst"], edge_bias=edge_bias,
-                    )
                 z_by_dst.setdefault(g.dst_type, []).append(z)
             # --- SF per destination type (+ self path for every type) ---
             h_next: Dict[str, jax.Array] = {}
             layer_betas: Dict[str, jax.Array] = {}
             for t, x in hp.items():
-                sf = lp["sf"][t]
-                self_z = x @ sf["w_self"]
-                if t in z_by_dst:
-                    stack = jnp.stack(z_by_dst[t] + [self_z])  # (P+1, N, D)
-                    beta = semantic_fusion_beta(stack, sf["w"], sf["b"],
-                                                sf["q"])
-                    layer_betas[t] = beta
-                    h_next[t] = jnp.einsum("p,pnd->nd", beta, stack)
-                else:
-                    h_next[t] = self_z
+                with obs.scope(obs.sf_scope(li, t)):
+                    sf = lp["sf"][t]
+                    self_z = x @ sf["w_self"]
+                    if t in z_by_dst:
+                        stack = jnp.stack(z_by_dst[t] + [self_z])  # (P+1, N, D)
+                        beta = semantic_fusion_beta(stack, sf["w"], sf["b"],
+                                                    sf["q"])
+                        layer_betas[t] = beta
+                        h_next[t] = jax.nn.relu(
+                            jnp.einsum("p,pnd->nd", beta, stack))
+                    else:
+                        h_next[t] = jax.nn.relu(self_z)
             if betas_out is not None:
                 betas_out.append(layer_betas)
-            h = {t: jax.nn.relu(v) for t, v in h_next.items()}
+            h = h_next
 
         return h
 
@@ -406,55 +411,60 @@ class HGNN:
         for t in self.num_vertices:
             rows = gather[t]
             if self.feature_dims.get(t, 0) > 0:
-                h[t] = features[t][rows]
+                with obs.scope(obs.fp_scope(0, t)):  # the input rows of FP
+                    h[t] = features[t][rows]
             else:
                 h[t] = jnp.ones((rows.shape[0], 1), jnp.float32)
 
         for li, lp in enumerate(params["layers"]):
-            hp = {
-                t: jax.nn.relu(feature_projection(lp["fp"][t]["w"],
-                                                  lp["fp"][t]["b"], x))
-                for t, x in h.items()
-            }
+            hp = {}
+            for t, x in h.items():
+                with obs.scope(obs.fp_scope(li, t)):
+                    hp[t] = jax.nn.relu(feature_projection(
+                        lp["fp"][t]["w"], lp["fp"][t]["b"], x))
             z_by_dst: Dict[str, List[jax.Array]] = {}
             for g, dg in zip(graphs, dep["graphs"]):
-                na_p = lp["na"][g.metapath]
-                h_src = hp[g.src_type] @ na_p["w_rel"]
-                edge_bias = None
-                if cfg.model == "shgn":
-                    edge_bias = lp["edge_emb"][g.edge_type_id] @ lp["a_edge"]
-                if banded:
-                    if cfg.model == "rgcn":
-                        z = na_mean_subset_banded(
-                            g.packed, dg, h_src, backend=kernel_backend)
+                with obs.scope(obs.na_scope(li, g.metapath)):
+                    na_p = lp["na"][g.metapath]
+                    h_src = hp[g.src_type] @ na_p["w_rel"]
+                    edge_bias = None
+                    if cfg.model == "shgn":
+                        edge_bias = lp["edge_emb"][g.edge_type_id] @ lp["a_edge"]
+                    if banded:
+                        if cfg.model == "rgcn":
+                            z = na_mean_subset_banded(
+                                g.packed, dg, h_src, backend=kernel_backend)
+                        else:
+                            z = na_attention_subset_banded(
+                                g.packed, dg, h_src, hp[g.dst_type],
+                                na_p["a_src"], na_p["a_dst"],
+                                edge_bias=edge_bias, backend=kernel_backend)
+                    elif cfg.model == "rgcn":
+                        z = na_mean(h_src, dg["src"], dg["dst"],
+                                    gather[g.dst_type].shape[0])
                     else:
-                        z = na_attention_subset_banded(
-                            g.packed, dg, h_src, hp[g.dst_type],
-                            na_p["a_src"], na_p["a_dst"],
-                            edge_bias=edge_bias, backend=kernel_backend)
-                elif cfg.model == "rgcn":
-                    z = na_mean(h_src, dg["src"], dg["dst"],
-                                gather[g.dst_type].shape[0])
-                else:
-                    z = na_attention(
-                        h_src, hp[g.dst_type], dg["src"], dg["dst"],
-                        gather[g.dst_type].shape[0],
-                        na_p["a_src"], na_p["a_dst"], edge_bias=edge_bias)
+                        z = na_attention(
+                            h_src, hp[g.dst_type], dg["src"], dg["dst"],
+                            gather[g.dst_type].shape[0],
+                            na_p["a_src"], na_p["a_dst"], edge_bias=edge_bias)
                 z_by_dst.setdefault(g.dst_type, []).append(z)
             h_next: Dict[str, jax.Array] = {}
             for t, x in hp.items():
-                sf = lp["sf"][t]
-                self_z = x @ sf["w_self"]
-                if t in z_by_dst:
-                    stack = jnp.stack(z_by_dst[t] + [self_z])
-                    h_next[t] = jnp.einsum("p,pnd->nd", betas[li][t], stack)
-                else:
-                    h_next[t] = self_z
-            h = {t: jax.nn.relu(v) for t, v in h_next.items()}
+                with obs.scope(obs.sf_scope(li, t)):
+                    sf = lp["sf"][t]
+                    self_z = x @ sf["w_self"]
+                    if t in z_by_dst:
+                        stack = jnp.stack(z_by_dst[t] + [self_z])
+                        h_next[t] = jax.nn.relu(
+                            jnp.einsum("p,pnd->nd", betas[li][t], stack))
+                    else:
+                        h_next[t] = jax.nn.relu(self_z)
+            h = h_next
 
-        head = params["head"]
-        rows = h[cfg.target_type][dep["node_rows"]]
-        return rows @ head["w"] + head["b"]
+        with obs.scope(obs.HEAD):
+            head = params["head"]
+            rows = h[cfg.target_type][dep["node_rows"]]
+            return rows @ head["w"] + head["b"]
 
     def execute(
         self,
@@ -477,8 +487,9 @@ class HGNN:
         h = self.hidden_states(params, features, graphs,
                                na_executor=na_executor,
                                kernel_backend=kernel_backend)
-        head = params["head"]
-        return h[self.cfg.target_type] @ head["w"] + head["b"]
+        with obs.scope(obs.HEAD):
+            head = params["head"]
+            return h[self.cfg.target_type] @ head["w"] + head["b"]
 
     def execute_subset(
         self,
@@ -505,9 +516,10 @@ class HGNN:
         h = self.hidden_states(params, features, graphs,
                                na_executor=na_executor,
                                kernel_backend=kernel_backend)
-        head = params["head"]
-        rows = h[self.cfg.target_type][node_ids]
-        return rows @ head["w"] + head["b"]
+        with obs.scope(obs.HEAD):
+            head = params["head"]
+            rows = h[self.cfg.target_type][node_ids]
+            return rows @ head["w"] + head["b"]
 
     def execute_loss(self, params, features, graphs, labels: jax.Array,
                      mask: Optional[jax.Array] = None, *,

@@ -18,7 +18,6 @@ these counters are what benchmarks/ report as the paper's Figs. 14–15.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -187,7 +186,6 @@ class SGBResult:
     graphs: Dict[str, Relation]  # every materialized metapath -> semantic graph
     cost: CompositionCost  # total MACs + bytes
     per_step: List[Tuple[PlanStep, CompositionCost]]
-    wall_seconds: float
     backend: str = "host"
     device_stats: Optional[Dict[str, int]] = None  # tile-pruning counters
 
@@ -295,7 +293,6 @@ def execute_plan(
     """
     if backend not in ("host", "device"):
         raise ValueError(f"unknown backend {backend!r}")
-    t0 = time.perf_counter()
     total = CompositionCost.zero()
     per_step: List[Tuple[PlanStep, CompositionCost]] = []
     mats: Dict[str, Relation] = dict(graph.relations)
@@ -315,7 +312,6 @@ def execute_plan(
             graphs=mats,
             cost=total,
             per_step=per_step,
-            wall_seconds=time.perf_counter() - t0,
             backend="device",
             device_stats=dict(composer.stats),
         )
@@ -329,7 +325,6 @@ def execute_plan(
         graphs=mats,
         cost=total,
         per_step=per_step,
-        wall_seconds=time.perf_counter() - t0,
         backend="host",
     )
 
@@ -394,7 +389,6 @@ def execute_plan_delta(
     The returned ``SGBResult.device_stats`` reports
     ``incremental_steps`` / ``full_steps``.
     """
-    t0 = time.perf_counter()
     total = CompositionCost.zero()
     per_step: List[Tuple[PlanStep, CompositionCost]] = []
     mats: Dict[str, Relation] = dict(graph.relations)
@@ -448,7 +442,6 @@ def execute_plan_delta(
         graphs=mats,
         cost=total,
         per_step=per_step,
-        wall_seconds=time.perf_counter() - t0,
         backend="host+delta",
         device_stats=stats,
     )

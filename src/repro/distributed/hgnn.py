@@ -48,6 +48,7 @@ import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.core.hgnn.layers import feature_projection, semantic_fusion_beta
 from repro.core.hgnn.models import HGNN, BandedBatch
 from repro.kernels.backend import use_interpret
@@ -401,6 +402,11 @@ class ShardedHGNNExecutor:
                     self._fn = self._build_forward()
         return self._fn(params, features, self._blocks)
 
+    def lower(self, params, features):
+        """The jitted forward lowered at ``(params, features)`` (arrays
+        or ``jax.ShapeDtypeStruct``s); the forward must have run once."""
+        return self._fn.lower(params, features, self._blocks)
+
     # ------------------------------------------------------------ builder --
     def _na_weights(self, cfg, blk, e_src_segs, e_dst_segs):
         """Per-slot aggregation weights for this device's stream.
@@ -449,70 +455,84 @@ class ShardedHGNNExecutor:
                     h[t] = features[t]
                 else:
                     h[t] = jnp.ones((n, 1), jnp.float32)
-            for lp in params["layers"]:
-                hp = {
-                    t: jax.nn.relu(feature_projection(lp["fp"][t]["w"], lp["fp"][t]["b"], x))
-                    for t, x in h.items()
-                }
+            merged = "+".join(g.metapath for g in graphs)
+            for li, lp in enumerate(params["layers"]):
+                hp = {}
+                for t, x in h.items():
+                    with obs.scope(obs.fp_scope(li, t)):
+                        hp[t] = jax.nn.relu(
+                            feature_projection(lp["fp"][t]["w"], lp["fp"][t]["b"], x)
+                        )
                 # banded per-relation features into the shared band space
                 feat_segs, e_src_segs, e_dst_segs = [], [], []
                 for r, g in enumerate(graphs):
-                    na_p = lp["na"][g.metapath]
-                    hb = (hp[g.src_type] @ na_p["w_rel"])[g.src_gather]
-                    row_pad = geom.seg_bands[r] * sb - hb.shape[0]
-                    feat_segs.append(jnp.pad(hb, ((0, row_pad), (0, 0))))
-                    if cfg.model != "rgcn":
-                        e_s = hb @ na_p["a_src"]
-                        e_src_segs.append(jnp.pad(e_s, (0, row_pad)))
-                        e_d = hp[g.dst_type][g.dst_gather] @ na_p["a_dst"]
-                        if cfg.model == "shgn":
-                            # the per-relation scalar bias folds into the
-                            # dst-side term: dst rows are relation-exclusive
-                            e_d = e_d + (lp["edge_emb"][g.edge_type_id] @ lp["a_edge"])
-                        e_dst_segs.append(jnp.pad(e_d, (0, geom.seg_tiles[r] * td - e_d.shape[0])))
-                h_cat = jnp.concatenate(feat_segs, axis=0)
-                w = self._na_weights(cfg, blk, e_src_segs, e_dst_segs)
-                out = seg_sum_blocks(
-                    blk["band"],
-                    blk["dst_tile"],
-                    blk["first"],
-                    blk["src_local"],
-                    blk["dst_local"],
-                    w,
-                    h_cat,
-                    num_dst_tiles=geom.total_tiles + 1,
-                    src_band=sb,
-                    dst_tile_rows=td,
-                    interpret=interpret,
-                )
-                # zero rows of tiles this device never touches (their
-                # owners contribute them), then sum exact per-tile results
-                # across the mesh: the semantic-fusion all-gather point
-                touched = jnp.zeros((geom.total_tiles + 1,), jnp.float32)
-                touched = touched.at[blk["dst_tile"]].max((blk["count"] > 0).astype(jnp.float32))
-                rmask = jnp.repeat(touched[: geom.total_tiles] > 0, td)
-                z_all = jnp.where(rmask[:, None], out[: geom.total_tiles * td], 0.0)
-                z_all = jax.lax.psum(z_all, _AXIS)
+                    with obs.scope(obs.na_scope(li, g.metapath)):
+                        na_p = lp["na"][g.metapath]
+                        hb = (hp[g.src_type] @ na_p["w_rel"])[g.src_gather]
+                        row_pad = geom.seg_bands[r] * sb - hb.shape[0]
+                        feat_segs.append(jnp.pad(hb, ((0, row_pad), (0, 0))))
+                        if cfg.model != "rgcn":
+                            e_s = hb @ na_p["a_src"]
+                            e_src_segs.append(jnp.pad(e_s, (0, row_pad)))
+                            e_d = hp[g.dst_type][g.dst_gather] @ na_p["a_dst"]
+                            if cfg.model == "shgn":
+                                # the per-relation scalar bias folds into the
+                                # dst-side term: dst rows are relation-exclusive
+                                e_d = e_d + (lp["edge_emb"][g.edge_type_id] @ lp["a_edge"])
+                            e_dst_segs.append(
+                                jnp.pad(e_d, (0, geom.seg_tiles[r] * td - e_d.shape[0]))
+                            )
+                # one stats + seg-sum kernel pair serves every relation
+                with obs.scope(obs.na_scope(li, merged)):
+                    h_cat = jnp.concatenate(feat_segs, axis=0)
+                    w = self._na_weights(cfg, blk, e_src_segs, e_dst_segs)
+                    out = seg_sum_blocks(
+                        blk["band"],
+                        blk["dst_tile"],
+                        blk["first"],
+                        blk["src_local"],
+                        blk["dst_local"],
+                        w,
+                        h_cat,
+                        num_dst_tiles=geom.total_tiles + 1,
+                        src_band=sb,
+                        dst_tile_rows=td,
+                        interpret=interpret,
+                    )
+                    # zero rows of tiles this device never touches (their
+                    # owners contribute them), then sum exact per-tile
+                    # results across the mesh: the semantic-fusion
+                    # all-gather point
+                    touched = jnp.zeros((geom.total_tiles + 1,), jnp.float32)
+                    touched = touched.at[blk["dst_tile"]].max(
+                        (blk["count"] > 0).astype(jnp.float32)
+                    )
+                    rmask = jnp.repeat(touched[: geom.total_tiles] > 0, td)
+                    z_all = jnp.where(rmask[:, None], out[: geom.total_tiles * td], 0.0)
+                    z_all = jax.lax.psum(z_all, _AXIS)
                 z_by_dst: Dict[str, List[jax.Array]] = {}
                 for r, g in enumerate(graphs):
-                    lo = geom.tile_offsets[r] * td
-                    zb = z_all[lo : lo + g.num_dst]
-                    if cfg.model == "rgcn":
-                        zb = zb / jnp.maximum(g.deg, 1.0)[:, None]
-                    z_by_dst.setdefault(g.dst_type, []).append(zb[g.dst_scatter])
+                    with obs.scope(obs.na_scope(li, g.metapath)):
+                        lo = geom.tile_offsets[r] * td
+                        zb = z_all[lo : lo + g.num_dst]
+                        if cfg.model == "rgcn":
+                            zb = zb / jnp.maximum(g.deg, 1.0)[:, None]
+                        z_by_dst.setdefault(g.dst_type, []).append(zb[g.dst_scatter])
                 h_next: Dict[str, jax.Array] = {}
                 for t, x in hp.items():
-                    sf = lp["sf"][t]
-                    self_z = x @ sf["w_self"]
-                    if t in z_by_dst:
-                        stack = jnp.stack(z_by_dst[t] + [self_z])
-                        beta = semantic_fusion_beta(stack, sf["w"], sf["b"], sf["q"])
-                        h_next[t] = jnp.einsum("p,pnd->nd", beta, stack)
-                    else:
-                        h_next[t] = self_z
-                h = {t: jax.nn.relu(v) for t, v in h_next.items()}
-            head = params["head"]
-            logits = h[cfg.target_type] @ head["w"] + head["b"]
+                    with obs.scope(obs.sf_scope(li, t)):
+                        sf = lp["sf"][t]
+                        self_z = x @ sf["w_self"]
+                        if t in z_by_dst:
+                            stack = jnp.stack(z_by_dst[t] + [self_z])
+                            beta = semantic_fusion_beta(stack, sf["w"], sf["b"], sf["q"])
+                            h_next[t] = jax.nn.relu(jnp.einsum("p,pnd->nd", beta, stack))
+                        else:
+                            h_next[t] = jax.nn.relu(self_z)
+                h = h_next
+            with obs.scope(obs.HEAD):
+                head = params["head"]
+                logits = h[cfg.target_type] @ head["w"] + head["b"]
             # replicated result; a broadcast leading axis satisfies the
             # check_vma=False requirement that out_specs mention the mesh
             # axis (the caller reads shard 0)

@@ -91,6 +91,17 @@ class PackedEdges:
     def num_edges(self) -> int:
         return int(self.count.sum())
 
+    @property
+    def num_slots(self) -> int:
+        """Edge slots the kernel steps over: blocks x ``edge_block``."""
+        return self.num_blocks * self.edge_block
+
+    @property
+    def fill(self) -> float:
+        """Valid edges over slots: the share of each grid step's one-hot
+        work that aggregates a real edge (0 for an empty packing)."""
+        return self.num_edges / self.num_slots if self.num_blocks else 0.0
+
     def hbm_feature_bytes(self, d: int, elem_bytes: int = 4) -> int:
         """Feature bytes streamed HBM->VMEM: one (BAND, D) tile per block.
 

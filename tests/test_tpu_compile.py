@@ -5,7 +5,9 @@ Interpret mode accepts block shapes and layouts that the TPU's compiler
 the banded seg-sum, the edge-softmax stats and the SGB ``spgemm_bsr`` —
 for a ``v5e:2x2`` topology that is described, not attached, at the
 paper's full-scale shapes, and assert that each compiled program holds a
-``tpu_custom_call``.  Nothing runs; a pass says the chip's compiler takes
+``tpu_custom_call``.  A small scoped Simple-HGN forward is compiled the
+same way, to check that the chip's compiled program keeps the scopes the
+profiler trace is read by.  Nothing runs; a pass says the chip's compiler takes
 the kernel, not that its results are right.
 
 The topology is described inside a module fixture (never at import
@@ -13,6 +15,8 @@ time), because only one process at a time may load the TPU library: the
 test workers all collect this file, and only the one that runs it loads
 the library.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -103,3 +107,42 @@ def test_spgemm_bsr_compiles_for_v5e(one_chip):
     b_occ = _shape(one_chip, ((k // TILE) * (m // TILE),), jnp.int32)
     _assert_kernel(lambda x, y, xo, yo: spgemm_bsr(x, y, xo, yo, interpret=False),
                    a, b, a_occ, b_occ)
+
+
+def test_scoped_forward_compiles_for_v5e(one_chip, monkeypatch):
+    """A small banded Simple-HGN forward with compiled Pallas kernels:
+    both NA kernels of each layer and metapath, and the fusions around
+    them, carry their ``layer<i>/na/<metapath>`` scope in the program the
+    chip would run."""
+    from repro import obs
+    from repro.api import ExecutorSpec, Session
+    from repro.core.hgnn import HGNNConfig
+    from repro.hetero import make_dataset
+
+    mps = ["MAM", "MDM", "MKM"]
+    graph = make_dataset("IMDB", scale=0.05)
+    cfg = HGNNConfig(model="shgn", hidden=HIDDEN, num_layers=2, num_classes=3,
+                     target_type="M", edge_emb_dim=16, sf_att_dim=HIDDEN)
+    c = Session(ExecutorSpec(na_executor="banded")).compile(graph, mps, cfg)
+    params = jax.tree.map(lambda x: _shape(one_chip, x.shape, x.dtype),
+                          jax.eval_shape(lambda: c.init(0)))
+    feats = {t: _shape(one_chip, x.shape, jnp.float32) for t, x in graph.features.items()}
+    # the kernel backend follows the platform: steer it to compiled Pallas
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = jax.jit(lambda p, f: c.model.execute(p, f, c.graphs, na_executor="banded")).lower(
+        params, feats).compile().as_text()
+    scope_of = obs.scopes_of_hlo(text)
+    na = {obs.na_scope(li, mp) for li in range(2) for mp in mps}
+    kernels, fusions = set(), set()
+    entry = text[text.index("\nENTRY"):]  # the ops the device runs one by one
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT\s+)?%([^\s=]+) = (?:\S+|\(.*?\)) ([\w-]+)\(", line)
+        if not m:
+            continue
+        name, opcode = m.groups()
+        if "tpu_custom_call" in line:
+            kernels.add((scope_of.get(name), name.split(".")[0]))
+        elif opcode == "fusion":
+            fusions.add(scope_of.get(name))
+    assert kernels == {(s, k) for s in na for k in ("na_seg_sum", "na_softmax_stats")}
+    assert na <= fusions and None not in fusions
