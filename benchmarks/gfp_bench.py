@@ -10,9 +10,10 @@ Reports, per dataset/workload:
   * packer throughput — the vectorized ``pack_edge_blocks`` vs the seed
     Python-loop ``pack_edge_blocks_reference`` on the largest semantic
     graph (claim: >= 10x at scale >= 1);
-  * HBM feature-tile loads — blocks needed (and fp32 feature bytes
-    streamed) for the original vs restructured layout of the same
-    semantic graph (claim at scale >= 1: restructured streams fewer).
+  * HBM feature-tile loads — (BAND, D) tiles (and fp32 feature bytes)
+    a walk in stream order loads, for the original vs restructured
+    layout of the same semantic graph (claim at scale >= 1: restructured
+    streams fewer), beside the blocks the packer emits for each.
 
 Run:  PYTHONPATH=src:. python benchmarks/gfp_bench.py [scale] [out_json]
           [--model-scale-cap CAP]
@@ -44,7 +45,8 @@ from benchmarks.common import row, timed
 from repro.api import ExecutorSpec, Session, device_features
 from repro.compile_cache import enable_compile_cache
 from repro.core.hgnn import HGNNConfig
-from repro.kernels.seg_sum import pack_edge_blocks, pack_edge_blocks_reference
+from repro.kernels.seg_sum import (
+    SRC_BAND, pack_edge_blocks, pack_edge_blocks_reference, stream_tile_loads)
 from repro.pipeline import SemanticGraphCache
 
 WORKLOADS = {
@@ -146,25 +148,32 @@ def bench_gfp(scale: float = 1.0, model_scale_cap: Optional[float] = None
                          f"speedup={speedup:.1f}x;edges={rel.num_edges}"))
 
         # --- HBM feature-tile loads: original vs restructured layout ---
+        # tile_loads_* walk each stream in its own order, so they meter
+        # the schedule's locality; blocks_packed_* are what the packer
+        # emits, which groups each dst-tile run by band and so packs
+        # both orders into about as many blocks
         for t in targets:
             relt = res.semantic[t]
             o = np.lexsort((relt.src, relt.dst))
-            pa = pack_edge_blocks(relt.src[o], relt.dst[o],
-                                  relt.num_src, relt.num_dst)
+            so, do = relt.src[o], relt.dst[o]
+            sr, dr = res.restructured[t].scheduled_edges(renumbered=True)
+            la, lb = stream_tile_loads(so, do), stream_tile_loads(sr, dr)
+            pa = pack_edge_blocks(so, do, relt.num_src, relt.num_dst)
             pb = res.packed[t]  # the pipeline's cached renumbered packing
+            # fp32: the NA kernel gathers/accumulates in fp32
+            tile_mb = SRC_BAND * FEATURE_DIM * 4 / 2**20
             entry["hbm"][t] = {
-                "tile_loads_original": pa.num_blocks,
-                "tile_loads_restructured": pb.num_blocks,
-                # fp32: the NA kernel gathers/accumulates in fp32
-                "hbm_mb_original":
-                    pa.hbm_feature_bytes(FEATURE_DIM, elem_bytes=4) / 2**20,
-                "hbm_mb_restructured":
-                    pb.hbm_feature_bytes(FEATURE_DIM, elem_bytes=4) / 2**20,
+                "tile_loads_original": la,
+                "tile_loads_restructured": lb,
+                "hbm_mb_original": la * tile_mb,
+                "hbm_mb_restructured": lb * tile_mb,
+                "blocks_packed_original": pa.num_blocks,
+                "blocks_packed_restructured": pb.num_blocks,
             }
             lines.append(row(
                 f"gfp/{ds}/hbm/{t}", 0.0,
-                f"tiles={pb.num_blocks}/{pa.num_blocks};"
-                f"ratio={pb.num_blocks / max(pa.num_blocks, 1):.3f}"))
+                f"tiles={lb}/{la};ratio={lb / max(la, 1):.3f};"
+                f"packed={pb.num_blocks}/{pa.num_blocks}"))
         point["datasets"][ds] = entry
     return lines, point
 

@@ -11,12 +11,17 @@ The Graph Restructurer makes this efficient: after restructuring, each edge
 block's sources fall in a narrow row *band* of the feature matrix, so the
 kernel streams one (BAND, D) feature tile HBM->VMEM per block instead of
 random rows.  The host-side ``pack_edge_blocks`` materializes this banded
-block format; the number of blocks it needs (and hence feature bytes moved)
-is the direct kernel-level measurement of the paper's buffer-thrashing
-claim (``benchmarks/paper_figures.py::bench_dram_access`` reports it, and
-``benchmarks/gfp_bench.py`` measures the executed kernel path).
+block format: it groups each destination tile's run of the scheduled stream
+by source band and cuts each (tile run, band) group into blocks, so the
+blocks it needs (and feature bytes moved) count those groups: a layout
+whose tiles gather from fewer bands packs into fewer, fuller blocks.
+Grouping hides most of the schedule's own order, so the paper's
+buffer-thrashing claim is metered by ``stream_tile_loads``, the tiles a
+walk over the stream in its own order loads
+(``benchmarks/paper_figures.py::bench_dram_access`` and
+``benchmarks/gfp_bench.py`` report both).
 
-Grid: one step per edge block in scheduled-stream order; the output tile is
+Grid: one step per edge block in tile-run order; the output tile is
 zero-initialized on the FIRST TOUCH EVER of its destination tile
 (``first_in_tile``) and accumulated on every later visit — including
 non-consecutive revisits, which the restructured schedule produces when a
@@ -72,16 +77,16 @@ class PackedEdges:
     count: np.ndarray  # (nb,) int32 valid edges in block (rest is padding)
     num_src: int
     num_dst: int
+    # Edge -> (block, slot) index map over the scheduled stream: edge p of
+    # the flat stream lives at [edge_block_id[p], edge_slot[p]] of the
+    # blocked arrays.  A permutation, not a chunking: the packer groups
+    # each tile run by band, so a block's slots need not be contiguous in
+    # the stream.  Per-layer weights/logits scatter through it.
+    edge_block_id: np.ndarray  # (E,) int32
+    edge_slot: np.ndarray  # (E,) int32
     edge_block: int = EDGE_BLOCK
     src_band: int = SRC_BAND
     dst_tile_rows: int = DST_TILE
-    # Edge -> (block, slot) index map over the scheduled stream: edge p of
-    # the flat stream lives at [edge_block_id[p], edge_slot[p]] of the
-    # blocked arrays.  Lets per-layer weights/logits become one scatter
-    # instead of an O(num_blocks) host loop; derived lazily for instances
-    # built before the map existed (old cache entries).
-    edge_block_id: Optional[np.ndarray] = None  # (E,) int32
-    edge_slot: Optional[np.ndarray] = None  # (E,) int32
 
     @property
     def num_blocks(self) -> int:
@@ -113,13 +118,6 @@ class PackedEdges:
 
     def edge_map(self) -> Tuple[np.ndarray, np.ndarray]:
         """(edge_block_id, edge_slot) for the flat scheduled stream."""
-        if self.edge_block_id is None or self.edge_slot is None:
-            cnt = self.count.astype(np.int64)
-            blk = np.repeat(np.arange(self.num_blocks, dtype=np.int64), cnt)
-            starts = np.concatenate(([0], np.cumsum(cnt)[:-1]))
-            slot = np.arange(int(cnt.sum()), dtype=np.int64) - np.repeat(starts, cnt)
-            self.edge_block_id = blk.astype(np.int32)
-            self.edge_slot = slot.astype(np.int32)
         return self.edge_block_id, self.edge_slot
 
     def valid_mask(self) -> np.ndarray:
@@ -152,9 +150,7 @@ class PackedEdges:
         nb, eb = self.src_local.shape
         ww = np.zeros((nb, eb), np.float32)
         ww[blk, slot] = np.asarray(flat_weights, np.float32)
-        return dataclasses.replace(
-            self, weight=ww, edge_block_id=self.edge_block_id,
-            edge_slot=self.edge_slot)
+        return dataclasses.replace(self, weight=ww)
 
     def scatter_blocks(self, flat: jax.Array, fill: float = 0.0) -> jax.Array:
         """Device-side scatter of per-edge values (scheduled order) into the
@@ -280,6 +276,42 @@ def shard_blocked(packed: PackedEdges, block_ids: np.ndarray) -> dict:
     }
 
 
+def _tile_run_starts(dtile: np.ndarray) -> np.ndarray:
+    """(E,) bool: True where a maximal run of constant dst tile starts."""
+    new = np.empty(dtile.shape[0], bool)
+    new[0] = True
+    np.not_equal(dtile[1:], dtile[:-1], out=new[1:])
+    return new
+
+
+def stream_tile_loads(
+    src: np.ndarray,
+    dst: np.ndarray,
+    *,
+    edge_block: int = EDGE_BLOCK,
+    src_band: int = SRC_BAND,
+    dst_tile: int = DST_TILE,
+) -> int:
+    """(BAND, D) feature-tile loads of a walk over the stream in its own order.
+
+    One load per ``edge_block`` chunk of every maximal run of constant
+    (dst tile, band): the blocks a packer that cuts the stream in order,
+    without grouping tile runs by band, would emit.  This meters the
+    schedule's own locality (the paper's buffer-thrashing claim), which
+    ``pack_edge_blocks``' block count does not: grouping by band packs a
+    plain (dst, src) order and a restructured one alike.
+    """
+    src = np.asarray(src, np.int64)
+    dst = np.asarray(dst, np.int64)
+    if src.size == 0:
+        return 0
+    new = _tile_run_starts(dst // dst_tile)
+    band = src // src_band
+    new[1:] |= band[1:] != band[:-1]
+    run_len = np.diff(np.append(np.flatnonzero(new), src.size))
+    return int((-(-run_len // edge_block)).sum())
+
+
 def pack_edge_blocks(
     src: np.ndarray,
     dst: np.ndarray,
@@ -292,21 +324,28 @@ def pack_edge_blocks(
 ) -> PackedEdges:
     """Cut the (already scheduled) edge stream into banded blocks.
 
-    A block closes when it reaches ``edge_block`` edges, its destination
-    tile changes, or its sources leave the current ``src_band``-aligned
-    band.  Locality-poor orderings therefore produce many more blocks —
-    the packer is itself a locality meter.
+    Within each maximal run of constant destination tile, the edges are
+    grouped by ``src_band``-aligned source band (a stable sort, so each
+    group keeps stream order), and each (tile run, band) group is cut
+    into ``edge_block`` chunks.  Tile runs keep their stream order, so a
+    tile the schedule revisits is revisited in the same order and
+    ``first_in_tile`` still means first touch ever.  The stream itself is
+    not reordered: the edge map (``edge_block_id``/``edge_slot``) says
+    where each stream edge landed.  Layouts whose tiles gather from many
+    bands, or whose tiles are split into many runs, need more blocks:
+    the block count is the number of (tile run, band) groups, cut into
+    chunks (``stream_tile_loads`` meters the order within a run).
 
-    Fully vectorized: run boundaries come from adjacent (dst-tile, band)
-    changes, runs are split into ``edge_block`` chunks with O(num_blocks)
+    Fully vectorized: one stable argsort groups the stream, group
+    boundaries come from adjacent (dst-tile, band) changes in the grouped
+    order, groups are split into ``edge_block`` chunks with O(num_blocks)
     run-length arithmetic, and the blocked arrays are built with one
-    fancy-indexed scatter per array — O(E) numpy work with no
-    Python-level edge loop (``pack_edge_blocks_reference`` keeps the seed
-    loop as the oracle).  Local indices are stored int16 (they are
+    fancy-indexed scatter per array — O(E log E) numpy work with no
+    Python-level edge loop (``pack_edge_blocks_reference`` keeps the loop
+    form as the oracle).  Local indices are stored int16 (they are
     bounded by the block geometry, 512/128) and unweighted packs defer
     the ones-mask (``PackedEdges.weight = None``): the dense (nb, EB)
-    arrays are the packer's memory-bandwidth floor, so shrinking them is
-    most of the throughput win over the seed.
+    arrays are the packer's memory-bandwidth floor.
     """
     src = np.asarray(src, np.int64)
     dst = np.asarray(dst, np.int64)
@@ -323,10 +362,16 @@ def pack_edge_blocks(
 
     dtile = dst // dst_tile
     band = src // src_band
-    # run = maximal stretch of constant (dst tile, band); block = run chunk
+    # grouped order: tile runs in stream order, bands ascending within each
+    tile_run = np.cumsum(_tile_run_starts(dtile)) - 1
+    order = np.argsort(tile_run * (int(band.max()) + 1) + band, kind="stable")
+    g_tile, g_band = dtile[order], band[order]
+    # group = maximal stretch of constant (dst tile, band) in grouped
+    # order (adjacent tile runs differ in tile); block = group chunk
     newrun = np.empty(E, bool)
     newrun[0] = True
-    np.logical_or(dtile[1:] != dtile[:-1], band[1:] != band[:-1], out=newrun[1:])
+    np.logical_or(g_tile[1:] != g_tile[:-1], g_band[1:] != g_band[:-1],
+                  out=newrun[1:])
     run_starts = np.flatnonzero(newrun)
     run_len = np.diff(np.append(run_starts, E))
     blocks_per_run = -(-run_len // edge_block)
@@ -336,11 +381,14 @@ def pack_edge_blocks(
     chunk = np.arange(nb) - blk_cum[run_of_blk]  # block index within run
     starts = run_starts[run_of_blk] + chunk * edge_block
     cnt = np.diff(np.append(starts, E)).astype(np.int32)
-    blk = np.repeat(np.arange(nb), cnt)  # (E,) block id per edge
-    slot = np.arange(E) - np.repeat(starts, cnt)  # (E,) slot within block
+    # edge map: grouped position q holds stream edge order[q]
+    blk = np.empty(E, np.int32)
+    slot = np.empty(E, np.int32)
+    blk[order] = np.repeat(np.arange(nb), cnt)
+    slot[order] = np.arange(E) - np.repeat(starts, cnt)
 
-    bandv = band[starts].astype(np.int32)
-    dt = dtile[starts].astype(np.int32)
+    bandv = g_band[starts].astype(np.int32)
+    dt = g_tile[starts].astype(np.int32)
     ft = _first_touch_flags(dt)
 
     sl = np.zeros((nb, edge_block), np.int16)
@@ -355,7 +403,7 @@ def pack_edge_blocks(
     return PackedEdges(
         sl, dl, ww, bandv, dt, ft, cnt, num_src, num_dst,
         edge_block=edge_block, src_band=src_band, dst_tile_rows=dst_tile,
-        edge_block_id=blk.astype(np.int32), edge_slot=slot.astype(np.int32),
+        edge_block_id=blk, edge_slot=slot,
     )
 
 
@@ -369,46 +417,52 @@ def pack_edge_blocks_reference(
     src_band: int = SRC_BAND,
     dst_tile: int = DST_TILE,
 ) -> PackedEdges:
-    """The seed Python-loop packer, kept as the equivalence oracle and the
-    baseline of ``benchmarks/gfp_bench.py``'s packer-throughput meter."""
+    """The packer in Python-loop form, kept as the equivalence oracle and
+    the baseline of ``benchmarks/gfp_bench.py``'s packer-throughput meter:
+    walk each tile run, bucket its edges by band in stream order, and
+    emit each bucket in ``edge_block`` chunks, bands ascending."""
     src = np.asarray(src, np.int64)
     dst = np.asarray(dst, np.int64)
     w = np.ones(src.shape, np.float32) if weight is None else np.asarray(weight, np.float32)
     E = src.size
-    bounds = []
+    blocks = []  # (stream positions, band, tile)
     i = 0
     while i < E:
-        dtile = dst[i] // dst_tile
-        band = src[i] // src_band
+        tile = int(dst[i] // dst_tile)
         j = i
-        while (
-            j < E
-            and j - i < edge_block
-            and dst[j] // dst_tile == dtile
-            and src[j] // src_band == band
-        ):
+        groups = {}
+        while j < E and dst[j] // dst_tile == tile:
+            groups.setdefault(int(src[j] // src_band), []).append(j)
             j += 1
-        bounds.append((i, j, int(band), int(dtile)))
+        for band in sorted(groups):
+            pos = groups[band]
+            for c in range(0, len(pos), edge_block):
+                blocks.append((pos[c:c + edge_block], band, tile))
         i = j
 
-    nb = len(bounds)
+    nb = len(blocks)
     sl = np.zeros((nb, edge_block), np.int32)
     dl = np.zeros((nb, edge_block), np.int32)
     ww = np.zeros((nb, edge_block), np.float32)
     bandv = np.zeros((nb,), np.int32)
     dt = np.zeros((nb,), np.int32)
     cnt = np.zeros((nb,), np.int32)
-    for k, (a, b, band, tile) in enumerate(bounds):
-        n = b - a
-        sl[k, :n] = src[a:b] - band * src_band
-        dl[k, :n] = dst[a:b] - tile * dst_tile
-        ww[k, :n] = w[a:b]
+    blk = np.zeros((E,), np.int32)
+    slot = np.zeros((E,), np.int32)
+    for k, (pos, band, tile) in enumerate(blocks):
+        n = len(pos)
+        sl[k, :n] = src[pos] - band * src_band
+        dl[k, :n] = dst[pos] - tile * dst_tile
+        ww[k, :n] = w[pos]
         bandv[k] = band
         dt[k] = tile
         cnt[k] = n
+        blk[pos] = k
+        slot[pos] = np.arange(n)
     return PackedEdges(
         sl, dl, ww, bandv, dt, _first_touch_flags(dt), cnt, num_src, num_dst,
         edge_block=edge_block, src_band=src_band, dst_tile_rows=dst_tile,
+        edge_block_id=blk, edge_slot=slot,
     )
 
 
@@ -427,22 +481,25 @@ def splice_pack_edge_blocks(
     """Repack an edited edge stream by splicing the unchanged blocks of
     an existing packing around a freshly packed edit window.
 
-    ``pack_edge_blocks`` is deterministic on the scheduled stream: blocks
-    are ``edge_block`` chunks of maximal constant (dst-tile, band) *runs*,
-    with chunk offsets measured from each run's start.  Hence any prefix
-    of the stream that (a) is unchanged and (b) ends on a run boundary
-    packs into exactly the same block rows, and likewise for a suffix that
-    *starts* on a run boundary — only the window between them needs the
-    packer.  This function finds the longest common prefix/suffix of the
-    old and new streams, snaps the window edges outward to run boundaries
-    (a run boundary inside the common region is a boundary of both
-    streams, because the flag at position ``i`` only reads positions
-    ``i-1`` and ``i``), packs the window, and concatenates.  The result is
-    bitwise-equal to ``pack_edge_blocks`` over the full new stream:
-    per-block arrays are reused verbatim, while the global products —
-    ``first_in_tile`` (first-touch-EVER semantics) and the edge->(block,
-    slot) map — are recomputed over the spliced block sequence, which is
-    O(nb)/O(E) arithmetic, not a repack.
+    ``pack_edge_blocks`` is deterministic on the scheduled stream and
+    local to its destination-tile runs: each maximal run of constant dst
+    tile packs into its own consecutive blocks (grouped by band,
+    ``edge_block`` chunks per group), whatever the rest of the stream
+    holds.  Hence any prefix of the stream that (a) is unchanged and (b)
+    ends on a tile-run boundary packs into exactly the same block rows,
+    and likewise for a suffix that *starts* on a tile-run boundary — only
+    the window between them needs the packer.  This function finds the
+    longest common prefix/suffix of the old and new streams, snaps the
+    window edges outward to tile-run starts (a tile-run start inside the
+    common region is one of both streams, because the flag at position
+    ``i`` only reads positions ``i-1`` and ``i``), packs the window, and
+    concatenates.  The result is bitwise-equal to ``pack_edge_blocks``
+    over the full new stream: per-block arrays are reused verbatim, the
+    edge->(block, slot) map keeps the prefix's entries, offsets the
+    window's by the prefix's blocks and the suffix's by the change in
+    block count, and ``first_in_tile`` (first-touch-EVER semantics) is
+    recomputed over the spliced block sequence — O(nb)/O(E) arithmetic,
+    not a repack.
 
     Only unweighted packings are spliced (``old`` must have been built
     with ``weight=None``; a lazily materialized ones-mask on it is fine —
@@ -473,30 +530,30 @@ def splice_pack_edge_blocks(
     if p + q > m:
         q = m - p
 
-    # run-start flags of the NEW stream; window edges snap to run starts
-    # strictly inside the common prefix (index <= p-1) / suffix
+    # tile-run starts of the NEW stream; window edges snap to tile-run
+    # starts strictly inside the common prefix (index <= p-1) / suffix
     # (index >= En-q+1), where old and new agree on the flag
-    dtile = dst // dst_tile
-    band = src // src_band
-    newrun = np.empty(En, bool)
-    newrun[0] = True
-    np.logical_or(dtile[1:] != dtile[:-1], band[1:] != band[:-1],
-                  out=newrun[1:])
-    rs = np.flatnonzero(newrun)
+    rs = np.flatnonzero(_tile_run_starts(dst // dst_tile))
     lo = int(rs[rs <= p - 1].max()) if p > 0 else 0
     hi_cand = rs[rs >= En - q + 1]
     hi = int(hi_cand.min()) if hi_cand.size else En
     hi_o = hi - En + Eo
 
+    # a tile run's blocks hold exactly its edges, so the prefix's blocks
+    # are those whose counts sum to lo, and the suffix's likewise
     cnt_o = old.count.astype(np.int64)
     starts_o = np.concatenate(([0], np.cumsum(cnt_o)[:-1]))
     n_pre = int(np.searchsorted(starts_o, lo))
     n_suf = int(np.searchsorted(starts_o, hi_o))
-    # run boundaries are block boundaries; anything else means the old
-    # packing did not come from pack_edge_blocks on this stream
+    blk_o, slot_o = old.edge_map()
+    # anything else means the old packing did not come from
+    # pack_edge_blocks on this stream
     if n_pre < starts_o.size and starts_o[n_pre] != lo:
         return None
     if n_suf < starts_o.size and starts_o[n_suf] != hi_o:
+        return None
+    if (lo and blk_o[:lo].max() >= n_pre) or (
+            hi_o < Eo and blk_o[hi_o:].min() < n_suf):
         return None
 
     mid = pack_edge_blocks(
@@ -512,15 +569,15 @@ def splice_pack_edge_blocks(
         [old.dst_tile[:n_pre], mid.dst_tile, old.dst_tile[n_suf:]])
     cnt = np.concatenate([old.count[:n_pre], mid.count, old.count[n_suf:]])
     nb = int(cnt.shape[0])
-    cnt64 = cnt.astype(np.int64)
-    starts = np.concatenate(([0], np.cumsum(cnt64)[:-1]))
-    blk = np.repeat(np.arange(nb), cnt64)
-    slot = np.arange(En) - np.repeat(starts, cnt64)
+    shift = n_pre + mid.num_blocks - n_suf  # suffix blocks move by this
+    blk = np.concatenate(
+        [blk_o[:lo], mid.edge_block_id + n_pre, blk_o[hi_o:] + shift])
+    slot = np.concatenate([slot_o[:lo], mid.edge_slot, slot_o[hi_o:]])
     packed = PackedEdges(
         srcl, dstl, None, bandv, dt, _first_touch_flags(dt), cnt,
         num_src, num_dst,
         edge_block=edge_block, src_band=src_band, dst_tile_rows=dst_tile,
-        edge_block_id=blk.astype(np.int32), edge_slot=slot.astype(np.int32),
+        edge_block_id=blk, edge_slot=slot,
     )
     reused = n_pre + (old.num_blocks - n_suf)
     return packed, reused, nb

@@ -59,10 +59,9 @@ def _assert_frontend_equal(a, b, targets):
             np.testing.assert_array_equal(pa, pb)
         ka, kb = a.packed[mp], b.packed[mp]
         assert ka.num_blocks == kb.num_blocks
-        # edge_block_id/edge_slot are lazily derived from these, so this
-        # set fully determines the packing
+        # with the edge map, this set fully determines the packing
         for f in ("src_local", "dst_local", "band", "dst_tile",
-                  "first_in_tile", "count"):
+                  "first_in_tile", "count", "edge_block_id", "edge_slot"):
             np.testing.assert_array_equal(
                 np.asarray(getattr(ka, f)), np.asarray(getattr(kb, f)),
                 err_msg=f"{mp}.{f}")
@@ -171,6 +170,44 @@ def test_splice_pack_matches_full_pack(seed):
     out = splice_pack_edge_blocks(ns, nd, src, dst, old, n_src, n_dst)
     if out is None:
         return  # legal fallback (empty stream / geometry mismatch)
+    spliced, reused, total = out
+    full = pack_edge_blocks(ns, nd, n_src, n_dst)
+    assert 0 <= reused <= total == full.num_blocks
+    for f in ("src_local", "dst_local", "band", "dst_tile",
+              "first_in_tile", "count", "edge_block_id", "edge_slot"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(spliced, f)), np.asarray(getattr(full, f)),
+            err_msg=f)
+
+
+@seeded_property(max_examples=20)
+def test_splice_pack_inside_multiband_tile_run(seed):
+    """An edit inside one destination tile's run whose edges span several
+    source bands (so the packer groups the run by band, and the run's
+    blocks are not contiguous in the stream) splices bitwise-equal to a
+    full pack of the edited stream."""
+    rng = np.random.default_rng(seed)
+    n_src, n_dst = int(rng.integers(1100, 3000)), int(rng.integers(130, 520))
+    e = int(rng.integers(200, 4000))
+    src = rng.integers(0, n_src, e).astype(np.int32)
+    dst = rng.integers(0, n_dst, e).astype(np.int32)
+    o = np.lexsort((src, dst))  # one tile run per tile, many bands each
+    src, dst = src[o], dst[o]
+    old = pack_edge_blocks(src, dst, n_src, n_dst)
+    i = int(rng.integers(0, e))
+    tile = dst[i] // 128
+    run = np.flatnonzero(dst // 128 == tile)
+    j = int(rng.integers(i, run[-1] + 2))
+    k = int(rng.integers(2, 64))
+    ins_dst = (tile * 128 + rng.integers(0, 128, k)).clip(max=n_dst - 1)
+    ins_src = rng.integers(0, n_src, k)
+    ins_src[[0, -1]] = rng.integers(0, 512), 512 + rng.integers(0, 512)
+    ns = np.concatenate([src[:i], ins_src.astype(np.int32), src[j:]])
+    nd = np.concatenate([dst[:i], ins_dst.astype(np.int32), dst[j:]])
+    nrun = np.flatnonzero(nd // 128 == tile)
+    assert np.unique(ns[nrun] // 512).size >= 2  # a multi-band tile run
+    out = splice_pack_edge_blocks(ns, nd, src, dst, old, n_src, n_dst)
+    assert out is not None
     spliced, reused, total = out
     full = pack_edge_blocks(ns, nd, n_src, n_dst)
     assert 0 <= reused <= total == full.num_blocks

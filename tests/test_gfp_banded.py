@@ -9,9 +9,11 @@ import numpy as np
 import pytest
 
 from repro.core.hgnn import HGNN, HGNNConfig
+from repro.hetero import make_dataset
 from repro.kernels import ops, ref
 from repro.kernels.seg_sum import (pack_edge_blocks,
-                                   pack_edge_blocks_reference, seg_sum_na)
+                                   pack_edge_blocks_reference, seg_sum_na,
+                                   stream_tile_loads)
 from repro.pipeline import (FrontendPipeline, PipelineConfig,
                             SemanticGraphCache)
 
@@ -138,8 +140,8 @@ def test_banded_batches_pack_on_demand(acm_small):
 
 # ------------------------------------------------------ packer semantics --
 def test_packer_vectorized_equals_reference(frontends):
-    """The vectorized run-boundary packer is field-identical to the seed
-    Python-loop packer on random streams and the restructured schedule."""
+    """The vectorized packer is field-identical to the Python-loop packer,
+    edge map included, on random streams and the restructured schedule."""
     streams = []
     for _ in range(8):
         ns, nd = int(RNG.integers(2, 1200)), int(RNG.integers(2, 900))
@@ -160,10 +162,141 @@ def test_packer_vectorized_equals_reference(frontends):
             assert np.array_equal(getattr(vec, f), getattr(loop, f)), f
         # weights: eager when given, lazily-materialized ones-mask when not
         assert np.array_equal(vec.valid_weight(), loop.valid_weight())
-        # the lazily-derived edge map matches the packer-built one
-        vblk, vslot = vec.edge_block_id, vec.edge_slot
-        lblk, lslot = loop.edge_map()
-        assert np.array_equal(vblk, lblk) and np.array_equal(vslot, lslot)
+        # both packers set the edge map explicitly, and agree on it
+        assert np.array_equal(vec.edge_block_id, loop.edge_block_id)
+        assert np.array_equal(vec.edge_slot, loop.edge_slot)
+
+
+def _revisit_stream():
+    """Tile 0 alternating between bands 0 and 1, tile 1, then tile 0
+    again alternating: three tile runs, the last a non-consecutive
+    revisit of tile 0 (dst 0 and 5 receive in both visits)."""
+    src = np.array([0, 600, 1, 601, 2, 602,  # tile 0: bands 0,1,0,1,0,1
+                    700, 3,                  # tile 1: bands 1,0
+                    900, 4, 901, 5])         # tile 0: bands 1,0,1,0
+    dst = np.array([0, 0, 5, 5, 7, 9,
+                    130, 131,
+                    0, 5, 5, 9])
+    return src, dst, 1024, 256
+
+
+def test_alternating_bands_in_one_tile_pack_into_two_blocks():
+    """One tile run whose edges alternate between two bands: one block
+    per band (the stream-order cut made one block per flip, ten here),
+    each holding its band's edges in stream order."""
+    src = np.array([0, 600, 1, 601, 2, 602, 3, 603, 4, 604])
+    dst = np.array([0, 0, 1, 1, 2, 2, 3, 3, 4, 4])
+    packed = pack_edge_blocks(src, dst, 1024, 128)
+    assert packed.num_blocks == 2
+    np.testing.assert_array_equal(packed.band, [0, 1])
+    np.testing.assert_array_equal(packed.dst_tile, [0, 0])
+    np.testing.assert_array_equal(packed.first_in_tile, [1, 0])
+    np.testing.assert_array_equal(packed.count, [5, 5])
+    np.testing.assert_array_equal(packed.edge_block_id,
+                                  [0, 1, 0, 1, 0, 1, 0, 1, 0, 1])
+    np.testing.assert_array_equal(packed.edge_slot,
+                                  [0, 0, 1, 1, 2, 2, 3, 3, 4, 4])
+    np.testing.assert_array_equal(packed.src_local[:, :5],
+                                  [[0, 1, 2, 3, 4], [88, 89, 90, 91, 92]])
+    np.testing.assert_array_equal(packed.dst_local[:, :5],
+                                  [[0, 1, 2, 3, 4], [0, 1, 2, 3, 4]])
+    assert packed.fill == pytest.approx(10 / 512)
+
+
+def test_stream_tile_loads_counts_runs_in_stream_order():
+    """The schedule's locality meter walks the stream in its own order:
+    one load per edge_block chunk of each (dst tile, band) run, so the
+    alternating stream the packer fits in 2 blocks costs one load per
+    flip, and a band-sorted tile run costs one per band."""
+    src = np.array([0, 600, 1, 601, 2, 602, 3, 603, 4, 604])
+    dst = np.array([0, 0, 1, 1, 2, 2, 3, 3, 4, 4])
+    assert stream_tile_loads(src, dst) == 10
+    assert stream_tile_loads(np.sort(src), dst) == 2
+    assert stream_tile_loads(np.sort(src), dst, edge_block=4) == 4  # 5+5
+    # a revisited tile is a new run: tile 0, tile 1, tile 0 again
+    assert stream_tile_loads(np.array([0, 1, 2]), np.array([0, 130, 1])) == 3
+    assert stream_tile_loads(np.zeros(0, int), np.zeros(0, int)) == 0
+    # a stream in one band per tile run loads what the packer packs
+    src = np.concatenate([np.arange(300) % 512, 1024 + np.arange(10)])
+    dst = np.concatenate([np.arange(300) % 128, 128 + np.arange(10)])
+    assert stream_tile_loads(src, dst) == pack_edge_blocks(
+        src, dst, 1536, 256).num_blocks == 3
+
+
+def test_one_band_per_tile_packs_as_contiguous_chunks():
+    """A stream already in one band per tile run packs as before the
+    grouping: blocks are consecutive edge_block chunks of the stream."""
+    src = np.concatenate([np.arange(300) % 512, 1024 + np.arange(10)])
+    dst = np.concatenate([np.arange(300) % 128, 128 + np.arange(10)])
+    packed = pack_edge_blocks(src, dst, 1536, 256)
+    np.testing.assert_array_equal(packed.count, [256, 44, 10])
+    np.testing.assert_array_equal(packed.band, [0, 0, 2])
+    np.testing.assert_array_equal(packed.dst_tile, [0, 0, 1])
+    np.testing.assert_array_equal(packed.first_in_tile, [1, 0, 1])
+    np.testing.assert_array_equal(
+        packed.edge_block_id, np.repeat([0, 1, 2], [256, 44, 10]))
+    np.testing.assert_array_equal(
+        packed.edge_slot,
+        np.concatenate([np.arange(256), np.arange(44), np.arange(10)]))
+
+
+def test_flat_global_edges_of_grouped_packing_is_the_stream():
+    """The edge map is a permutation, so going back from blocks to the
+    stream recovers the input stream element for element."""
+    src, dst, ns, nd = _revisit_stream()
+    packed = pack_edge_blocks(src, dst, ns, nd)
+    # tile 0: band 0 (3), band 1 (3); tile 1: band 0, band 1; tile 0
+    # again: band 0 (2), band 1 (2)
+    np.testing.assert_array_equal(packed.count, [3, 3, 1, 1, 2, 2])
+    np.testing.assert_array_equal(packed.band, [0, 1, 0, 1, 0, 1])
+    np.testing.assert_array_equal(packed.dst_tile, [0, 0, 1, 1, 0, 0])
+    np.testing.assert_array_equal(packed.first_in_tile, [1, 0, 1, 0, 0, 0])
+    fs, fd = packed.flat_global_edges()
+    np.testing.assert_array_equal(fs, src)
+    np.testing.assert_array_equal(fd, dst)
+
+
+def test_grouped_packing_with_revisit_matches_reference():
+    """seg_sum_na and the attention op over a grouped packing whose tile
+    0 is revisited non-consecutively match the jnp references."""
+    src, dst, ns, nd = _revisit_stream()
+    packed = pack_edge_blocks(src, dst, ns, nd)
+    h = jnp.asarray(RNG.standard_normal((ns, 16)), jnp.float32)
+    out = seg_sum_na(packed, h, interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(ref.seg_sum_na_ref(src, dst, h, nd)),
+        atol=1e-5)
+    logits = (RNG.standard_normal(src.size) * 2).astype(np.float32)
+    out_a, alpha = ops.na_attention_packed(packed, logits, h, dst,
+                                           backend="interpret")
+    want_a, alpha_ref = ops.na_attention_aggregate(src, dst, logits, h, nd,
+                                                   backend="jnp")
+    np.testing.assert_allclose(np.asarray(alpha), np.asarray(alpha_ref),
+                               atol=1e-5)
+    np.testing.assert_allclose(np.asarray(out_a)[:nd], np.asarray(want_a),
+                               atol=1e-4)
+
+
+def test_restructured_imdb_blocks_count_tile_run_band_groups():
+    """On the restructured IMDB graph (scale 0.3) the packer emits
+    ceil(n / edge_block) blocks for each (tile run, band) group of n
+    edges, counted here with a plain loop over the stream."""
+    graph = make_dataset("IMDB", scale=0.3)
+    res = FrontendPipeline(
+        PipelineConfig(planner="ctt", backend="host", pack=True),
+        cache=SemanticGraphCache()).run(graph, ["MAM", "MDM", "MKM"])
+    for mp, rg in res.restructured.items():
+        s, d = rg.scheduled_edges(renumbered=True)
+        packed = res.packed[mp]
+        groups, run, prev = {}, -1, None
+        for a, b in zip((d // packed.dst_tile_rows).tolist(),
+                        (s // packed.src_band).tolist()):
+            if a != prev:
+                run, prev = run + 1, a
+            groups[(run, b)] = groups.get((run, b), 0) + 1
+        want = sum(-(-n // packed.edge_block) for n in groups.values())
+        assert packed.num_blocks == want, mp
+        assert packed.num_edges == s.size
 
 
 def test_first_in_tile_survives_nonconsecutive_revisit():
