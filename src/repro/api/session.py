@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import dataclasses
 import threading
+import time
 from collections import OrderedDict
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -31,7 +32,8 @@ import numpy as np
 
 from repro import obs
 from repro.api.spec import ExecutorSpec
-from repro.core.hgnn.models import HGNN, HGNNConfig
+from repro.core.hgnn.models import (HGNN, HGNNConfig, bind_graphs,
+                                    graph_arrays)
 from repro.core.subgraph import DependencyExtractor, DependencySubset
 from repro.distributed.hgnn import (ShardedHGNNExecutor, ShardPlan,
                                     build_shard_plan)
@@ -151,9 +153,15 @@ class CompiledHGNN:
     Holds the ``HGNN`` + the correct batch flavor for the session's
     ``ExecutorSpec`` (``SemanticGraphBatch`` for jnp, ``BandedBatch`` over
     the cached ``PackedEdges`` for banded) and exposes the full model
-    lifecycle.  ``forward``/``loss``/``evaluate`` are jitted once with the
-    batches closed over (they are host-side packings, not pytrees), so
-    repeated calls — the serving scenario — never retrace.
+    lifecycle.  ``forward``/``forward_subset``/``loss`` are jitted once
+    and take the batches' device arrays as an argument (one pytree, built
+    once: ``models.graph_arrays``), so the graph is data of the compiled
+    programs rather than constants baked into them, and repeated calls —
+    the serving scenario — never retrace.
+
+    ``timings["forward_compile"]`` is the host seconds of the forward's
+    first call: tracing, compiling (or loading from the persistent compile
+    cache) and dispatching it.
     """
 
     def __init__(self, session: "Session", spec: ExecutorSpec, model: HGNN,
@@ -194,9 +202,12 @@ class CompiledHGNN:
         self._build_lock = threading.Lock()
         self._loss = None
         self._accuracy = None
-        # abstract (params, features) of the first forward call, for
+        # the graphs' device arrays, the last argument of the jitted entries
+        self._graph_arrays = None
+        # abstract arguments of the first forward call, for
         # forward_executable(); recorded once, when the forward is built
         self._forward_shapes = None
+        self.timings: Dict[str, float] = {}
         obs.register(self)
 
     # ------------------------------------------------------- conveniences --
@@ -230,6 +241,7 @@ class CompiledHGNN:
             logits = compiled.forward(params, device_features(graph))
             assert logits.shape == (compiled.num_target, cfg.num_classes)
         """
+        built = False
         if self.shard_plan is not None:
             if self._shard_exec is None:
                 with self._build_lock:
@@ -240,26 +252,62 @@ class CompiledHGNN:
                             interpret=use_interpret(
                                 self.spec.kernel_backend))
                         self._forward_shapes = _abstract((params, features))
-            return self._shard_exec.forward(params, features)
-        if self._forward is None:
-            with self._build_lock:
-                if self._forward is None:
-                    spec = self.spec
+                        built = True
+            call, args = self._shard_exec.forward, (params, features)
+        else:
+            if self._forward is None:
+                with self._build_lock:
+                    if self._forward is None:
+                        self._forward = self._jit_on_graph(self.model.execute)
+                        self._forward_shapes = _abstract(
+                            (params, features, self._graph()))
+                        built = True
+            call, args = self._forward, (params, features, self._graph())
+        t = time.perf_counter()
+        out = call(*args)
+        if built:
+            self.timings["forward_compile"] = time.perf_counter() - t
+        return out
 
-                    def fwd(p, f):
-                        return self.model.execute(
-                            p, f, self.graphs,
-                            na_executor=spec.na_executor,
-                            kernel_backend=spec.kernel_backend)
+    def _graph(self) -> List[Dict]:
+        """The bound graphs' device arrays as one pytree (built once)."""
+        if self._graph_arrays is None:
+            self._graph_arrays = graph_arrays(self.graphs, self.cfg.model)
+        return self._graph_arrays
 
-                    self._forward = jax.jit(fwd)
-                    self._forward_shapes = _abstract((params, features))
-        return self._forward(params, features)
+    def _jit_on_graph(self, method):
+        """``jax.jit`` of the model's ``method(p, f, graphs, *rest, **spec)``
+        as a function of ``(p, f, graph, *rest)``: ``graph`` is
+        :meth:`_graph`'s pytree, bound to the batches inside the trace, so
+        the graph's arrays are arguments of the compiled program, not
+        constants of it."""
+        spec = self.spec
+
+        def fn(p, f, graph, *rest):
+            return method(p, f, bind_graphs(self.graphs, graph), *rest,
+                          na_executor=spec.na_executor,
+                          kernel_backend=spec.kernel_backend)
+
+        return jax.jit(fn)
 
     @property
     def forward_built(self) -> bool:
         """Whether :meth:`forward` has been called (and so jitted)."""
         return self._forward_shapes is not None
+
+    def forward_lowered(self):
+        """:meth:`forward` lowered at the argument shapes of its first
+        call (``jax.stages.Lowered``), or None before that call.
+
+        Example::
+
+            compiled.forward(params, feats)
+            text = compiled.forward_lowered().as_text()  # StableHLO
+        """
+        if self._forward_shapes is None:
+            return None
+        fn = self._shard_exec if self._shard_exec is not None else self._forward
+        return fn.lower(*self._forward_shapes)
 
     def forward_executable(self):
         """The compiled program of :meth:`forward` at the argument shapes
@@ -273,11 +321,8 @@ class CompiledHGNN:
             compiled.forward(params, feats)
             text = compiled.forward_executable().as_text()
         """
-        if self._forward_shapes is None:
-            return None
-        if self._shard_exec is not None:
-            return self._shard_exec.lower(*self._forward_shapes).compile()
-        return self._forward.lower(*self._forward_shapes).compile()
+        lowered = self.forward_lowered()
+        return None if lowered is None else lowered.compile()
 
     def packing_counts(self) -> Dict[str, Dict]:
         """Per metapath, the banded packing's ``edges``, ``blocks``,
@@ -370,16 +415,8 @@ class CompiledHGNN:
         if self._beta_fn is None:
             with self._build_lock:
                 if self._beta_fn is None:
-                    spec = self.spec
-
-                    def beta_fn(p, f):
-                        return self.model.fusion_betas(
-                            p, f, self.graphs,
-                            na_executor=spec.na_executor,
-                            kernel_backend=spec.kernel_backend)
-
-                    self._beta_fn = jax.jit(beta_fn)
-        betas = self._beta_fn(params, features)
+                    self._beta_fn = self._jit_on_graph(self.model.fusion_betas)
+        betas = self._beta_fn(params, features, self._graph())
         self._beta_memo[key] = (params, features, betas)
         while len(self._beta_memo) > 4:
             self._beta_memo.popitem(last=False)
@@ -439,24 +476,21 @@ class CompiledHGNN:
         if self._forward_subset is None:
             with self._build_lock:
                 if self._forward_subset is None:
-                    spec = self.spec
 
-                    def fwd_subset(p, f, padded_ids):
+                    def execute_subset(*args, **kw):
                         # traced once per bucket shape; the counter
                         # increments at trace time only, which is what the
                         # no-retrace guard (subset_traces) observes
                         self._subset_traces += 1
-                        return self.model.execute_subset(
-                            p, f, self.graphs, padded_ids,
-                            na_executor=spec.na_executor,
-                            kernel_backend=spec.kernel_backend)
+                        return self.model.execute_subset(*args, **kw)
 
-                    self._forward_subset = jax.jit(fwd_subset)
+                    self._forward_subset = self._jit_on_graph(execute_subset)
         n = int(ids.shape[0])
         bucket = max(int(bucket_min), 1 << max(0, n - 1).bit_length())
         padded = np.zeros((bucket,), np.int32)
         padded[:n] = ids
-        out = self._forward_subset(params, features, jnp.asarray(padded))
+        out = self._forward_subset(params, features, self._graph(),
+                                   jnp.asarray(padded))
         return out[:n]
 
     def _forward_dependency(self, params, features, ids,
@@ -498,18 +532,10 @@ class CompiledHGNN:
         if self._loss is None:
             with self._build_lock:
                 if self._loss is None:
-                    spec = self.spec
-
-                    def loss_fn(p, f, y, m):
-                        return self.model.execute_loss(
-                            p, f, self.graphs, y, mask=m,
-                            na_executor=spec.na_executor,
-                            kernel_backend=spec.kernel_backend)
-
-                    self._loss = jax.jit(loss_fn)
+                    self._loss = self._jit_on_graph(self.model.execute_loss)
         if mask is None:
             mask = jnp.ones((self.num_target,), jnp.float32)
-        return self._loss(params, features, labels, mask)
+        return self._loss(params, features, self._graph(), labels, mask)
 
     def evaluate(self, params, features, labels, mask=None) -> jax.Array:
         """Masked accuracy on the target type (jitted; delegates to the
@@ -720,8 +746,8 @@ class Session:
             lands on (``DependencyExtractor.migrate_from``).
 
         The full-graph forwards and fusion betas are *not* carried — they
-        close over the topology, so the successor re-traces/recalibrates
-        them on first use.  Returns
+        are traced for the topology's packed shapes, so the successor
+        re-traces/recalibrates them on first use.  Returns
         ``(new_compiled, new_graph, delta_result)``.
 
         Example::

@@ -32,7 +32,7 @@ import jax.numpy as jnp
 
 from repro.kernels.backend import use_interpret
 from repro.kernels.ops import na_attention_packed
-from repro.kernels.seg_sum import PackedEdges, seg_sum_na
+from repro.kernels.seg_sum import PackedEdges, gather_rows, seg_sum_na
 
 
 def feature_projection(w: jax.Array, b: jax.Array, x: jax.Array) -> jax.Array:
@@ -121,7 +121,7 @@ def na_attention_banded(
     """
     e_s = h_src @ a_src
     e_d = h_dst @ a_dst
-    logits = e_s[src] + e_d[dst]
+    logits = gather_rows(e_s, src) + gather_rows(e_d, dst)
     if edge_bias is not None:
         logits = logits + edge_bias
     logits = jax.nn.leaky_relu(logits, leaky_slope)

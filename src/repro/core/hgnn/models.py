@@ -31,7 +31,7 @@ from repro.core.hgnn.layers import (
 )
 from repro.hetero.graph import HetGraph, Relation
 from repro.kernels.backend import resolve as resolve_backend
-from repro.kernels.seg_sum import PackedEdges
+from repro.kernels.seg_sum import PackedEdges, gather_rows
 
 
 @dataclasses.dataclass(frozen=True)
@@ -80,6 +80,15 @@ class SemanticGraphBatch:
             dst=jnp.asarray(dst, jnp.int32),
             edge_type_id=edge_type_id,
         )
+
+    def device_arrays(self, edge_maps: bool = True) -> Dict:
+        """The batch's device arrays as one pytree (``edge_maps`` is the
+        banded batch's option; this batch has only its edge list)."""
+        return {"src": self.src, "dst": self.dst}
+
+    def bind(self, arrays: Dict) -> "SemanticGraphBatch":
+        """The same batch over ``arrays`` (see :func:`bind_graphs`)."""
+        return dataclasses.replace(self, **arrays)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -135,6 +144,39 @@ class BandedBatch:
             dst_banded=jnp.asarray(d, jnp.int32),
             deg=jnp.asarray(deg),
         )
+
+    def device_arrays(self, edge_maps: bool = True) -> Dict:
+        """The batch's device arrays as one pytree: the packing's
+        (``PackedEdges.device_arrays``, with its edge maps when
+        ``edge_maps``) and the permutations, banded edges and degrees."""
+        return {"packed": self.packed.device_arrays(edge_maps),
+                **{f: getattr(self, f) for f in _BANDED_ARRAYS}}
+
+    def bind(self, arrays: Dict) -> "BandedBatch":
+        """The same batch over ``arrays`` (see :func:`bind_graphs`)."""
+        return dataclasses.replace(
+            self, packed=self.packed.bind(arrays["packed"]),
+            **{f: arrays[f] for f in _BANDED_ARRAYS})
+
+
+_BANDED_ARRAYS = ("src_gather", "dst_gather", "dst_scatter", "src_banded",
+                  "dst_banded", "deg")
+
+
+def graph_arrays(graphs: List, model: str) -> List[Dict]:
+    """The device arrays of ``graphs`` as one pytree, for a jitted function
+    that takes the graph as an argument and binds it (:func:`bind_graphs`):
+    the graph is then data of the compiled program, not constants baked
+    into it.  The mean (``rgcn``) forward reads no edge map, so its
+    batches leave them out (a backward pass uploads them on first use)."""
+    return [g.device_arrays(edge_maps=model != "rgcn") for g in graphs]
+
+
+def bind_graphs(graphs: List, arrays: List[Dict]) -> List:
+    """``graphs`` over ``arrays`` (as :func:`graph_arrays` gives them, or
+    a jitted function's tracers of them): the same batches, every device
+    array taken from ``arrays``."""
+    return [g.bind(a) for g, a in zip(graphs, arrays)]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -305,18 +347,19 @@ class HGNN:
                         eb = lp["edge_emb"][g.edge_type_id] @ lp["a_edge"]
                         edge_bias = eb  # scalar broadcast over edges
                     if banded:
-                        hb = h_src[g.src_gather]
+                        hb = gather_rows(h_src, g.src_gather)
                         if cfg.model == "rgcn":
                             zb = na_mean_banded(g.packed, hb, g.deg,
                                                 backend=kernel_backend)
                         else:
                             zb = na_attention_banded(
-                                hb, hp[g.dst_type][g.dst_gather],
+                                hb, gather_rows(hp[g.dst_type], g.dst_gather),
                                 g.src_banded, g.dst_banded, g.packed,
                                 na_p["a_src"], na_p["a_dst"],
                                 edge_bias=edge_bias, backend=kernel_backend,
                             )
-                        z = zb[g.dst_scatter]  # banded -> global dst order
+                        # banded -> global dst order
+                        z = gather_rows(zb, g.dst_scatter)
                     elif cfg.model == "rgcn":
                         z = na_mean(h_src, g.src, g.dst, g.num_dst)
                     else:

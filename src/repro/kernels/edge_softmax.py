@@ -112,12 +112,10 @@ def edge_softmax_stats(
     num_dst_tiles = max(1, -(-packed.num_dst // td))
     # count-derived validity, NOT the weights: zero-weight edges still
     # belong to their destination's softmax
-    valid = packed.valid_mask()
+    _, dtile, first, _, dstl = packed.device_blocked()
     m, s = _stats_call(
-        jnp.asarray(packed.dst_tile), jnp.asarray(packed.first_in_tile),
-        jnp.asarray(logits_blocked, jnp.float32),
-        jnp.asarray(packed.dst_local), jnp.asarray(valid),
-        num_dst_tiles, td, interpret,
+        dtile, first, jnp.asarray(logits_blocked, jnp.float32), dstl,
+        packed.device_valid(), num_dst_tiles, td, interpret,
     )
     touched = np.zeros(num_dst_tiles, bool)
     if packed.num_blocks:

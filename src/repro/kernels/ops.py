@@ -20,7 +20,8 @@ from repro.kernels import ref as _ref
 from repro.kernels.backend import use_interpret
 from repro.kernels.edge_softmax import edge_softmax_stats
 from repro.kernels.flash_attention import flash_attention as _fa
-from repro.kernels.seg_sum import PackedEdges, pack_edge_blocks, seg_sum_na
+from repro.kernels.seg_sum import (PackedEdges, gather_rows, pack_edge_blocks,
+                                   seg_sum_na)
 from repro.kernels.spgemm_bsr import compose_dense_blocked
 from repro.kernels.ssd_scan import ssd_scan as _ssd
 
@@ -193,7 +194,8 @@ def _build_attention_packed_vjp(packed: PackedEdges, interpret: bool):
     def stats_alpha(logits):
         lb = packed.scatter_blocks(logits, fill=-1e30)
         m, s = edge_softmax_stats(packed, lb, interpret=interpret)
-        alpha = jnp.exp(logits - m[dst_g]) / jnp.maximum(s[dst_g], 1e-9)
+        alpha = (jnp.exp(logits - gather_rows(m, dst_g))
+                 / jnp.maximum(gather_rows(s, dst_g), 1e-9))
         return m, s, alpha
 
     def primal(logits, h):

@@ -41,7 +41,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -66,10 +66,11 @@ class PackedEdges:
     src_local: np.ndarray  # (nb, EB) int: src - band*SRC_BAND (pad: w=0)
     dst_local: np.ndarray  # (nb, EB) int: dst - dst_tile*DST_TILE
     # (nb, EB) float32 edge weights, 0 for padding.  None = unweighted:
-    # the ones-over-valid-slots mask is materialized lazily by
-    # ``valid_weight()`` on first kernel use (packing a graph no model
-    # ends up running never pays for it) and cached on the instance, so
-    # the shared per-semantic-graph packing builds it at most once.
+    # the kernels weigh by the ones-over-valid-slots mask, materialized
+    # lazily by ``valid_mask()`` on first kernel use (packing a graph no
+    # model ends up running never pays for it) and cached on the
+    # instance, so the shared per-semantic-graph packing builds it at
+    # most once.
     weight: Optional[np.ndarray]
     band: np.ndarray  # (nb,) int32 band unit index
     dst_tile: np.ndarray  # (nb,) int32
@@ -139,9 +140,7 @@ class PackedEdges:
     def valid_weight(self) -> np.ndarray:
         """(nb, EB) float32 weights; unweighted packs resolve to the
         ones-over-valid-slots mask (built lazily, cached)."""
-        if self.weight is None:
-            self.weight = self.valid_mask()
-        return self.weight
+        return self.valid_mask() if self.weight is None else self.weight
 
     def with_weights(self, flat_weights: np.ndarray) -> "PackedEdges":
         """Same blocking, new per-edge weights given in scheduled order."""
@@ -166,23 +165,27 @@ class PackedEdges:
         blk, slot = self.device_edge_map()
         if blk.shape[0] == 0:
             return out
-        return out.at[blk, slot].set(jnp.asarray(flat, jnp.float32))
+        return out.at[blk, slot].set(jnp.asarray(flat, jnp.float32),
+                                     **IN_BOUNDS)
+
+    def _device(self, attr: str, host):
+        """The device copy cached under ``attr``, uploaded from ``host()``
+        on first use.  ``ensure_compile_time_eval``: the first use may
+        happen inside a jitted function's trace — the cached arrays must
+        be concrete, not tracers, or they leak into later traces."""
+        arr = getattr(self, attr, None)
+        if arr is None:
+            with jax.ensure_compile_time_eval():
+                arr = jax.tree.map(jnp.asarray, host())
+            setattr(self, attr, arr)
+        return arr
 
     def device_edge_map(self) -> Tuple[jax.Array, jax.Array]:
         """Device-resident copy of ``edge_map()``, uploaded once and
         cached on the instance (the attention path scatters twice per
         layer per semantic graph — re-staging (E,) index constants every
         call would be a per-layer host round-trip)."""
-        dm = getattr(self, "_device_map", None)
-        if dm is None:
-            blk, slot = self.edge_map()
-            # ensure_compile_time_eval: the first call may happen inside a
-            # jitted train step's trace — the cached arrays must be
-            # concrete, not tracers, or they leak into later traces
-            with jax.ensure_compile_time_eval():
-                dm = (jnp.asarray(blk), jnp.asarray(slot))
-            self._device_map = dm
-        return dm
+        return self._device("_device_map", self.edge_map)
 
     def flat_global_edges(self) -> Tuple[np.ndarray, np.ndarray]:
         """(src, dst) global ids of the flat scheduled stream, recovered
@@ -207,30 +210,81 @@ class PackedEdges:
     def device_flat_edges(self) -> Tuple[jax.Array, jax.Array]:
         """Device-resident ``flat_global_edges()`` (uploaded once; the
         backward pass of every layer of every train step reuses it)."""
-        dfe = getattr(self, "_device_flat_edges", None)
-        if dfe is None:
-            src, dst = self.flat_global_edges()
-            with jax.ensure_compile_time_eval():  # see device_edge_map
-                dfe = (jnp.asarray(src), jnp.asarray(dst))
-            self._device_flat_edges = dfe
-        return dfe
+        return self._device("_device_flat_edges", self.flat_global_edges)
 
     def device_blocked(self) -> Tuple[jax.Array, ...]:
         """Device-resident copies of the static block arrays consumed by
         the NA kernel (band, dst_tile, first_in_tile, src_local,
-        dst_local), uploaded once per packing."""
-        db = getattr(self, "_device_blocked", None)
-        if db is None:
-            with jax.ensure_compile_time_eval():  # see device_edge_map
-                db = (
-                    jnp.asarray(self.band),
-                    jnp.asarray(self.dst_tile),
-                    jnp.asarray(self.first_in_tile),
-                    jnp.asarray(self.src_local),
-                    jnp.asarray(self.dst_local),
-                )
-            self._device_blocked = db
-        return db
+        dst_local), uploaded once per packing.  The per-slot arrays are
+        stored ``(nb, 1, EB)`` (:func:`block_rows`) and int32: a program
+        that takes them as arguments in that shape and type hands them to
+        the kernel as they are, where int16 ones are copied into another
+        tiling on every call."""
+        return self._device("_device_blocked", lambda: (
+            self.band, self.dst_tile, self.first_in_tile,
+            block_rows(self.src_local.astype(np.int32)),
+            block_rows(self.dst_local.astype(np.int32))))
+
+    def device_valid(self) -> jax.Array:
+        """Device-resident ``valid_mask()``, stored ``(nb, 1, EB)``
+        (uploaded once)."""
+        return self._device("_device_valid", lambda: block_rows(self.valid_mask()))
+
+    def device_weight(self) -> jax.Array:
+        """Device-resident ``valid_weight()``, stored ``(nb, 1, EB)``: the
+        valid mask itself for an unweighted packing (uploaded once)."""
+        if self.weight is None:
+            return self.device_valid()
+        return self._device("_device_weight", lambda: block_rows(self.weight))
+
+    def device_arrays(self, edge_maps: bool = True) -> Dict[str, object]:
+        """Every device array the NA kernels read from this packing, as one
+        pytree: the block arrays, the valid mask, the weights of a
+        weighted packing and, with ``edge_maps``, the edge map and the
+        flat edges (the attention path's scatters and every backward pass
+        read those; the mean path's forward does not).
+
+        A jitted function that takes this pytree as an argument and
+        :meth:`bind` s it runs on the packing without holding its arrays as
+        constants of its program."""
+        names = ["blocked", "valid"]
+        if self.weight is not None:
+            names.append("weight")
+        if edge_maps:
+            names += ["edge_map", "flat_edges"]
+        return {n: getattr(self, _DEVICE[n][0])() for n in names}
+
+    def bind(self, arrays: Dict[str, object]) -> "PackedEdges":
+        """A view of this packing whose device arrays are ``arrays`` (a
+        :meth:`device_arrays` pytree, or the tracers a jitted function was
+        given for one).  The view shares the host arrays; it has its own
+        caches, so a device array ``arrays`` leaves out is uploaded (as a
+        constant) on first use, as on the packing itself."""
+        view = dataclasses.replace(self)  # fields only: no cache is shared
+        for name, arr in arrays.items():
+            setattr(view, _DEVICE[name][1], arr)
+        return view
+
+
+# Indexing by index arrays known to lie in bounds (a packing's maps and
+# permutations): no wrap of negative indices and no bounds handling.  Where
+# the indices are arguments of a program rather than constants, the
+# compiler cannot prove either needless, and plain ``x[idx]`` pays a pass
+# over the indices for each.
+IN_BOUNDS = {"mode": "promise_in_bounds", "wrap_negative_indices": False}
+
+
+def gather_rows(x: jax.Array, idx: jax.Array) -> jax.Array:
+    """``x[idx]`` for ``idx`` known to lie in ``[0, len(x))``."""
+    return x.at[idx].get(**IN_BOUNDS)
+
+
+# the device_arrays() entries: name -> (accessor, instance cache)
+_DEVICE = {"blocked": ("device_blocked", "_device_blocked"),
+           "valid": ("device_valid", "_device_valid"),
+           "weight": ("device_weight", "_device_weight"),
+           "edge_map": ("device_edge_map", "_device_map"),
+           "flat_edges": ("device_flat_edges", "_device_flat_edges")}
 
 
 def _first_touch_flags(dt: np.ndarray) -> np.ndarray:
@@ -595,7 +649,7 @@ def _na_kernel(
     def _zero():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    srcl = srcl_ref[0, :].astype(jnp.int32)  # host arrays are int16
+    srcl = srcl_ref[0, :].astype(jnp.int32)  # int16 in the host packing
     dstl = dstl_ref[0, :].astype(jnp.int32)
     w = w_ref[0, :]
     # HIGHEST: the one-hots are exact at any precision, but the MXU's
@@ -611,13 +665,16 @@ def _na_kernel(
 
 
 def block_rows(x: jax.Array) -> jax.Array:
-    """(nb, EB) per-block array -> the (nb, 1, EB) layout the kernels tile.
+    """(nb, EB) per-block array -> the (nb, 1, EB) layout the kernels tile
+    (an array already in it is returned as it is).
 
     Mosaic needs a block's last two dims to be multiples of (8, 128) or
     the whole array dims; a (1, EB) block of an (nb, EB) array is neither,
-    while a (1, EB) block of (nb, 1, EB) is the whole trailing pair.
+    while a (1, EB) block of (nb, 1, EB) is the whole trailing pair.  On a
+    TPU the two layouts differ in memory, so the reshape of a device
+    array is a copy: the packing stores its arrays in this one.
     """
-    return x.reshape(x.shape[0], 1, x.shape[-1])
+    return x if x.ndim == 3 else x.reshape(x.shape[0], 1, x.shape[-1])
 
 
 @functools.partial(
@@ -627,7 +684,7 @@ def _seg_sum_call(
     band, dst_tile, first, src_local, dst_local, weight, h,
     num_dst_tiles, src_band, dst_tile_rows, interpret,
 ):
-    nb, eb = src_local.shape
+    nb, eb = src_local.shape[0], src_local.shape[-1]
     d = h.shape[1]
     row = pl.BlockSpec((None, 1, eb), lambda i, b, t, f: (i, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -681,13 +738,15 @@ def _build_banded_matvec(packed: PackedEdges, interpret: bool,
         h_pad, w = res
         src_g, dst_g = packed.device_flat_edges()
         blk, slot = packed.device_edge_map()
-        w_e = w[blk, slot]  # (E,) weights of the scheduled stream
+        w2 = w.reshape(w.shape[0], -1)  # (nb, EB), however w is stored
+        w_e = w2[blk, slot]  # (E,) weights of the scheduled stream
         g_e = g[dst_g]  # (E, D) output cotangents gathered per edge
         grad_h = jnp.zeros_like(h_pad).at[src_g].add(
             (w_e[:, None] * g_e).astype(h_pad.dtype))
         if weight_grad:
-            grad_w = jnp.zeros_like(w).at[blk, slot].add(
-                jnp.sum(h_pad[src_g].astype(jnp.float32) * g_e, axis=1))
+            grad_w = jnp.zeros_like(w2).at[blk, slot].add(
+                jnp.sum(h_pad[src_g].astype(jnp.float32) * g_e, axis=1)
+            ).reshape(w.shape)
         else:
             grad_w = jnp.zeros_like(w)
         return grad_h, grad_w
@@ -740,7 +799,7 @@ def seg_sum_na(
         )
     num_dst_tiles = max(1, -(-packed.num_dst // packed.dst_tile_rows))
     weight_grad = weights is not None
-    w = jnp.asarray(packed.valid_weight()) if weights is None else jnp.asarray(weights)
+    w = packed.device_weight() if weights is None else jnp.asarray(weights)
     out = banded_matvec_vjp(packed, interpret, weight_grad)(h, w)
     # tiles never visited by any block hold uninitialized memory -> zero them
     touched = np.zeros(num_dst_tiles, bool)
