@@ -327,20 +327,29 @@ class CompiledHGNN:
     def packing_counts(self) -> Dict[str, Dict]:
         """Per metapath, the banded packing's ``edges``, ``blocks``,
         ``slots`` (blocks x edges per block) and ``fill`` (edges over
-        slots); empty on the jnp executor.
+        slots) of its edge blocks, and the ``dense_tiles`` and
+        ``dense_edges`` the forward aggregates as dense tiles (both 0
+        where it steps over the edge blocks: the attention models' traced
+        weights, a sharded forward, a sparse packing); empty on the jnp
+        executor.
 
         Example::
 
             c = compiled.packing_counts()["MAM"]
             fill = c["edges"] / c["slots"]
         """
+        # the mean model's unsharded forward aggregates with static weights
+        static = self.cfg.model == "rgcn" and self.shard_plan is None
         out = {}
         for g in self.graphs:
             pk = getattr(g, "packed", None)
             if pk is not None:
+                dense = static and pk.dense_format
                 out[g.metapath] = {"edges": pk.num_edges,
                                    "blocks": pk.num_blocks,
-                                   "slots": pk.num_slots, "fill": pk.fill}
+                                   "slots": pk.num_slots, "fill": pk.fill,
+                                   "dense_tiles": pk.num_dense_tiles if dense else 0,
+                                   "dense_edges": pk.num_edges if dense else 0}
         return out
 
     @property

@@ -29,6 +29,26 @@ backbone destination's edges span two subgraphs.  Bands are aligned to
 BAND-row units so the feature BlockSpec index is just the band id
 (scalar-prefetched).
 
+Two formats, one kernel name.  A semantic graph that joins a large share
+of its (dst, src) pairs leaves its edge blocks full, and each block still
+builds a (EB, BAND) and a (TD, EB) one-hot for its 256 edges: about 82
+kFLOP per edge where aggregating it takes 2·D.  Such a packing is instead
+aggregated as dense adjacency tiles: one (TD, BAND) tile of summed static
+edge weights (the count of each (dst, src) pair for the mean path) per
+nonempty (dst tile, band) pair, ordered by dst tile so each output tile
+stays resident, and a grid step per tile of ``out_tile += A_tile @
+H_band`` at ``HIGHEST``.  That is the same f32 sum with exact zeros added;
+only its order changes.  ``seg_sum_na`` takes the dense format when the
+weights are static (``weights is None``: the packing's mask or host
+weights) and the packing's pairs number at most 1/``DENSE_BLOCKS_PER_TILE``
+of its blocks (``PackedEdges.dense_format``); traced weights (the
+attention path's alpha) and sparse packings stay on edge blocks, as do
+the raw block entries (``seg_sum_blocks``, ``_seg_sum_call``).  The dense
+kernel's ``pallas_call`` keeps the name ``na_seg_sum``: a packing takes one
+format or the other, so each aggregation is still one ``na_seg_sum`` call,
+and what reads the device trace by kernel name (its roofline share, the
+kernel stage of the forward) reads either format.
+
 ``seg_sum_na`` is differentiable: a ``jax.custom_vjp`` wraps the Pallas
 call, and the backward pass is a gather through the same cached
 edge -> (block, slot) map — ``grad_h[s] = sum_{e: src_e=s} w_e g[dst_e]``
@@ -57,6 +77,13 @@ from repro.kernels.backend import use_interpret
 EDGE_BLOCK = 256  # edges per block (EB)
 SRC_BAND = 512  # feature rows per band (BAND); also the band alignment
 DST_TILE = 128  # output rows per tile (TD)
+# Static-weight aggregation takes the dense format when each dense tile
+# replaces at least this many edge blocks.  On a TPU v5e an edge block
+# step takes about 1.5 us whatever its fill and a dense tile step about
+# 0.7 us, so time alone would go dense sooner; the factor bounds bytes:
+# at 8 the tiles (256 KB each) hold at most about 11x the bytes of the
+# block arrays they replace (3 KB per block).
+DENSE_BLOCKS_PER_TILE = 8
 
 
 @dataclasses.dataclass
@@ -116,6 +143,63 @@ class PackedEdges:
         are stored bf16.
         """
         return self.num_blocks * self.src_band * d * elem_bytes
+
+    @property
+    def num_bands(self) -> int:
+        """Band units the blocks read from (1 for an empty packing)."""
+        return int(self.band.max()) + 1 if self.num_blocks else 1
+
+    def _pair_keys(self, tile: np.ndarray, band: np.ndarray) -> np.ndarray:
+        """(dst tile, band) pairs as int64 keys, ordered by tile, then band."""
+        return tile.astype(np.int64) * self.num_bands + band
+
+    def dense_pairs(self) -> Tuple[np.ndarray, np.ndarray]:
+        """(band, dst_tile) of each nonempty (dst tile, band) pair of the
+        whole packing, ordered by dst tile, then band (memoized).  A tile
+        the schedule revisits is one pair however many tile runs hold it."""
+        dp = getattr(self, "_dense_pairs", None)
+        if dp is None:
+            key = np.unique(self._pair_keys(self.dst_tile, self.band))
+            dp = ((key % self.num_bands).astype(np.int32),
+                  (key // self.num_bands).astype(np.int32))
+            self._dense_pairs = dp
+        return dp
+
+    @property
+    def num_dense_tiles(self) -> int:
+        """Dense tiles of the packing: its nonempty (dst tile, band) pairs."""
+        return int(self.dense_pairs()[0].shape[0])
+
+    @property
+    def dense_format(self) -> bool:
+        """Whether aggregation with static weights takes the dense-tile
+        format: each tile replaces at least ``DENSE_BLOCKS_PER_TILE`` edge
+        blocks."""
+        return bool(self.num_blocks) and (
+            self.num_dense_tiles * DENSE_BLOCKS_PER_TILE <= self.num_blocks)
+
+    def dense_tiles(self) -> Tuple[np.ndarray, ...]:
+        """(band, dst_tile, first, tiles) of the dense format (memoized):
+        per nonempty pair in :meth:`dense_pairs` order, its band, its dst
+        tile, 1 on the first pair of each dst tile, and its
+        ``(dst_tile_rows, src_band)`` float32 tile whose entry [d, s] sums
+        the static weights (``valid_weight``) of the edges from s to d,
+        local indices.  One ``np.bincount`` over every slot of every block
+        (padding slots weigh 0)."""
+        dt = getattr(self, "_dense_tiles", None)
+        if dt is None:
+            band, tile = self.dense_pairs()
+            pair = np.searchsorted(self._pair_keys(tile, band),
+                                   self._pair_keys(self.dst_tile, self.band))
+            flat = ((pair[:, None] * self.dst_tile_rows + self.dst_local)
+                    * self.src_band + self.src_local)
+            size = tile.shape[0] * self.dst_tile_rows * self.src_band
+            tiles = np.bincount(flat.ravel(), weights=self.valid_weight().ravel(),
+                                minlength=size).astype(np.float32)
+            dt = (band, tile, _first_touch_flags(tile),
+                  tiles.reshape(-1, self.dst_tile_rows, self.src_band))
+            self._dense_tiles = dt
+        return dt
 
     def edge_map(self) -> Tuple[np.ndarray, np.ndarray]:
         """(edge_block_id, edge_slot) for the flat scheduled stream."""
@@ -237,12 +321,17 @@ class PackedEdges:
             return self.device_valid()
         return self._device("_device_weight", lambda: block_rows(self.weight))
 
+    def device_dense(self) -> Tuple[jax.Array, ...]:
+        """Device-resident ``dense_tiles()`` (uploaded once)."""
+        return self._device("_device_dense", self.dense_tiles)
+
     def device_arrays(self, edge_maps: bool = True) -> Dict[str, object]:
         """Every device array the NA kernels read from this packing, as one
         pytree: the block arrays, the valid mask, the weights of a
-        weighted packing and, with ``edge_maps``, the edge map and the
-        flat edges (the attention path's scatters and every backward pass
-        read those; the mean path's forward does not).
+        weighted packing, the dense tiles of a packing in the dense format
+        and, with ``edge_maps``, the edge map and the flat edges (the
+        attention path's scatters and every backward pass read those; the
+        mean path's forward does not).
 
         A jitted function that takes this pytree as an argument and
         :meth:`bind` s it runs on the packing without holding its arrays as
@@ -250,6 +339,8 @@ class PackedEdges:
         names = ["blocked", "valid"]
         if self.weight is not None:
             names.append("weight")
+        if self.dense_format:
+            names.append("dense")
         if edge_maps:
             names += ["edge_map", "flat_edges"]
         return {n: getattr(self, _DEVICE[n][0])() for n in names}
@@ -283,6 +374,7 @@ def gather_rows(x: jax.Array, idx: jax.Array) -> jax.Array:
 _DEVICE = {"blocked": ("device_blocked", "_device_blocked"),
            "valid": ("device_valid", "_device_valid"),
            "weight": ("device_weight", "_device_weight"),
+           "dense": ("device_dense", "_device_dense"),
            "edge_map": ("device_edge_map", "_device_map"),
            "flat_edges": ("device_flat_edges", "_device_flat_edges")}
 
@@ -664,6 +756,23 @@ def _na_kernel(
     out_ref[...] += contrib.astype(out_ref.dtype)
 
 
+def _dense_kernel(
+    band_ref, dtile_ref, first_ref,  # scalar-prefetch (SMEM)
+    a_ref, h_ref,  # VMEM inputs: (TD, BAND) weight tile, (BAND, D) band
+    out_ref,  # VMEM output tile (TD, D)
+):
+    i = pl.program_id(0)
+
+    @pl.when(first_ref[i] == 1)
+    def _zero():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    # HIGHEST: the default f32 pass rounds both operands to bf16
+    contrib = jnp.dot(a_ref[...], h_ref[...].astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
+    out_ref[...] += contrib.astype(out_ref.dtype)
+
+
 def block_rows(x: jax.Array) -> jax.Array:
     """(nb, EB) per-block array -> the (nb, 1, EB) layout the kernels tile
     (an array already in it is returned as it is).
@@ -707,11 +816,37 @@ def _seg_sum_call(
       block_rows(weight), h)
 
 
+@functools.partial(jax.jit, static_argnames=("num_dst_tiles", "interpret"))
+def _dense_call(band, dst_tile, first, tiles, h, num_dst_tiles, interpret):
+    """The dense-tile format's kernel: one grid step per (dst tile, band)
+    tile, in dst-tile order, ``out[tile] += tiles[i] @ h[band]``."""
+    nt, td, src_band = tiles.shape
+    d = h.shape[1]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(nt,),
+        in_specs=[
+            pl.BlockSpec((None, td, src_band), lambda i, b, t, f: (i, 0, 0)),
+            pl.BlockSpec((src_band, d), lambda i, b, t, f: (b[i], 0)),
+        ],
+        out_specs=pl.BlockSpec((td, d), lambda i, b, t, f: (t[i], 0)),
+    )
+    return pl.pallas_call(
+        _dense_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((num_dst_tiles * td, d), h.dtype),
+        interpret=interpret,
+        name="na_seg_sum",
+    )(band, dst_tile, first, tiles, h)
+
+
 def _build_banded_matvec(packed: PackedEdges, interpret: bool,
                          weight_grad: bool):
     """``custom_vjp``-wrapped banded matvec for one packing.
 
-    Forward is the Pallas kernel over the padded feature matrix; backward
+    Forward is the Pallas kernel over the padded feature matrix, over
+    dense tiles where the weights are static (``weight_grad=False``) and
+    the packing takes the dense format, else over edge blocks; backward
     is a jnp gather/segment-add through the packing's cached flat edge map
     (``device_flat_edges``) — the transpose of the one-hot matmuls the
     kernel performs, with no host re-packing.  ``weight_grad=False`` skips
@@ -719,13 +854,19 @@ def _build_banded_matvec(packed: PackedEdges, interpret: bool,
     path, whose ones-mask never needs a gradient).
     """
     num_dst_tiles = max(1, -(-packed.num_dst // packed.dst_tile_rows))
-    band, dtile, first, srcl, dstl = packed.device_blocked()
+    if not weight_grad and packed.dense_format:
+        dense = packed.device_dense()
 
-    def primal(h_pad, w):
-        return _seg_sum_call(
-            band, dtile, first, srcl, dstl, w, h_pad,
-            num_dst_tiles, packed.src_band, packed.dst_tile_rows, interpret,
-        )
+        def primal(h_pad, w):
+            return _dense_call(*dense, h_pad, num_dst_tiles, interpret)
+    else:
+        band, dtile, first, srcl, dstl = packed.device_blocked()
+
+        def primal(h_pad, w):
+            return _seg_sum_call(
+                band, dtile, first, srcl, dstl, w, h_pad,
+                num_dst_tiles, packed.src_band, packed.dst_tile_rows, interpret,
+            )
 
     @jax.custom_vjp
     def matvec(h_pad, w):
@@ -786,13 +927,15 @@ def seg_sum_na(
     device-resident (nb, EB) blocked array (see
     ``PackedEdges.scatter_blocks``) — the attention path feeds per-layer
     alpha this way without re-materializing host-side blocks; its
-    cotangent flows back through the blocked layout.  ``interpret=None``
-    runs the platform's kernel backend (``repro.kernels.backend``).
+    cotangent flows back through the blocked layout, and it always runs
+    on edge blocks.  Without it the weights are static and a packing in
+    the dense format (``PackedEdges.dense_format``) is aggregated as
+    dense tiles.  ``interpret=None`` runs the platform's kernel backend
+    (``repro.kernels.backend``).
     """
     if interpret is None:
         interpret = use_interpret()
-    band_units = int(packed.band.max()) + 1 if packed.num_blocks else 1
-    n_src_pad = max(band_units * packed.src_band, packed.num_src)
+    n_src_pad = max(packed.num_bands * packed.src_band, packed.num_src)
     if h.shape[0] < n_src_pad:
         h = jnp.concatenate(
             [h, jnp.zeros((n_src_pad - h.shape[0], h.shape[1]), h.dtype)], axis=0

@@ -119,14 +119,42 @@ def imdb(imdb_small):
 def test_lowered_forward_holds_no_graph_constant(name, request):
     """The graph's arrays are parameters of the program: no literal over
     1 MB, and all of them together a few KiB, where the same forward
-    with the graph closed over holds every packed block as a literal."""
+    with the graph closed over holds as literals every array its NA
+    kernels read: the dense tiles of the mean forward's packings in the
+    dense format (DBLP's APTPA and APVPA at this scale), the packed
+    blocks of the others."""
     m = request.getfixturevalue(name)
     c = m["banded"]
     sizes = _constant_bytes(c.forward_lowered().as_text())
     assert max(sizes, default=0) < MB and sum(sizes) < 64 * 1024
     closed = jax.jit(lambda p, f: c.model.execute(p, f, c.graphs, na_executor="banded"))
-    blocks = sum(g.packed.src_local.nbytes for g in c.graphs)
-    assert sum(_constant_bytes(closed.lower(m["params"], m["feats"]).as_text())) > blocks
+    dense = [g.packed.dense_format and c.cfg.model == "rgcn" for g in c.graphs]
+    assert any(dense) is (name == "dblp")
+    read = sum(g.packed.dense_tiles()[3].nbytes if d else g.packed.src_local.nbytes
+               for g, d in zip(c.graphs, dense))
+    assert sum(_constant_bytes(closed.lower(m["params"], m["feats"]).as_text())) > read
+
+
+def test_packing_counts_report_the_dense_format(dblp, imdb):
+    """Per metapath, ``edges``, ``blocks``, ``slots`` and ``fill`` of the
+    edge blocks as before, and the ``dense_tiles`` and ``dense_edges`` the
+    forward aggregates as dense tiles: DBLP's APTPA and APVPA under the
+    mean model, none of the attention model's packings."""
+    for m in (dblp, imdb):
+        c = m["banded"]
+        counts = c.packing_counts()
+        for g in c.graphs:
+            pk, n = g.packed, counts[g.metapath]
+            assert (n["edges"], n["blocks"], n["slots"]) == (
+                pk.num_edges, pk.num_blocks, pk.num_blocks * pk.edge_block)
+            assert n["fill"] == pytest.approx(n["edges"] / n["slots"])
+            dense = (pk.num_dense_tiles, pk.num_edges) if c.cfg.model == "rgcn" \
+                and pk.dense_format else (0, 0)
+            assert (n["dense_tiles"], n["dense_edges"]) == dense
+    dblp_counts = dblp["banded"].packing_counts()
+    assert [dblp_counts[mp]["dense_edges"] == dblp_counts[mp]["edges"]
+            for mp in DBLP_METAPATHS] == [False, True, True]
+    assert not any(n["dense_edges"] for n in imdb["banded"].packing_counts().values())
 
 
 def test_forward_traces_once(dblp):
@@ -148,15 +176,18 @@ def test_attention_forward_equals_the_closed_over_one(imdb):
 
 
 def test_bound_batches_read_only_the_given_arrays(imdb_small):
-    """``graph_arrays`` leaves the edge maps out of a mean model's graph;
+    """``graph_arrays`` leaves the edge maps out of a mean model's graph
+    and holds the dense tiles of a packing in the dense format;
     ``bind_graphs`` gives batches whose every device array is the given
     one, and whose packings share the host arrays but no cache."""
     c = Session(ExecutorSpec(na_executor="banded")).compile(
         imdb_small, IMDB_METAPATHS, _cfg("rgcn", "M", 3))
     mean = graph_arrays(c.graphs, "rgcn")
     attention = graph_arrays(c.graphs, "shgn")
-    assert set(mean[0]["packed"]) == {"blocked", "valid"}
-    assert set(attention[0]["packed"]) == {"blocked", "valid", "edge_map", "flat_edges"}
+    dense = {"dense"} if c.graphs[0].packed.dense_format else set()
+    assert set(mean[0]["packed"]) == {"blocked", "valid"} | dense
+    assert set(attention[0]["packed"]) == {"blocked", "valid", "edge_map",
+                                           "flat_edges"} | dense
     marked = jax.tree.map(lambda x: x + 0, attention)
     bound = bind_graphs(c.graphs, marked)
     for g, b, a in zip(c.graphs, bound, marked):
@@ -164,6 +195,8 @@ def test_bound_batches_read_only_the_given_arrays(imdb_small):
         assert b.packed.device_blocked() is a["packed"]["blocked"]
         assert b.packed.device_weight() is a["packed"]["valid"]
         assert b.packed.device_flat_edges() is a["packed"]["flat_edges"]
+        if g.packed.dense_format:
+            assert b.packed.device_dense() is a["packed"]["dense"]
         assert b.packed.src_local is g.packed.src_local
         assert b.packed.device_valid() is not g.packed.device_valid()
 

@@ -23,7 +23,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.edge_softmax import _stats_call
-from repro.kernels.seg_sum import DST_TILE, EDGE_BLOCK, SRC_BAND, _seg_sum_call
+from repro.kernels.seg_sum import (DST_TILE, EDGE_BLOCK, SRC_BAND, _dense_call,
+                                   _seg_sum_call)
 from repro.kernels.spgemm_bsr import TILE, spgemm_bsr
 
 # The largest packing of each paper graph at scale=1.0 (restructured,
@@ -84,6 +85,17 @@ def test_seg_sum_kernel_compiles_for_v5e(one_chip, graph):
         lambda b, t, f, s, d, w, x: _seg_sum_call(
             b, t, f, s, d, w, x, tiles, SRC_BAND, DST_TILE, False),
         *blocks, *per_edge, weight, h)
+
+
+def test_dense_seg_sum_kernel_compiles_for_v5e(one_chip):
+    """The dense-tile format at DBLP's APVPA at full scale: every one of
+    its 32 dst tiles x 8 bands holds an edge."""
+    bands, tiles = 8, 32
+    pairs = [_shape(one_chip, (bands * tiles,), jnp.int32)] * 3
+    a = _shape(one_chip, (bands * tiles, DST_TILE, SRC_BAND), jnp.float32)
+    h = _shape(one_chip, (bands * SRC_BAND, HIDDEN), jnp.float32)
+    _assert_kernel(lambda b, t, f, x, y: _dense_call(b, t, f, x, y, tiles, False),
+                   *pairs, a, h)
 
 
 @pytest.mark.parametrize("graph", sorted(PACKINGS))
