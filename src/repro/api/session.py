@@ -33,7 +33,7 @@ import numpy as np
 from repro import obs
 from repro.api.spec import ExecutorSpec
 from repro.core.hgnn.models import (HGNN, HGNNConfig, bind_graphs,
-                                    graph_arrays)
+                                    graph_arrays, static_na_weights)
 from repro.core.subgraph import DependencyExtractor, DependencySubset
 from repro.distributed.hgnn import (ShardedHGNNExecutor, ShardPlan,
                                     build_shard_plan)
@@ -276,17 +276,16 @@ class CompiledHGNN:
         return self._graph_arrays
 
     def _jit_on_graph(self, method):
-        """``jax.jit`` of the model's ``method(p, f, graphs, *rest, **spec)``
-        as a function of ``(p, f, graph, *rest)``: ``graph`` is
-        :meth:`_graph`'s pytree, bound to the batches inside the trace, so
-        the graph's arrays are arguments of the compiled program, not
-        constants of it."""
-        spec = self.spec
+        """``jax.jit`` of the model's ``method(p, f, graphs, *rest,
+        kernel_backend=...)`` as a function of ``(p, f, graph, *rest)``:
+        ``graph`` is :meth:`_graph`'s pytree, bound to the batches inside
+        the trace, so the graph's arrays are arguments of the compiled
+        program, not constants of it."""
+        kernel_backend = self.spec.kernel_backend
 
         def fn(p, f, graph, *rest):
             return method(p, f, bind_graphs(self.graphs, graph), *rest,
-                          na_executor=spec.na_executor,
-                          kernel_backend=spec.kernel_backend)
+                          kernel_backend=kernel_backend)
 
         return jax.jit(fn)
 
@@ -338,8 +337,8 @@ class CompiledHGNN:
             c = compiled.packing_counts()["MAM"]
             fill = c["edges"] / c["slots"]
         """
-        # the mean model's unsharded forward aggregates with static weights
-        static = self.cfg.model == "rgcn" and self.shard_plan is None
+        # the sharded forward always steps over the edge blocks
+        static = static_na_weights(self.cfg.model) and self.shard_plan is None
         out = {}
         for g in self.graphs:
             pk = getattr(g, "packed", None)
@@ -513,7 +512,7 @@ class CompiledHGNN:
         if self._forward_dep is None:
             with self._build_lock:
                 if self._forward_dep is None:
-                    spec = self.spec
+                    kernel_backend = self.spec.kernel_backend
 
                     def fwd_dep(p, f, b, dep):
                         # traced once per bucket signature; the counter
@@ -522,8 +521,7 @@ class CompiledHGNN:
                         self._dependency_traces += 1
                         return self.model.execute_dependency_subset(
                             p, f, self.graphs, dep, b,
-                            na_executor=spec.na_executor,
-                            kernel_backend=spec.kernel_backend)
+                            kernel_backend=kernel_backend)
 
                     self._forward_dep = jax.jit(fwd_dep)
         out = self._forward_dep(params, features, betas, sub.arrays)
@@ -555,8 +553,9 @@ class CompiledHGNN:
                 if self._accuracy is None:
                     from repro.train.hgnn_step import make_eval_fn
 
-                    self._accuracy = make_eval_fn(self.model, self.graphs,
-                                                  executor=self.spec)
+                    self._accuracy = make_eval_fn(
+                        self.model, self.graphs,
+                        kernel_backend=self.spec.kernel_backend)
         if mask is None:
             mask = jnp.ones((self.num_target,), jnp.float32)
         return self._accuracy(params, features, labels, mask)
@@ -584,7 +583,8 @@ class CompiledHGNN:
 
         return _fit(self.model, self.graphs, features, labels, masks,
                     epochs=epochs, seed=seed, lr=lr,
-                    weight_decay=weight_decay, executor=self.spec,
+                    weight_decay=weight_decay,
+                    kernel_backend=self.spec.kernel_backend,
                     epoch_callback=epoch_callback, ckpt_dir=ckpt_dir,
                     ckpt_every=ckpt_every)
 

@@ -42,6 +42,9 @@ _SHARD_MODES = ("none", "relation", "edge_block")
 class ExecutorSpec:
     """How to plan, build, and execute — everything but the workload.
 
+    ``na_executor`` picks the batches ``Session`` builds: edge lists
+    (``"jnp"``, the reference) or the banded packings (``"banded"``).
+    The batches then pick the model's NA step (``HGNN.hidden_states``).
     ``kernel_backend`` is shared by the two kernel consumers: the SGB
     device composer (``interpret`` | ``pallas`` | ``jnp``) and the banded
     NA executor (``interpret`` | ``pallas`` — kernels only, validated).

@@ -125,7 +125,7 @@ def na_attention_banded(
     if edge_bias is not None:
         logits = logits + edge_bias
     logits = jax.nn.leaky_relu(logits, leaky_slope)
-    out, _ = na_attention_packed(packed, logits, h_src, dst, backend=backend)
+    out, _ = na_attention_packed(packed, logits, h_src, backend=backend)
     return out
 
 

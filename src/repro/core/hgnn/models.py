@@ -9,12 +9,22 @@ The model consumes the output of the SGB stage: a list of semantic graphs
   SF  — HAN-style semantic attention fusing all semantic graphs that end at
         the same destination type (plus a self/residual path).
 
+The layer is written once, in ``HGNN._layers``.  Each execution path
+supplies only its NA step, and the batches pick it: the jnp pair over edge
+lists for ``SemanticGraphBatch`` es, the banded Pallas kernels for
+``BandedBatch`` es (each in a full-graph and a dependency-subset form), and
+the merged multi-relation step of the sharded executor
+(``repro.distributed.hgnn``).  Every NA step takes its inputs from
+:func:`na_inputs`; :func:`static_na_weights` is the one place on the
+forward path that reads the model's name.
+
 Paper §5.3 configuration: hidden 64, layers {3: RGAT, 3: RGCN, 2: S-HGN}.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional
+import functools
+from typing import Callable, Dict, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -29,6 +39,7 @@ from repro.core.hgnn.layers import (
     na_mean_banded,
     semantic_fusion_beta,
 )
+from repro.core.subgraph import na_subset_banded
 from repro.hetero.graph import HetGraph, Relation
 from repro.kernels.backend import resolve as resolve_backend
 from repro.kernels.seg_sum import PackedEdges, gather_rows
@@ -95,8 +106,8 @@ class SemanticGraphBatch:
 class BandedBatch:
     """Device-ready semantic graph in the restructured BANDED layout.
 
-    The sibling of ``SemanticGraphBatch`` consumed by the banded NA
-    executor (``HGNN.execute(..., na_executor="banded")``, bound by
+    The sibling of ``SemanticGraphBatch`` that picks the banded NA step
+    (``HGNN.execute`` over a list of them, bound by
     ``repro.api.Session.compile``): it carries the pipeline's
     cached ``PackedEdges`` blocks (built once per semantic graph, shared
     across models and layers) plus the gather/scatter permutations that
@@ -167,9 +178,11 @@ def graph_arrays(graphs: List, model: str) -> List[Dict]:
     """The device arrays of ``graphs`` as one pytree, for a jitted function
     that takes the graph as an argument and binds it (:func:`bind_graphs`):
     the graph is then data of the compiled program, not constants baked
-    into it.  The mean (``rgcn``) forward reads no edge map, so its
-    batches leave them out (a backward pass uploads them on first use)."""
-    return [g.device_arrays(edge_maps=model != "rgcn") for g in graphs]
+    into it.  A forward with static NA weights (:func:`static_na_weights`)
+    reads no edge map, so its batches leave them out (a backward pass
+    uploads them on first use)."""
+    return [g.device_arrays(edge_maps=not static_na_weights(model))
+            for g in graphs]
 
 
 def bind_graphs(graphs: List, arrays: List[Dict]) -> List:
@@ -252,6 +265,75 @@ def init_params(
     return params
 
 
+def static_na_weights(model: str) -> bool:
+    """Whether ``model``'s NA aggregates with the packing's static weights
+    (the mean model; attention weights are traced).  The one place on the
+    forward path that reads the model's name."""
+    return model == "rgcn"
+
+
+class NAInputs(NamedTuple):
+    """What one semantic graph's NA reads in one layer (:func:`na_inputs`):
+    the ``w_rel``-projected sources; for attention also the destination
+    features and attention vectors, and Simple-HGN's scalar ``bias``."""
+
+    h_src: jax.Array
+    h_dst: Optional[jax.Array] = None
+    a_src: Optional[jax.Array] = None
+    a_dst: Optional[jax.Array] = None
+    bias: Optional[jax.Array] = None
+
+    @property
+    def attention(self) -> bool:
+        """Edge-softmax attention (else the degree-normalised mean)."""
+        return self.a_src is not None
+
+
+def na_inputs(cfg: HGNNConfig, lp: Dict, g, hp: Dict[str, jax.Array]) -> NAInputs:
+    """The NA inputs of batch ``g`` in a layer with params ``lp`` and FP
+    outputs ``hp``; every NA step takes its inputs from here.  Simple-HGN's
+    edge-type term is there where the layer holds an edge-type embedding."""
+    na_p = lp["na"][g.metapath]
+    h_src = hp[g.src_type] @ na_p["w_rel"]
+    if static_na_weights(cfg.model):
+        return NAInputs(h_src)
+    bias = None
+    if "edge_emb" in lp:
+        bias = lp["edge_emb"][g.edge_type_id] @ lp["a_edge"]  # one scalar per metapath
+    return NAInputs(h_src, hp[g.dst_type], na_p["a_src"], na_p["a_dst"], bias)
+
+
+def _na_edges(x: NAInputs, src: jax.Array, dst: jax.Array, num_dst: int) -> jax.Array:
+    """NA over an edge list (``jax.ops.segment_*``, the reference)."""
+    if not x.attention:
+        return na_mean(x.h_src, src, dst, num_dst)
+    return na_attention(x.h_src, x.h_dst, src, dst, num_dst, x.a_src, x.a_dst,
+                        edge_bias=x.bias)
+
+
+def _na_banded(g: "BandedBatch", x: NAInputs, backend: str) -> jax.Array:
+    """NA on the Pallas kernels over ``g``'s packing: features permuted
+    into the banded numbering, the output back into global order."""
+    hb = gather_rows(x.h_src, g.src_gather)
+    if not x.attention:
+        zb = na_mean_banded(g.packed, hb, g.deg, backend=backend)
+    else:
+        zb = na_attention_banded(
+            hb, gather_rows(x.h_dst, g.dst_gather), g.src_banded, g.dst_banded,
+            g.packed, x.a_src, x.a_dst, edge_bias=x.bias, backend=backend)
+    return gather_rows(zb, g.dst_scatter)
+
+
+def _is_banded(graphs: List) -> bool:
+    """Whether ``graphs`` are ``BandedBatch`` es (else ``SemanticGraphBatch``
+    es): the batches pick the NA step.  A mixed list is a ``TypeError``."""
+    kinds = {type(g) for g in graphs}
+    if len(kinds) > 1 or not kinds <= {BandedBatch, SemanticGraphBatch}:
+        raise TypeError("graphs must be all BandedBatch or all SemanticGraphBatch, "
+                        f"got {sorted(k.__name__ for k in kinds)}")
+    return BandedBatch in kinds
+
+
 class HGNN:
     """Config + pure apply function (params are an explicit pytree)."""
 
@@ -265,140 +347,137 @@ class HGNN:
     def init(self, key: jax.Array) -> Dict:
         return init_params(key, self.cfg, self.feature_dims, self.metapaths)
 
-    def hidden_states(
-        self,
-        params: Dict,
-        features: Dict[str, jax.Array],
-        graphs: List[SemanticGraphBatch],
-        *,
-        na_executor: str = "jnp",
-        kernel_backend: Optional[str] = None,
-        betas_out: Optional[List] = None,
-    ) -> Dict[str, jax.Array]:
-        """Run every FP -> NA -> SF layer; returns the final per-type
-        hidden states (global vertex numbering), pre-classifier-head.
-
-        ``betas_out``, when given an empty list, collects one
-        ``{dst_type: (P_t + 1,)}`` dict of semantic-attention weights per
-        layer — the graph-level SF statistics the dependency-subset
-        executor freezes (see :meth:`fusion_betas`).
-
-        This is the shared body of :meth:`execute` (full head) and
-        :meth:`execute_subset` (head over a gathered row subset): message
-        passing is always full-graph — a target vertex's logits depend on
-        its whole receptive field — so the two entry points differ only in
-        which target rows go through the head.
-
-        ``na_executor`` selects the NA executor:
-          * "jnp"    — ``jax.ops.segment_*`` over global edge lists
-                       (``graphs`` must be ``SemanticGraphBatch``);
-          * "banded" — the Pallas NA kernels over the restructurer's cached
-                       ``PackedEdges`` blocks (``graphs`` must be
-                       ``BandedBatch``, see
-                       ``FrontendResult.banded_batches()``); features are
-                       permuted once per layer into the renumbered banded
-                       layout and NA outputs permuted back, so FP/SF and
-                       the returned logits keep global vertex numbering.
-        ``kernel_backend`` ("interpret" | "pallas" | None for the
-        platform's, see ``repro.kernels.backend``) only applies to the
-        banded path.
-
-        Both executors are differentiable: the banded NA kernels carry
-        custom VJPs (kernels/seg_sum.py, kernels/ops.py) whose backward
-        gathers through the cached packing, so ``jax.grad`` of a loss
-        built on this apply works identically on either backend — the
-        training path (train/hgnn_step.py) runs banded with the same
-        cached ``BandedBatch`` list across every step.
-        """
-        cfg = self.cfg
-        if na_executor not in ("jnp", "banded"):
-            raise ValueError(f"unknown na_executor {na_executor!r}")
-        banded = na_executor == "banded"
-        if banded:
-            kernel_backend = resolve_backend(kernel_backend)
-        for g in graphs:
-            if banded != isinstance(g, BandedBatch):
-                raise TypeError(
-                    f"na_executor={na_executor!r} needs "
-                    f"{'BandedBatch' if banded else 'SemanticGraphBatch'} "
-                    f"inputs, got {type(g).__name__} for {g.metapath!r}")
+    # ------------------------------------------------- the one layer loop --
+    def _inputs(self, features: Dict[str, jax.Array],
+                rows: Optional[Dict[str, jax.Array]] = None) -> Dict[str, jax.Array]:
+        """Layer 0's input per vertex type: its features, or a ones column
+        for a featureless type; with ``rows``, only the rows ``rows[type]``."""
         h: Dict[str, jax.Array] = {}
         for t, n in self.num_vertices.items():
-            if self.feature_dims.get(t, 0) > 0:
+            if rows is not None:
+                n = rows[t].shape[0]
+            if self.feature_dims.get(t, 0) == 0:
+                h[t] = jnp.ones((n, 1), jnp.float32)  # featureless placeholder
+            elif rows is None:
                 h[t] = features[t]
             else:
-                h[t] = jnp.ones((n, 1), jnp.float32)  # featureless placeholder
+                with obs.scope(obs.fp_scope(0, t)):  # the input rows of FP
+                    h[t] = features[t][rows[t]]
+        return h
 
+    def _layers(self, params: Dict, h: Dict[str, jax.Array], graphs: List,
+                aggregate: Callable, *, betas: Optional[List] = None,
+                betas_out: Optional[List] = None) -> Dict[str, jax.Array]:
+        """Every FP -> NA -> SF layer over ``h``; the last layer's states.
+
+        ``aggregate(li, lp, hp)`` is the path's NA step: layer ``li``'s NA
+        outputs (params ``lp``, FP outputs ``hp``), one per batch of
+        ``graphs``, in order.  SF fuses them per destination type with the
+        semantic attention it computes, or with the frozen ``betas`` (one
+        dict per layer) where given; ``betas_out`` collects the computed
+        ones."""
         for li, lp in enumerate(params["layers"]):
-            # --- FP ---
             hp = {}
             for t, x in h.items():
                 with obs.scope(obs.fp_scope(li, t)):
                     hp[t] = jax.nn.relu(feature_projection(
                         lp["fp"][t]["w"], lp["fp"][t]["b"], x))
-            # --- NA per semantic graph ---
             z_by_dst: Dict[str, List[jax.Array]] = {}
-            for g in graphs:
-                with obs.scope(obs.na_scope(li, g.metapath)):
-                    na_p = lp["na"][g.metapath]
-                    h_src = hp[g.src_type] @ na_p["w_rel"]
-                    edge_bias = None
-                    if cfg.model == "shgn":
-                        eb = lp["edge_emb"][g.edge_type_id] @ lp["a_edge"]
-                        edge_bias = eb  # scalar broadcast over edges
-                    if banded:
-                        hb = gather_rows(h_src, g.src_gather)
-                        if cfg.model == "rgcn":
-                            zb = na_mean_banded(g.packed, hb, g.deg,
-                                                backend=kernel_backend)
-                        else:
-                            zb = na_attention_banded(
-                                hb, gather_rows(hp[g.dst_type], g.dst_gather),
-                                g.src_banded, g.dst_banded, g.packed,
-                                na_p["a_src"], na_p["a_dst"],
-                                edge_bias=edge_bias, backend=kernel_backend,
-                            )
-                        # banded -> global dst order
-                        z = gather_rows(zb, g.dst_scatter)
-                    elif cfg.model == "rgcn":
-                        z = na_mean(h_src, g.src, g.dst, g.num_dst)
-                    else:
-                        z = na_attention(
-                            h_src, hp[g.dst_type], g.src, g.dst, g.num_dst,
-                            na_p["a_src"], na_p["a_dst"], edge_bias=edge_bias,
-                        )
+            for g, z in zip(graphs, aggregate(li, lp, hp)):
                 z_by_dst.setdefault(g.dst_type, []).append(z)
-            # --- SF per destination type (+ self path for every type) ---
-            h_next: Dict[str, jax.Array] = {}
-            layer_betas: Dict[str, jax.Array] = {}
+            h, layer_betas = {}, {}
             for t, x in hp.items():
                 with obs.scope(obs.sf_scope(li, t)):
                     sf = lp["sf"][t]
                     self_z = x @ sf["w_self"]
-                    if t in z_by_dst:
-                        stack = jnp.stack(z_by_dst[t] + [self_z])  # (P+1, N, D)
-                        beta = semantic_fusion_beta(stack, sf["w"], sf["b"],
-                                                    sf["q"])
-                        layer_betas[t] = beta
-                        h_next[t] = jax.nn.relu(
-                            jnp.einsum("p,pnd->nd", beta, stack))
+                    if t not in z_by_dst:
+                        h[t] = jax.nn.relu(self_z)
+                        continue
+                    stack = jnp.stack(z_by_dst[t] + [self_z])  # (P+1, N, D)
+                    if betas is None:
+                        beta = layer_betas[t] = semantic_fusion_beta(
+                            stack, sf["w"], sf["b"], sf["q"])
                     else:
-                        h_next[t] = jax.nn.relu(self_z)
+                        beta = betas[li][t]
+                    h[t] = jax.nn.relu(jnp.einsum("p,pnd->nd", beta, stack))
             if betas_out is not None:
                 betas_out.append(layer_betas)
-            h = h_next
-
         return h
 
-    def fusion_betas(
+    def _head(self, params: Dict, h: Dict[str, jax.Array],
+              rows: Optional[jax.Array] = None) -> jax.Array:
+        """The classifier over the target type's states (its ``rows``)."""
+        with obs.scope(obs.HEAD):
+            head = params["head"]
+            x = h[self.cfg.target_type]
+            if rows is not None:
+                x = x[rows]
+            return x @ head["w"] + head["b"]
+
+    def _per_graph(self, graphs: List, step: Callable) -> Callable:
+        """The ``aggregate`` of :meth:`_layers` that runs ``step(g, inputs)``
+        for each batch under its NA scope."""
+        cfg = self.cfg
+
+        def aggregate(li, lp, hp):
+            zs = []
+            for g in graphs:
+                with obs.scope(obs.na_scope(li, g.metapath)):
+                    zs.append(step(g, na_inputs(cfg, lp, g, hp)))
+            return zs
+
+        return aggregate
+
+    # ------------------------------------------------------------ entries --
+    def hidden_states(
         self,
         params: Dict,
         features: Dict[str, jax.Array],
-        graphs: List[SemanticGraphBatch],
+        graphs: List,
         *,
-        na_executor: str = "jnp",
         kernel_backend: Optional[str] = None,
-    ) -> List[Dict[str, jax.Array]]:
+        betas_out: Optional[List] = None,
+    ) -> Dict[str, jax.Array]:
+        """Run every FP -> NA -> SF layer over the whole graph; returns the
+        final per-type hidden states (global vertex numbering),
+        pre-classifier-head.  The body of :meth:`execute`,
+        :meth:`execute_subset`, :meth:`execute_loss` and
+        :meth:`fusion_betas`.
+
+        The batches pick the NA step:
+          * ``SemanticGraphBatch`` es — ``jax.ops.segment_*`` over global
+            edge lists (the reference);
+          * ``BandedBatch`` es (``FrontendResult.banded_batches()``) — the
+            Pallas NA kernels over the restructurer's cached
+            ``PackedEdges`` blocks; features are permuted per layer into
+            the banded numbering and NA outputs back, so FP/SF and the
+            returned states keep global vertex numbering.
+        A mixed list is a ``TypeError``.  ``kernel_backend`` ("interpret"
+        | "pallas" | None for the platform's, see ``repro.kernels.backend``)
+        only applies to banded batches.
+
+        ``betas_out``, when given an empty list, collects one
+        ``{dst_type: (P_t + 1,)}`` dict of semantic-attention weights per
+        layer (see :meth:`fusion_betas`).
+
+        Both NA steps are differentiable: the banded kernels carry custom
+        VJPs (kernels/seg_sum.py, kernels/ops.py) whose backward gathers
+        through the cached packing, so ``jax.grad`` of a loss built on
+        this apply agrees across batch types, and one cached
+        ``BandedBatch`` list serves every training step.
+        """
+        if _is_banded(graphs):
+            step = functools.partial(_na_banded, backend=resolve_backend(kernel_backend))
+        else:
+            def step(g, x):
+                return _na_edges(x, g.src, g.dst, g.num_dst)
+
+        return self._layers(params, self._inputs(features), graphs,
+                            self._per_graph(graphs, step), betas_out=betas_out)
+
+    def fusion_betas(self, params: Dict, features: Dict[str, jax.Array],
+                     graphs: List, *, kernel_backend: Optional[str] = None
+                     ) -> List[Dict[str, jax.Array]]:
         """Per-layer SF attention weights from one full forward.
 
         Semantic fusion's beta is a mean over *all* rows of a type — a
@@ -411,137 +490,76 @@ class HGNN:
         """
         betas: List[Dict[str, jax.Array]] = []
         self.hidden_states(params, features, graphs,
-                           na_executor=na_executor,
-                           kernel_backend=kernel_backend,
-                           betas_out=betas)
+                           kernel_backend=kernel_backend, betas_out=betas)
         return betas
 
     def execute_dependency_subset(
         self,
         params: Dict,
         features: Dict[str, jax.Array],
-        graphs: List[SemanticGraphBatch],
+        graphs: List,
         dep: Dict,
         betas: List[Dict[str, jax.Array]],
         *,
-        na_executor: str = "jnp",
         kernel_backend: Optional[str] = None,
     ) -> jax.Array:
         """FP -> NA -> SF over an induced k-hop dependency subgraph.
 
         ``dep`` is a ``core.subgraph.DependencySubset.arrays`` pytree for
-        the same graph/executor flavor as ``graphs`` (every array traced,
-        so requests sharing a bucket signature share one jit trace) and
-        ``betas`` the frozen SF weights from :meth:`fusion_betas` under
-        the same params/features.  Rows ``dep["node_rows"][:n]`` of the
-        result match the same target rows of :meth:`execute` to
-        reassociation tolerance: the closure keeps every edge into the
-        hop-``L-1`` frontier, so requested rows aggregate their full
-        receptive field while garbage on deeper-frontier rows only flows
-        into outputs nothing reads.
+        the same batch type as ``graphs`` (every array traced, so requests
+        sharing a bucket signature share one jit trace) and ``betas`` the
+        frozen SF weights from :meth:`fusion_betas` under the same
+        params/features.  Rows ``dep["node_rows"][:n]`` of the result
+        match the same target rows of :meth:`execute` to reassociation
+        tolerance: the closure keeps every edge into the hop-``L-1``
+        frontier, so requested rows aggregate their full receptive field
+        while garbage on deeper-frontier rows only flows into outputs
+        nothing reads.
         """
-        from repro.core.subgraph import (na_attention_subset_banded,
-                                         na_mean_subset_banded)
-
-        cfg = self.cfg
-        if na_executor not in ("jnp", "banded"):
-            raise ValueError(f"unknown na_executor {na_executor!r}")
-        banded = na_executor == "banded"
-        if banded:
-            kernel_backend = resolve_backend(kernel_backend)
         gather = dep["gather"]
-        h: Dict[str, jax.Array] = {}
-        for t in self.num_vertices:
-            rows = gather[t]
-            if self.feature_dims.get(t, 0) > 0:
-                with obs.scope(obs.fp_scope(0, t)):  # the input rows of FP
-                    h[t] = features[t][rows]
-            else:
-                h[t] = jnp.ones((rows.shape[0], 1), jnp.float32)
+        dg_of = dict(zip((g.metapath for g in graphs), dep["graphs"]))
+        if _is_banded(graphs):
+            backend = resolve_backend(kernel_backend)
 
-        for li, lp in enumerate(params["layers"]):
-            hp = {}
-            for t, x in h.items():
-                with obs.scope(obs.fp_scope(li, t)):
-                    hp[t] = jax.nn.relu(feature_projection(
-                        lp["fp"][t]["w"], lp["fp"][t]["b"], x))
-            z_by_dst: Dict[str, List[jax.Array]] = {}
-            for g, dg in zip(graphs, dep["graphs"]):
-                with obs.scope(obs.na_scope(li, g.metapath)):
-                    na_p = lp["na"][g.metapath]
-                    h_src = hp[g.src_type] @ na_p["w_rel"]
-                    edge_bias = None
-                    if cfg.model == "shgn":
-                        edge_bias = lp["edge_emb"][g.edge_type_id] @ lp["a_edge"]
-                    if banded:
-                        if cfg.model == "rgcn":
-                            z = na_mean_subset_banded(
-                                g.packed, dg, h_src, backend=kernel_backend)
-                        else:
-                            z = na_attention_subset_banded(
-                                g.packed, dg, h_src, hp[g.dst_type],
-                                na_p["a_src"], na_p["a_dst"],
-                                edge_bias=edge_bias, backend=kernel_backend)
-                    elif cfg.model == "rgcn":
-                        z = na_mean(h_src, dg["src"], dg["dst"],
-                                    gather[g.dst_type].shape[0])
-                    else:
-                        z = na_attention(
-                            h_src, hp[g.dst_type], dg["src"], dg["dst"],
-                            gather[g.dst_type].shape[0],
-                            na_p["a_src"], na_p["a_dst"], edge_bias=edge_bias)
-                z_by_dst.setdefault(g.dst_type, []).append(z)
-            h_next: Dict[str, jax.Array] = {}
-            for t, x in hp.items():
-                with obs.scope(obs.sf_scope(li, t)):
-                    sf = lp["sf"][t]
-                    self_z = x @ sf["w_self"]
-                    if t in z_by_dst:
-                        stack = jnp.stack(z_by_dst[t] + [self_z])
-                        h_next[t] = jax.nn.relu(
-                            jnp.einsum("p,pnd->nd", betas[li][t], stack))
-                    else:
-                        h_next[t] = jax.nn.relu(self_z)
-            h = h_next
+            def step(g, x):
+                return na_subset_banded(g.packed, dg_of[g.metapath], x,
+                                        backend=backend)
+        else:
+            def step(g, x):
+                dg = dg_of[g.metapath]
+                return _na_edges(x, dg["src"], dg["dst"],
+                                 gather[g.dst_type].shape[0])
 
-        with obs.scope(obs.HEAD):
-            head = params["head"]
-            rows = h[cfg.target_type][dep["node_rows"]]
-            return rows @ head["w"] + head["b"]
+        h = self._layers(params, self._inputs(features, gather), graphs,
+                         self._per_graph(graphs, step), betas=betas)
+        return self._head(params, h, dep["node_rows"])
 
     def execute(
         self,
         params: Dict,
         features: Dict[str, jax.Array],
-        graphs: List[SemanticGraphBatch],
+        graphs: List,
         *,
-        na_executor: str = "jnp",
         kernel_backend: Optional[str] = None,
     ) -> jax.Array:
         """Full GFP stage; returns logits for ``cfg.target_type`` vertices.
 
-        This is the executor-dispatching implementation behind
-        ``repro.api.CompiledHGNN.forward`` — callers should compile
-        through a ``repro.api.Session``, which binds the batch flavor and
-        these kwargs once from an ``ExecutorSpec``.  See :meth:`hidden_states`
-        for the executor semantics (``na_executor``/``kernel_backend``)
-        and differentiability notes shared with :meth:`execute_subset`.
+        The implementation behind ``repro.api.CompiledHGNN.forward`` —
+        callers should compile through a ``repro.api.Session``, which
+        builds the batches an ``ExecutorSpec`` names.  See
+        :meth:`hidden_states` for how the batches pick the NA step.
         """
         h = self.hidden_states(params, features, graphs,
-                               na_executor=na_executor,
                                kernel_backend=kernel_backend)
-        with obs.scope(obs.HEAD):
-            head = params["head"]
-            return h[self.cfg.target_type] @ head["w"] + head["b"]
+        return self._head(params, h)
 
     def execute_subset(
         self,
         params: Dict,
         features: Dict[str, jax.Array],
-        graphs: List[SemanticGraphBatch],
+        graphs: List,
         node_ids: jax.Array,
         *,
-        na_executor: str = "jnp",
         kernel_backend: Optional[str] = None,
     ) -> jax.Array:
         """Logits for an explicit subset of ``cfg.target_type`` vertices.
@@ -557,23 +575,17 @@ class HGNN:
         :meth:`execute` under the same trace.
         """
         h = self.hidden_states(params, features, graphs,
-                               na_executor=na_executor,
                                kernel_backend=kernel_backend)
-        with obs.scope(obs.HEAD):
-            head = params["head"]
-            rows = h[self.cfg.target_type][node_ids]
-            return rows @ head["w"] + head["b"]
+        return self._head(params, h, node_ids)
 
     def execute_loss(self, params, features, graphs, labels: jax.Array,
                      mask: Optional[jax.Array] = None, *,
-                     na_executor: str = "jnp",
                      kernel_backend: Optional[str] = None) -> jax.Array:
         """Masked cross-entropy over ``cfg.target_type`` vertices
-        (semi-supervised node classification).  Differentiable on both NA
-        executors: ``jax.grad`` of this loss on the banded executor
-        matches the jnp executor's gradients to float tolerance."""
+        (semi-supervised node classification).  Differentiable on both
+        batch types: ``jax.grad`` of this loss over banded batches
+        matches its gradients over edge-list batches to float tolerance."""
         logits = self.execute(params, features, graphs,
-                              na_executor=na_executor,
                               kernel_backend=kernel_backend)
         logp = jax.nn.log_softmax(logits)
         nll = -jnp.take_along_axis(logp, labels[:, None], axis=1)[:, 0]
@@ -629,16 +641,3 @@ def graphs_from_sgb(
     del graph  # packaging depends only on the semantic graphs
     return package_batches(semantic, targets, restructured=restructured,
                            restructured_graphs=restructured_graphs)
-
-
-def graphs_from_pipeline(result) -> List[SemanticGraphBatch]:
-    """Batches from a ``pipeline.FrontendResult`` — built once on the
-    result and shared by every model (multi-model scenario)."""
-    return result.batches()
-
-
-def banded_graphs_from_pipeline(result) -> List[BandedBatch]:
-    """Banded batches from a ``pipeline.FrontendResult`` for the banded
-    NA executor — one ``PackedEdges`` per semantic graph, shared by every
-    model and layer."""
-    return result.banded_batches()

@@ -444,62 +444,47 @@ class DependencyExtractor:
 
 
 # ------------------------------------------------------- banded NA compute --
-def na_mean_subset_banded(packed, dg: Dict, h_src: jax.Array,
-                          backend: Optional[str] = None) -> jax.Array:
-    """RGCN-style NA over one sliced banded graph (closure-local in/out).
+def na_subset_banded(packed, dg: Dict, x, leaky_slope: float = 0.2,
+                     backend: Optional[str] = None) -> jax.Array:
+    """NA over one sliced banded graph (closure-local in and out), from
+    the inputs ``x`` of ``core.hgnn.models.na_inputs``.
 
     The blocked arrays are *traced* operands of ``_seg_sum_call`` (only
     the tile geometry is static), so every extraction whose slice lands
-    in the same buckets reuses one kernel trace.  Degrees come from the
-    sliced valid-edge map and are exact for every row the pick reads.
+    in the same buckets reuses one kernel trace.  The mean divides by
+    degrees from the sliced valid-edge map, exact for every row the pick
+    reads.  Attention runs its edge softmax as jnp segment stats over the
+    sliced flat edge map (this is a no-backward serving path) and scatters
+    alpha into the blocked layout: pad edges are masked to ``-1e30``
+    before the stats and their alpha is zeroed, and the scatter *adds* so
+    pad slots (all aliased to (pad block, 0)) can never clobber a real
+    weight.
     """
     td, sb = packed.dst_tile_rows, packed.src_band
-    hb = h_src[dg["src_rows"]]
-    num_tiles = dg["dst_rows"].shape[0] // td
-    out = _seg_sum_call(
-        dg["band"], dg["dtile"], dg["first"], dg["srcl"], dg["dstl"],
-        dg["weight"], hb, num_dst_tiles=num_tiles, src_band=sb,
-        dst_tile_rows=td, interpret=use_interpret(backend))
-    deg = jnp.zeros((num_tiles * td,), jnp.float32).at[dg["e_dst"]].add(
-        dg["e_valid"])
-    z = out / jnp.maximum(deg, 1.0)[:, None]
-    return z[dg["dst_pick"]] * dg["pick_valid"][:, None]
-
-
-def na_attention_subset_banded(packed, dg: Dict, h_src: jax.Array,
-                               h_dst: jax.Array, a_src: jax.Array,
-                               a_dst: jax.Array,
-                               edge_bias: Optional[jax.Array] = None,
-                               leaky_slope: float = 0.2,
-                               backend: Optional[str] = None) -> jax.Array:
-    """GAT-style NA over one sliced banded graph.
-
-    Edge softmax runs as jnp segment stats over the sliced flat edge map
-    (this is a no-backward serving path); the alpha-weighted aggregation
-    reuses the blocked Pallas kernel with alpha scattered into the
-    blocked layout.  Pad edges are masked to ``-1e30`` before the stats
-    and their alpha is zeroed, and the scatter *adds* so pad slots (all
-    aliased to (pad block, 0)) can never clobber a real weight.
-    """
-    td, sb = packed.dst_tile_rows, packed.src_band
-    hb = h_src[dg["src_rows"]]
-    hd = h_dst[dg["dst_rows"]]
+    hb = x.h_src[dg["src_rows"]]
     num_tiles = dg["dst_rows"].shape[0] // td
     num_rows = num_tiles * td
-    logits = (hb @ a_src)[dg["e_src"]] + (hd @ a_dst)[dg["e_dst"]]
-    if edge_bias is not None:
-        logits = logits + edge_bias
-    logits = jax.nn.leaky_relu(logits, leaky_slope)
-    logits = jnp.where(dg["e_valid"] > 0, logits, -1e30)
-    m = jax.ops.segment_max(logits, dg["e_dst"], num_segments=num_rows)
-    m = jnp.where(jnp.isfinite(m), m, 0.0)
-    ex = jnp.exp(logits - m[dg["e_dst"]])
-    s = jax.ops.segment_sum(ex, dg["e_dst"], num_segments=num_rows)
-    alpha = ex / jnp.maximum(s[dg["e_dst"]], 1e-9) * dg["e_valid"]
-    wblk = jnp.zeros(dg["srcl"].shape, jnp.float32).at[
-        dg["e_blk"], dg["e_slot"]].add(alpha)
+    weight = dg["weight"]
+    if x.attention:
+        hd = x.h_dst[dg["dst_rows"]]
+        logits = (hb @ x.a_src)[dg["e_src"]] + (hd @ x.a_dst)[dg["e_dst"]]
+        if x.bias is not None:
+            logits = logits + x.bias
+        logits = jax.nn.leaky_relu(logits, leaky_slope)
+        logits = jnp.where(dg["e_valid"] > 0, logits, -1e30)
+        m = jax.ops.segment_max(logits, dg["e_dst"], num_segments=num_rows)
+        m = jnp.where(jnp.isfinite(m), m, 0.0)
+        ex = jnp.exp(logits - m[dg["e_dst"]])
+        s = jax.ops.segment_sum(ex, dg["e_dst"], num_segments=num_rows)
+        alpha = ex / jnp.maximum(s[dg["e_dst"]], 1e-9) * dg["e_valid"]
+        weight = jnp.zeros(dg["srcl"].shape, jnp.float32).at[
+            dg["e_blk"], dg["e_slot"]].add(alpha)
     out = _seg_sum_call(
         dg["band"], dg["dtile"], dg["first"], dg["srcl"], dg["dstl"],
-        wblk, hb, num_dst_tiles=num_tiles, src_band=sb,
+        weight, hb, num_dst_tiles=num_tiles, src_band=sb,
         dst_tile_rows=td, interpret=use_interpret(backend))
+    if not x.attention:
+        deg = jnp.zeros((num_rows,), jnp.float32).at[dg["e_dst"]].add(
+            dg["e_valid"])
+        out = out / jnp.maximum(deg, 1.0)[:, None]
     return out[dg["dst_pick"]] * dg["pick_valid"][:, None]

@@ -26,9 +26,11 @@ space, so the unmodified single-device kernels
 stream directly.  Because a dst tile lives wholly on one device, each
 device's NA output rows are exact (not partial) for the tiles it owns;
 one ``psum`` over the mesh then materializes every relation's full NA
-output on every device — the semantic-fusion all-gather point — and FP
-/ SF / head run replicated, returning logits identical (to fp
-tolerance) to the single-device banded forward.
+output on every device — the semantic-fusion all-gather point.  This
+merged step is the executor's only NA code: it is the ``aggregate`` of
+the model's one layer loop (``HGNN._layers``), which runs FP / SF / head
+replicated, so logits match the single-device banded forward to fp
+tolerance.
 
 Wire-up lives in ``repro.api``: ``ExecutorSpec(shard=..., mesh_shape=...)``
 declares the mode, ``Session.compile`` builds and caches the plan by
@@ -39,6 +41,7 @@ hosts via ``XLA_FLAGS=--xla_force_host_platform_device_count=N``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import threading
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -49,8 +52,7 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro import obs
-from repro.core.hgnn.layers import feature_projection, semantic_fusion_beta
-from repro.core.hgnn.models import HGNN, BandedBatch
+from repro.core.hgnn.models import HGNN, BandedBatch, na_inputs
 from repro.kernels.backend import use_interpret
 from repro.kernels.edge_softmax import edge_softmax_stats_blocks
 from repro.kernels.seg_sum import seg_sum_blocks, shard_blocked
@@ -336,12 +338,13 @@ class ShardedHGNNExecutor:
     """``shard_map``-based banded forward bound to one :class:`ShardPlan`.
 
     Holds the per-device stacked block streams (host-built once) and a
-    lazily-jitted forward whose body runs the full FP -> NA -> SF layer
-    loop under ``shard_map``: NA kernels consume each device's stream,
-    one ``psum`` per layer rematerializes full NA outputs (the SF
-    all-gather point), and the replicated FP/SF/head keep logits
-    identical to the single-device banded forward.  ``traces`` counts
-    jit traces — the serving no-retrace guard.
+    lazily-jitted forward whose body is the model's one layer loop
+    (``HGNN._layers``) with this executor's NA step: the per-relation
+    terms from ``core.hgnn.models.na_inputs``, one merged NA kernel pair
+    over each device's stream, and one ``psum`` per layer that
+    rematerializes full NA outputs (the SF all-gather point); FP, SF and
+    the head run replicated.  ``traces`` counts jit traces — the serving
+    no-retrace guard.
     """
 
     def __init__(
@@ -393,7 +396,7 @@ class ShardedHGNNExecutor:
     def forward(self, params: Dict, features: Dict[str, jax.Array]) -> jax.Array:
         """Logits for every target vertex, executed over the mesh.
 
-        Matches ``HGNN.execute(..., na_executor="banded")`` on one
+        Matches ``HGNN.execute`` over the same banded batches on one
         device to fp tolerance; repeated calls reuse one jit trace.
         """
         if self._fn is None:
@@ -408,135 +411,88 @@ class ShardedHGNNExecutor:
         return self._fn.lower(params, features, self._blocks)
 
     # ------------------------------------------------------------ builder --
-    def _na_weights(self, cfg, blk, e_src_segs, e_dst_segs):
-        """Per-slot aggregation weights for this device's stream.
+    def _aggregate(self, blk, li, lp, hp):
+        """One layer's NA outputs on this device's stream ``blk``, in
+        graph order (the ``aggregate`` of ``HGNN._layers``).
 
-        rgcn uses the packing weights directly; attention models compute
-        blocked logits by gathering the concatenated per-row logit
-        terms, run the online stats kernel over the device's stream, and
-        resolve alpha in place — exact per destination because every dst
+        Each relation's banded features (and, for attention, its per-row
+        logit terms) are padded into the shared band/tile space.  One
+        seg-sum kernel serves every relation: with the packing weights
+        for the mean, or with alpha, which the online stats kernel
+        resolves in place over blocked logits gathered from the
+        concatenated terms — exact per destination because every dst
         tile's edges are device-local.
         """
-        geom = self.geometry
-        td = geom.dst_tile_rows
-        if cfg.model == "rgcn":
-            return blk["weight"]
-        e_s = jnp.concatenate(e_src_segs)
-        e_d = jnp.concatenate(e_dst_segs + [jnp.zeros((td,), jnp.float32)])
-        lb = e_s[blk["src_id"]] + e_d[blk["dst_id"]]
-        lb = jax.nn.leaky_relu(lb, 0.2)
-        lb = jnp.where(blk["valid"] > 0, lb, _NEG)
-        m, s = edge_softmax_stats_blocks(
-            blk["dst_tile"],
-            blk["first"],
-            lb,
-            blk["dst_local"],
-            blk["valid"],
-            num_dst_tiles=geom.total_tiles + 1,
-            dst_tile_rows=td,
-            interpret=self.interpret,
-        )
-        m_flat, s_flat = m.reshape(-1), s.reshape(-1)
-        alpha = jnp.exp(lb - m_flat[blk["dst_id"]]) / jnp.maximum(s_flat[blk["dst_id"]], 1e-9)
-        return alpha * blk["valid"]
+        geom, graphs, cfg = self.geometry, self.graphs, self.model.cfg
+        sb, td = geom.src_band, geom.dst_tile_rows
+        feat_segs, e_src_segs, e_dst_segs = [], [], []
+        attention = False
+        for r, g in enumerate(graphs):
+            with obs.scope(obs.na_scope(li, g.metapath)):
+                x = na_inputs(cfg, lp, g, hp)
+                attention = x.attention
+                hb = x.h_src[g.src_gather]
+                row_pad = geom.seg_bands[r] * sb - hb.shape[0]
+                feat_segs.append(jnp.pad(hb, ((0, row_pad), (0, 0))))
+                if attention:
+                    e_src_segs.append(jnp.pad(hb @ x.a_src, (0, row_pad)))
+                    e_d = x.h_dst[g.dst_gather] @ x.a_dst
+                    if x.bias is not None:
+                        # the per-relation scalar bias folds into the
+                        # dst-side term: dst rows are relation-exclusive
+                        e_d = e_d + x.bias
+                    e_dst_segs.append(
+                        jnp.pad(e_d, (0, geom.seg_tiles[r] * td - e_d.shape[0])))
+        # one stats + seg-sum kernel pair serves every relation
+        with obs.scope(obs.na_scope(li, "+".join(g.metapath for g in graphs))):
+            h_cat = jnp.concatenate(feat_segs, axis=0)
+            w = blk["weight"]
+            if attention:
+                e_s = jnp.concatenate(e_src_segs)
+                e_d = jnp.concatenate(e_dst_segs + [jnp.zeros((td,), jnp.float32)])
+                lb = jax.nn.leaky_relu(e_s[blk["src_id"]] + e_d[blk["dst_id"]], 0.2)
+                lb = jnp.where(blk["valid"] > 0, lb, _NEG)
+                m, s = edge_softmax_stats_blocks(
+                    blk["dst_tile"], blk["first"], lb, blk["dst_local"], blk["valid"],
+                    num_dst_tiles=geom.total_tiles + 1, dst_tile_rows=td,
+                    interpret=self.interpret)
+                m_flat, s_flat = m.reshape(-1), s.reshape(-1)
+                w = (jnp.exp(lb - m_flat[blk["dst_id"]])
+                     / jnp.maximum(s_flat[blk["dst_id"]], 1e-9)) * blk["valid"]
+            out = seg_sum_blocks(
+                blk["band"], blk["dst_tile"], blk["first"], blk["src_local"],
+                blk["dst_local"], w, h_cat, num_dst_tiles=geom.total_tiles + 1,
+                src_band=sb, dst_tile_rows=td, interpret=self.interpret)
+            # zero rows of tiles this device never touches (their owners
+            # contribute them), then sum exact per-tile results across the
+            # mesh: the semantic-fusion all-gather point
+            touched = jnp.zeros((geom.total_tiles + 1,), jnp.float32)
+            touched = touched.at[blk["dst_tile"]].max((blk["count"] > 0).astype(jnp.float32))
+            rmask = jnp.repeat(touched[: geom.total_tiles] > 0, td)
+            z_all = jnp.where(rmask[:, None], out[: geom.total_tiles * td], 0.0)
+            z_all = jax.lax.psum(z_all, _AXIS)
+        zs = []
+        for r, g in enumerate(graphs):
+            with obs.scope(obs.na_scope(li, g.metapath)):
+                lo = geom.tile_offsets[r] * td
+                zb = z_all[lo : lo + g.num_dst]
+                if not attention:
+                    zb = zb / jnp.maximum(g.deg, 1.0)[:, None]
+                zs.append(zb[g.dst_scatter])
+        return zs
 
     def _build_forward(self):
         """Jit the shard_map'd layer loop (one trace, counted)."""
-        model, graphs, geom = self.model, self.graphs, self.geometry
-        cfg = model.cfg
-        sb, td = geom.src_band, geom.dst_tile_rows
-        interpret = self.interpret
+        model = self.model
 
         def body(params, features, blocks):
             blk = {k: v[0] for k, v in blocks.items()}  # this device's shard
-            h: Dict[str, jax.Array] = {}
-            for t, n in model.num_vertices.items():
-                if model.feature_dims.get(t, 0) > 0:
-                    h[t] = features[t]
-                else:
-                    h[t] = jnp.ones((n, 1), jnp.float32)
-            merged = "+".join(g.metapath for g in graphs)
-            for li, lp in enumerate(params["layers"]):
-                hp = {}
-                for t, x in h.items():
-                    with obs.scope(obs.fp_scope(li, t)):
-                        hp[t] = jax.nn.relu(
-                            feature_projection(lp["fp"][t]["w"], lp["fp"][t]["b"], x)
-                        )
-                # banded per-relation features into the shared band space
-                feat_segs, e_src_segs, e_dst_segs = [], [], []
-                for r, g in enumerate(graphs):
-                    with obs.scope(obs.na_scope(li, g.metapath)):
-                        na_p = lp["na"][g.metapath]
-                        hb = (hp[g.src_type] @ na_p["w_rel"])[g.src_gather]
-                        row_pad = geom.seg_bands[r] * sb - hb.shape[0]
-                        feat_segs.append(jnp.pad(hb, ((0, row_pad), (0, 0))))
-                        if cfg.model != "rgcn":
-                            e_s = hb @ na_p["a_src"]
-                            e_src_segs.append(jnp.pad(e_s, (0, row_pad)))
-                            e_d = hp[g.dst_type][g.dst_gather] @ na_p["a_dst"]
-                            if cfg.model == "shgn":
-                                # the per-relation scalar bias folds into the
-                                # dst-side term: dst rows are relation-exclusive
-                                e_d = e_d + (lp["edge_emb"][g.edge_type_id] @ lp["a_edge"])
-                            e_dst_segs.append(
-                                jnp.pad(e_d, (0, geom.seg_tiles[r] * td - e_d.shape[0]))
-                            )
-                # one stats + seg-sum kernel pair serves every relation
-                with obs.scope(obs.na_scope(li, merged)):
-                    h_cat = jnp.concatenate(feat_segs, axis=0)
-                    w = self._na_weights(cfg, blk, e_src_segs, e_dst_segs)
-                    out = seg_sum_blocks(
-                        blk["band"],
-                        blk["dst_tile"],
-                        blk["first"],
-                        blk["src_local"],
-                        blk["dst_local"],
-                        w,
-                        h_cat,
-                        num_dst_tiles=geom.total_tiles + 1,
-                        src_band=sb,
-                        dst_tile_rows=td,
-                        interpret=interpret,
-                    )
-                    # zero rows of tiles this device never touches (their
-                    # owners contribute them), then sum exact per-tile
-                    # results across the mesh: the semantic-fusion
-                    # all-gather point
-                    touched = jnp.zeros((geom.total_tiles + 1,), jnp.float32)
-                    touched = touched.at[blk["dst_tile"]].max(
-                        (blk["count"] > 0).astype(jnp.float32)
-                    )
-                    rmask = jnp.repeat(touched[: geom.total_tiles] > 0, td)
-                    z_all = jnp.where(rmask[:, None], out[: geom.total_tiles * td], 0.0)
-                    z_all = jax.lax.psum(z_all, _AXIS)
-                z_by_dst: Dict[str, List[jax.Array]] = {}
-                for r, g in enumerate(graphs):
-                    with obs.scope(obs.na_scope(li, g.metapath)):
-                        lo = geom.tile_offsets[r] * td
-                        zb = z_all[lo : lo + g.num_dst]
-                        if cfg.model == "rgcn":
-                            zb = zb / jnp.maximum(g.deg, 1.0)[:, None]
-                        z_by_dst.setdefault(g.dst_type, []).append(zb[g.dst_scatter])
-                h_next: Dict[str, jax.Array] = {}
-                for t, x in hp.items():
-                    with obs.scope(obs.sf_scope(li, t)):
-                        sf = lp["sf"][t]
-                        self_z = x @ sf["w_self"]
-                        if t in z_by_dst:
-                            stack = jnp.stack(z_by_dst[t] + [self_z])
-                            beta = semantic_fusion_beta(stack, sf["w"], sf["b"], sf["q"])
-                            h_next[t] = jax.nn.relu(jnp.einsum("p,pnd->nd", beta, stack))
-                        else:
-                            h_next[t] = jax.nn.relu(self_z)
-                h = h_next
-            with obs.scope(obs.HEAD):
-                head = params["head"]
-                logits = h[cfg.target_type] @ head["w"] + head["b"]
+            h = model._layers(params, model._inputs(features), self.graphs,
+                              functools.partial(self._aggregate, blk))
             # replicated result; a broadcast leading axis satisfies the
             # check_vma=False requirement that out_specs mention the mesh
             # axis (the caller reads shard 0)
-            return logits[None]
+            return model._head(params, h)[None]
 
         sharded = jax.shard_map(
             body,

@@ -254,8 +254,6 @@ def na_attention_packed(
     packed: PackedEdges,
     edge_logits: jax.Array,  # (E,) logits in the packing's scheduled order
     h: jax.Array,  # (N_src, D) features in the packing's src numbering
-    dst: Optional[jax.Array] = None,  # kept for API compat; the packing's
-    # own edge map is authoritative for per-edge destination ids
     backend: Optional[str] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Device-resident fused attention NA over a cached packing.
@@ -270,7 +268,6 @@ def na_attention_packed(
     lives in ``na_attention_aggregate``.
     """
     assert backend != "jnp", "na_attention_packed is the kernel path"
-    del dst  # derived from the packing (identical by construction)
     fn = attention_packed_vjp(packed, use_interpret(backend))
     return fn(jnp.asarray(edge_logits, jnp.float32), h)
 
@@ -297,7 +294,7 @@ def na_attention_aggregate(
         return out, alpha
     if packed is None:
         packed = pack_edge_blocks(src, dst, int(h.shape[0]), num_dst)
-    return na_attention_packed(packed, edge_logits, h, dst, backend=backend)
+    return na_attention_packed(packed, edge_logits, h, backend=backend)
 
 
 def compose_boolean(

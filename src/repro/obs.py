@@ -1,10 +1,9 @@
 """What the compiled HGNN tells an observer: scope names, live models, and
 the map from a compiled forward's device ops to those scopes.
 
-Every HGNN layer loop (``HGNN.hidden_states``,
-``HGNN.execute_dependency_subset`` and the sharded body in
-``repro.distributed.hgnn``) names its sub-stages with ``jax.named_scope``
-in one grammar, built only by the helpers here:
+The one HGNN layer loop (``HGNN._layers``, which the full-graph,
+dependency-subset and sharded paths all run) names its sub-stages with
+``jax.named_scope`` in one grammar, built only by the helpers here:
 
   * ``layer<i>/fp/<vertex type>`` — feature projection and its ReLU;
   * ``layer<i>/na/<metapath>``    — everything neighbour aggregation does

@@ -1,12 +1,11 @@
 """Jitted semi-supervised HGNN training on either NA executor.
 
-The banded executor became differentiable in kernels/seg_sum.py and
-kernels/ops.py (custom VJPs over the cached ``PackedEdges``), so the same
-train step runs on the jnp executor (segment-sum oracle) or the banded
-executor (Pallas NA kernels) — pick one by threading a
-``repro.api.ExecutorSpec`` through ``executor=`` (what
-``CompiledHGNN.fit`` does) or via the legacy ``na_backend`` string
-kwargs.  Semantic-graph batches are
+The banded NA kernels are differentiable (custom VJPs over the cached
+``PackedEdges``, kernels/seg_sum.py and kernels/ops.py), so the same
+train step runs over edge-list batches (the segment-sum reference) or
+banded batches (the Pallas NA kernels): the batches pick the NA step
+(``HGNN.hidden_states``), and ``kernel_backend`` is the one kernel
+choice.  Semantic-graph batches are
 closed over by the step function — they are host-side packings, not
 pytrees — and because every VJP closure is memoized on its packing, a
 jitted step retraces nothing across steps: one ``BandedBatch`` list
@@ -33,17 +32,6 @@ from repro.train.optim import (
     clip_by_global_norm,
     warmup_cosine,
 )
-
-
-def _resolve_executor(
-    executor: Optional[Any], na_backend: str, kernel_backend: Optional[str]
-) -> Tuple[str, Optional[str]]:
-    """An executor spec (``repro.api.ExecutorSpec``, duck-typed so this
-    module stays import-independent of the api layer) wins over the
-    legacy string kwargs."""
-    if executor is not None:
-        return executor.na_executor, executor.kernel_backend
-    return na_backend, kernel_backend
 
 
 @jax.tree_util.register_dataclass
@@ -154,33 +142,17 @@ def make_train_step(
     total: int = 200,
     weight_decay: float = 0.0,
     clip_norm: Optional[float] = None,
-    na_backend: str = "jnp",
     kernel_backend: Optional[str] = None,
-    executor: Optional[Any] = None,
 ) -> Callable[..., Tuple[HGNNTrainState, jax.Array]]:
     """Build the jitted train step ``(state, features, labels, mask) ->
-    (state, loss)`` for one (model, graphs, executor) combination.
-
-    ``executor`` — anything with ``na_executor``/``kernel_backend``
-    attributes, i.e. a ``repro.api.ExecutorSpec`` — overrides the two
-    string kwargs; ``repro.api.CompiledHGNN.fit`` threads the session's
-    spec through it so compiled models train with no backend strings.
-    ``graphs`` must match the executor (``SemanticGraphBatch`` for
-    "jnp", ``BandedBatch`` for "banded") — ``HGNN.execute`` validates.
-    """
-    na_backend, kernel_backend = _resolve_executor(executor, na_backend, kernel_backend)
+    (state, loss)`` for one (model, graphs) pair; the batches pick the NA
+    step (all ``SemanticGraphBatch`` or all ``BandedBatch``)."""
     lr_fn = warmup_cosine(lr, warmup=warmup, total=total)
 
     def step(state: HGNNTrainState, features, labels, mask):
         def loss_fn(p):
             return model.execute_loss(
-                p,
-                features,
-                graphs,
-                labels,
-                mask=mask,
-                na_executor=na_backend,
-                kernel_backend=kernel_backend,
+                p, features, graphs, labels, mask=mask, kernel_backend=kernel_backend
             )
 
         loss, grads = jax.value_and_grad(loss_fn)(state.params)
@@ -202,21 +174,12 @@ def make_eval_fn(
     model,
     graphs: List[Any],
     *,
-    na_backend: str = "jnp",
     kernel_backend: Optional[str] = None,
-    executor: Optional[Any] = None,
 ) -> Callable[..., jax.Array]:
     """Jitted masked accuracy ``(params, features, labels, mask) -> ()``."""
-    na_backend, kernel_backend = _resolve_executor(executor, na_backend, kernel_backend)
 
     def accuracy(params, features, labels, mask):
-        logits = model.execute(
-            params,
-            features,
-            graphs,
-            na_executor=na_backend,
-            kernel_backend=kernel_backend,
-        )
+        logits = model.execute(params, features, graphs, kernel_backend=kernel_backend)
         hit = (logits.argmax(-1) == labels).astype(jnp.float32)
         return jnp.sum(hit * mask) / jnp.maximum(mask.sum(), 1.0)
 
@@ -234,9 +197,7 @@ def fit(
     seed: int = 0,
     lr: float = 3e-3,
     weight_decay: float = 0.0,
-    na_backend: str = "jnp",
     kernel_backend: Optional[str] = None,
-    executor: Optional[Any] = None,
     epoch_callback: Optional[Callable[[int, float], None]] = None,
     ckpt_dir: Optional[str] = None,
     ckpt_every: int = 1,
@@ -247,8 +208,8 @@ def fit(
     setting).  ``epoch_callback(epoch, loss)`` lets callers time or log
     per-epoch without re-implementing the loop (``benchmarks/train_bench``
     uses it for the latency trajectory).  Prefer reaching this through
-    ``repro.api.CompiledHGNN.fit``, which binds ``executor`` to the
-    session's spec.
+    ``repro.api.CompiledHGNN.fit``, which passes the session's batches
+    and kernel backend.
 
     ``ckpt_dir`` enables fault-tolerant training through
     ``repro.train.checkpoint.CheckpointManager``: train state (params +
@@ -259,7 +220,6 @@ def fit(
     history is carried in the checkpoint, so the returned ``losses``
     covers every epoch regardless of how many times the loop restarted.
     """
-    na_backend, kernel_backend = _resolve_executor(executor, na_backend, kernel_backend)
     state = init_train_state(model, jax.random.key(seed))
     ckpt = None
     start_epoch = 0
@@ -282,15 +242,9 @@ def fit(
         warmup=max(1, epochs // 10),
         total=epochs,
         weight_decay=weight_decay,
-        na_backend=na_backend,
         kernel_backend=kernel_backend,
     )
-    acc_fn = make_eval_fn(
-        model,
-        graphs,
-        na_backend=na_backend,
-        kernel_backend=kernel_backend,
-    )
+    acc_fn = make_eval_fn(model, graphs, kernel_backend=kernel_backend)
     for epoch in range(start_epoch, epochs):
         state, loss = step(state, features, labels, masks["train"])
         losses.append(float(loss))

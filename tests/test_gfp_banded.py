@@ -60,8 +60,7 @@ def test_banded_matches_jnp(frontends, ds, model):
     m = HGNN(cfg, graph.feature_dims, graph.num_vertices, sorted(targets))
     params = m.init(jax.random.key(0))
     logits_jnp = m.execute(params, feats, res.batches())
-    logits_banded = m.execute(params, feats, res.banded_batches(),
-                              na_executor="banded")
+    logits_banded = m.execute(params, feats, res.banded_batches())
     assert not jnp.isnan(logits_banded).any()
     np.testing.assert_allclose(np.asarray(logits_jnp),
                                np.asarray(logits_banded), atol=1e-4)
@@ -93,8 +92,8 @@ def test_packed_built_once_and_shared(frontends):
                              num_classes=3, target_type=target_type)
             m = HGNN(cfg, graph.feature_dims, graph.num_vertices,
                      sorted(targets))
-            m.execute(m.init(jax.random.key(1)), feats, banded,
-                      na_executor="banded").block_until_ready()
+            m.execute(m.init(jax.random.key(1)), feats,
+                      banded).block_until_ready()
     finally:
         seg_sum_mod.pack_edge_blocks = orig
 
@@ -107,12 +106,14 @@ def test_execute_rejects_mismatched_batches(frontends):
                      target_type=target_type)
     m = HGNN(cfg, graph.feature_dims, graph.num_vertices, sorted(targets))
     params = m.init(jax.random.key(0))
+    # the batches pick the NA step, so they must agree on one
+    edges, banded = res.batches(), res.banded_batches()
     with pytest.raises(TypeError):
-        m.execute(params, feats, res.batches(), na_executor="banded")
+        m.execute(params, feats, edges[:1] + banded[1:])
+    with pytest.raises(TypeError):  # not a batch at all
+        m.execute(params, feats, [res.semantic[b.metapath] for b in banded])
     with pytest.raises(TypeError):
-        m.execute(params, feats, res.banded_batches())
-    with pytest.raises(ValueError):
-        m.execute(params, feats, res.batches(), na_executor="spam")
+        m.execute(params, feats, [banded[0], edges[1]])
 
 
 def test_banded_batches_need_restructure(acm_small):
@@ -267,7 +268,7 @@ def test_grouped_packing_with_revisit_matches_reference():
         np.asarray(out), np.asarray(ref.seg_sum_na_ref(src, dst, h, nd)),
         atol=1e-5)
     logits = (RNG.standard_normal(src.size) * 2).astype(np.float32)
-    out_a, alpha = ops.na_attention_packed(packed, logits, h, dst,
+    out_a, alpha = ops.na_attention_packed(packed, logits, h,
                                            backend="interpret")
     want_a, alpha_ref = ops.na_attention_aggregate(src, dst, logits, h, nd,
                                                    backend="jnp")
@@ -321,7 +322,7 @@ def test_first_in_tile_survives_nonconsecutive_revisit():
 
     # the attention stats accumulate across the revisit too
     logits = (RNG.standard_normal(src.size) * 2).astype(np.float32)
-    out_a, alpha = ops.na_attention_packed(packed, logits, h, dst,
+    out_a, alpha = ops.na_attention_packed(packed, logits, h,
                                            backend="interpret")
     want_a, alpha_ref = ops.na_attention_aggregate(src, dst, logits, h, nd,
                                                    backend="jnp")
@@ -361,7 +362,7 @@ def test_out_of_order_revisits_on_platform_backend():
     with jax.default_matmul_precision("highest"):
         out = seg_sum_na(packed, h)
         logits = (RNG.standard_normal(ne) * 2).astype(np.float32)
-        out_a, alpha = ops.na_attention_packed(packed, logits, h, dst)
+        out_a, alpha = ops.na_attention_packed(packed, logits, h)
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(ref.seg_sum_na_ref(src, dst, h, nd)),
                                atol=1e-4)
@@ -434,8 +435,7 @@ def test_execute_rejects_unknown_kernel_backend(frontends):
     m = HGNN(cfg, graph.feature_dims, graph.num_vertices, sorted(targets))
     params = m.init(jax.random.key(0))
     with pytest.raises(ValueError):
-        m.execute(params, feats, res.banded_batches(),
-                  na_executor="banded", kernel_backend="jnp")
+        m.execute(params, feats, res.banded_batches(), kernel_backend="jnp")
 
 
 def test_hbm_feature_bytes_fp32_default():

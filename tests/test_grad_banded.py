@@ -70,13 +70,12 @@ def test_loss_grads_match_jnp(frontends, ds, model):
     m = HGNN(cfg, graph.feature_dims, graph.num_vertices, sorted(targets))
     params = m.init(jax.random.key(2))
 
-    def loss_fn(backend, graphs):
-        return lambda p, f: m.execute_loss(p, f, graphs, labels, mask=mask,
-                                           na_executor=backend)
+    def loss_fn(graphs):
+        return lambda p, f: m.execute_loss(p, f, graphs, labels, mask=mask)
 
-    g_jnp = jax.grad(loss_fn("jnp", res.batches()), argnums=(0, 1))(
+    g_jnp = jax.grad(loss_fn(res.batches()), argnums=(0, 1))(
         params, feats)
-    g_banded = jax.grad(loss_fn("banded", res.banded_batches()),
+    g_banded = jax.grad(loss_fn(res.banded_batches()),
                         argnums=(0, 1))(params, feats)
     flat_j, tree_j = jax.tree.flatten(g_jnp)
     flat_b, tree_b = jax.tree.flatten(g_banded)
@@ -101,8 +100,7 @@ def test_attention_param_grads_nonzero(frontends):
     m = HGNN(cfg, graph.feature_dims, graph.num_vertices, sorted(targets))
     params = m.init(jax.random.key(3))
     grads = jax.grad(
-        lambda p: m.execute_loss(p, feats, res.banded_batches(), labels,
-                                 na_executor="banded"))(params)
+        lambda p: m.execute_loss(p, feats, res.banded_batches(), labels))(params)
     # only PAP/PSP can influence the P-type head in this workload (APA is
     # A -> A, and nothing live consumes h[A]); their attention params must
     # get gradients in EVERY layer — a stop_gradient hole anywhere in the
@@ -181,7 +179,7 @@ def test_na_attention_packed_grads_match_ref():
     logits = jnp.asarray(RNG.standard_normal(ne), jnp.float32)
 
     def f_banded(lg, x):
-        out, alpha = ops.na_attention_packed(packed, lg, x, dst,
+        out, alpha = ops.na_attention_packed(packed, lg, x,
                                              backend="interpret")
         return jnp.sum(out * r) + jnp.sum(alpha * ra)
 
@@ -215,7 +213,7 @@ def test_train_step_banded_reuses_packing(frontends):
     m = HGNN(cfg, graph.feature_dims, graph.num_vertices, sorted(targets))
     banded = res.banded_batches()
     state = init_train_state(m, jax.random.key(0))
-    step = make_train_step(m, banded, na_backend="banded", total=8)
+    step = make_train_step(m, banded, total=8)
 
     def _boom(*a, **k):
         raise AssertionError("pack_edge_blocks called inside the train step")
@@ -251,8 +249,7 @@ def test_fit_banded_converges_like_jnp(frontends):
                      target_type=target_type)
     m = HGNN(cfg, graph.feature_dims, graph.num_vertices, sorted(targets))
     out_j = fit(m, res.batches(), feats, labels, masks, epochs=40)
-    out_b = fit(m, res.banded_batches(), feats, labels, masks, epochs=40,
-                na_backend="banded")
+    out_b = fit(m, res.banded_batches(), feats, labels, masks, epochs=40)
     assert out_j["train_acc"] >= 0.9
     assert out_b["train_acc"] >= out_j["train_acc"] - 0.01
     assert out_b["val_acc"] >= out_j["val_acc"] - 0.02

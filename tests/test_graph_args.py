@@ -127,7 +127,7 @@ def test_lowered_forward_holds_no_graph_constant(name, request):
     c = m["banded"]
     sizes = _constant_bytes(c.forward_lowered().as_text())
     assert max(sizes, default=0) < MB and sum(sizes) < 64 * 1024
-    closed = jax.jit(lambda p, f: c.model.execute(p, f, c.graphs, na_executor="banded"))
+    closed = jax.jit(lambda p, f: c.model.execute(p, f, c.graphs))
     dense = [g.packed.dense_format and c.cfg.model == "rgcn" for g in c.graphs]
     assert any(dense) is (name == "dblp")
     read = sum(g.packed.dense_tiles()[3].nbytes if d else g.packed.src_local.nbytes
@@ -171,7 +171,7 @@ def test_attention_forward_equals_the_closed_over_one(imdb):
     """Same ops in the same order: an argument in place of a constant
     changes no logit of the banded Simple-HGN forward."""
     c = imdb["banded"]
-    closed = jax.jit(lambda p, f: c.model.execute(p, f, c.graphs, na_executor="banded"))
+    closed = jax.jit(lambda p, f: c.model.execute(p, f, c.graphs))
     assert np.array_equal(imdb["logits"], np.asarray(closed(imdb["params"], imdb["feats"])))
 
 

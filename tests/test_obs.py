@@ -95,11 +95,10 @@ def test_dependency_subset_forward_ops_map_to_scopes(compiled, graph):
     c, params = compiled["shgn"]
     feats = device_features(graph)
     sub = c.dependency_subset(np.array([0, 3, 5]))
-    betas = c.model.fusion_betas(params, feats, c.graphs, na_executor="banded")
+    betas = c.model.fusion_betas(params, feats, c.graphs)
 
     def dep(p, f, b, arrays):
-        return c.model.execute_dependency_subset(p, f, c.graphs, arrays, b,
-                                                 na_executor="banded")
+        return c.model.execute_dependency_subset(p, f, c.graphs, arrays, b)
 
     text = jax.jit(dep).lower(params, feats, betas, sub.arrays).compile().as_text()
     scope_of = obs.scopes_of_hlo(text)
@@ -131,7 +130,7 @@ def test_scopes_change_only_metadata(compiled, graph, monkeypatch):
 
     def hlo():
         def fwd(p, f):  # a new function each time: no trace is reused
-            return c.model.execute(p, f, c.graphs, na_executor="banded")
+            return c.model.execute(p, f, c.graphs)
         text = jax.jit(fwd).lower(params, feats).compile().as_text()
         body = text[text.index("\n%"):]  # past the stack-frame tables
         return re.sub(r",? metadata=\{[^}]*\}", "", body), text

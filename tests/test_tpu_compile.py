@@ -141,7 +141,7 @@ def test_scoped_forward_compiles_for_v5e(one_chip, monkeypatch):
     feats = {t: _shape(one_chip, x.shape, jnp.float32) for t, x in graph.features.items()}
     # the kernel backend follows the platform: steer it to compiled Pallas
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    text = jax.jit(lambda p, f: c.model.execute(p, f, c.graphs, na_executor="banded")).lower(
+    text = jax.jit(lambda p, f: c.model.execute(p, f, c.graphs)).lower(
         params, feats).compile().as_text()
     scope_of = obs.scopes_of_hlo(text)
     na = {obs.na_scope(li, mp) for li in range(2) for mp in mps}
