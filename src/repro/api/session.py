@@ -272,7 +272,7 @@ class CompiledHGNN:
     def _graph(self) -> List[Dict]:
         """The bound graphs' device arrays as one pytree (built once)."""
         if self._graph_arrays is None:
-            self._graph_arrays = graph_arrays(self.graphs, self.cfg.model)
+            self._graph_arrays = graph_arrays(self.graphs)
         return self._graph_arrays
 
     def _jit_on_graph(self, method):

@@ -17,11 +17,14 @@ Two NA families live here:
     features permuted into the renumbered banded layout — the executed
     form of the paper's GFP stage.
 
-Both families are differentiable end to end: the jnp primitives by
-construction, the banded ones through the custom VJPs the kernels carry
-(backward is a jnp gather/segment-add over the packing's cached edge
-map — see kernels/seg_sum.py and kernels/ops.py), so ``jax.grad`` of a
-model loss agrees between executors to float tolerance.
+The banded attention forward does only per-vertex work in jnp (the two
+score columns); the logits, the softmax stats and alpha are made inside
+the two kernels.  Both families are differentiable end to end: the jnp
+primitives by construction, the banded ones through the custom VJPs the
+kernels carry (backward is a jnp gather/segment-add over the packing's
+cached edge map — see kernels/seg_sum.py and kernels/ops.py), so
+``jax.grad`` of a model loss agrees between executors to float
+tolerance.
 """
 from __future__ import annotations
 
@@ -32,7 +35,7 @@ import jax.numpy as jnp
 
 from repro.kernels.backend import use_interpret
 from repro.kernels.ops import na_attention_packed
-from repro.kernels.seg_sum import PackedEdges, gather_rows, seg_sum_na
+from repro.kernels.seg_sum import PackedEdges, seg_sum_na
 
 
 def feature_projection(w: jax.Array, b: jax.Array, x: jax.Array) -> jax.Array:
@@ -101,32 +104,28 @@ def na_mean_banded(
 
 
 def na_attention_banded(
+    packed: PackedEdges,
     h_src: jax.Array,  # (N_src, D) banded-numbered source features
     h_dst: jax.Array,  # (N_dst, D) banded-numbered destination features
-    src: jax.Array,  # (E,) banded src ids, scheduled order
-    dst: jax.Array,  # (E,) banded dst ids, scheduled order
-    packed: PackedEdges,
     a_src: jax.Array,
     a_dst: jax.Array,
-    edge_bias: Optional[jax.Array] = None,
+    edge_bias: Optional[jax.Array] = None,  # scalar edge-type term (Simple-HGN)
     leaky_slope: float = 0.2,
     backend: Optional[str] = None,
 ) -> jax.Array:
-    """GAT-style NA on the fused device-resident kernel path.
+    """GAT-style NA on the attention kernels (``kernels.ops.na_attention_packed``).
 
-    Same math as ``na_attention``; logits are computed per edge of the
-    *scheduled* stream and everything downstream (blocked scatter, online
-    (m, s) stats, alpha-weighted aggregation) stays on device via
-    ``kernels.ops.na_attention_packed``.
+    Same math as ``na_attention``, with only per-vertex work outside the
+    kernels: the two score columns ``h_src @ a_src`` and ``h_dst @ a_dst``,
+    the scalar bias folded into the destination's.  The logits, the
+    online (m, s) stats and alpha are made per edge slot inside the
+    kernels, from the packing's blocks.
     """
-    e_s = h_src @ a_src
     e_d = h_dst @ a_dst
-    logits = gather_rows(e_s, src) + gather_rows(e_d, dst)
     if edge_bias is not None:
-        logits = logits + edge_bias
-    logits = jax.nn.leaky_relu(logits, leaky_slope)
-    out, _ = na_attention_packed(packed, logits, h_src, backend=backend)
-    return out
+        e_d = e_d + edge_bias
+    return na_attention_packed(packed, h_src @ a_src, e_d, h_src, leaky_slope,
+                               backend=backend)
 
 
 def semantic_fusion_beta(

@@ -92,9 +92,8 @@ class SemanticGraphBatch:
             edge_type_id=edge_type_id,
         )
 
-    def device_arrays(self, edge_maps: bool = True) -> Dict:
-        """The batch's device arrays as one pytree (``edge_maps`` is the
-        banded batch's option; this batch has only its edge list)."""
+    def device_arrays(self) -> Dict:
+        """The batch's device arrays as one pytree: its edge list."""
         return {"src": self.src, "dst": self.dst}
 
     def bind(self, arrays: Dict) -> "SemanticGraphBatch":
@@ -126,8 +125,6 @@ class BandedBatch:
     src_gather: jax.Array  # (num_src,) banded row -> global src id
     dst_gather: jax.Array  # (num_dst,) banded row -> global dst id
     dst_scatter: jax.Array  # (num_dst,) global dst -> banded row
-    src_banded: jax.Array  # (E,) banded src ids, scheduled order
-    dst_banded: jax.Array  # (E,) banded dst ids, scheduled order
     deg: jax.Array  # (num_dst,) in-degree per banded dst row (float32)
 
     @staticmethod
@@ -138,7 +135,7 @@ class BandedBatch:
         the same layout knobs, which the pipeline cache guarantees."""
         rel = rg.original
         sperm, dperm = rg.permutations()  # global -> banded
-        s, d = rg.scheduled_edges(renumbered=True)
+        _, d = rg.scheduled_edges(renumbered=True)
         deg = np.bincount(d, minlength=rel.num_dst).astype(np.float32)
         return BandedBatch(
             metapath=metapath,
@@ -151,16 +148,13 @@ class BandedBatch:
             src_gather=jnp.asarray(np.argsort(sperm), jnp.int32),
             dst_gather=jnp.asarray(np.argsort(dperm), jnp.int32),
             dst_scatter=jnp.asarray(dperm, jnp.int32),
-            src_banded=jnp.asarray(s, jnp.int32),
-            dst_banded=jnp.asarray(d, jnp.int32),
             deg=jnp.asarray(deg),
         )
 
-    def device_arrays(self, edge_maps: bool = True) -> Dict:
+    def device_arrays(self) -> Dict:
         """The batch's device arrays as one pytree: the packing's
-        (``PackedEdges.device_arrays``, with its edge maps when
-        ``edge_maps``) and the permutations, banded edges and degrees."""
-        return {"packed": self.packed.device_arrays(edge_maps),
+        (``PackedEdges.device_arrays``) and the permutations and degrees."""
+        return {"packed": self.packed.device_arrays(),
                 **{f: getattr(self, f) for f in _BANDED_ARRAYS}}
 
     def bind(self, arrays: Dict) -> "BandedBatch":
@@ -170,19 +164,16 @@ class BandedBatch:
             **{f: arrays[f] for f in _BANDED_ARRAYS})
 
 
-_BANDED_ARRAYS = ("src_gather", "dst_gather", "dst_scatter", "src_banded",
-                  "dst_banded", "deg")
+_BANDED_ARRAYS = ("src_gather", "dst_gather", "dst_scatter", "deg")
 
 
-def graph_arrays(graphs: List, model: str) -> List[Dict]:
+def graph_arrays(graphs: List) -> List[Dict]:
     """The device arrays of ``graphs`` as one pytree, for a jitted function
     that takes the graph as an argument and binds it (:func:`bind_graphs`):
     the graph is then data of the compiled program, not constants baked
-    into it.  A forward with static NA weights (:func:`static_na_weights`)
-    reads no edge map, so its batches leave them out (a backward pass
-    uploads them on first use)."""
-    return [g.device_arrays(edge_maps=not static_na_weights(model))
-            for g in graphs]
+    into it.  No forward reads a packing's edge maps, so the pytree leaves
+    them out (a backward pass uploads them on first use)."""
+    return [g.device_arrays() for g in graphs]
 
 
 def bind_graphs(graphs: List, arrays: List[Dict]) -> List:
@@ -319,8 +310,8 @@ def _na_banded(g: "BandedBatch", x: NAInputs, backend: str) -> jax.Array:
         zb = na_mean_banded(g.packed, hb, g.deg, backend=backend)
     else:
         zb = na_attention_banded(
-            hb, gather_rows(x.h_dst, g.dst_gather), g.src_banded, g.dst_banded,
-            g.packed, x.a_src, x.a_dst, edge_bias=x.bias, backend=backend)
+            g.packed, hb, gather_rows(x.h_dst, g.dst_gather), x.a_src, x.a_dst,
+            edge_bias=x.bias, backend=backend)
     return gather_rows(zb, g.dst_scatter)
 
 

@@ -1,4 +1,4 @@
-"""Per-destination edge-softmax statistics kernel (flash-style online m/s).
+"""Per-destination edge-softmax statistics kernels (flash-style online m/s).
 
 The attention NA sub-stage needs alpha_e = exp(l_e - m[dst_e]) / s[dst_e]
 with m/s the per-destination max / sum-of-exp.  A destination's edges can
@@ -10,8 +10,18 @@ graph aggregation:
     m_new = max(m_old, max_block)
     s_new = s_old * exp(m_old - m_new) + sum_e exp(l_e - m_new[dst_e])
 
-The cheap 1-D epilogue (alpha per edge) runs in plain jnp; the heavy
-feature aggregation then uses kernels/seg_sum.py with alpha as weights.
+Two kernels, one name (``na_softmax_stats``):
+
+  * ``_attn_stats_kernel`` (the model's attention path) builds each
+    slot's logit itself, ``leaky_relu(e_s[src] + e_d[dst])``, from the
+    per-vertex score columns of the block's source band and destination
+    tile, and writes the blocked logits out for the aggregation kernel
+    (``seg_sum._attn_na_kernel``), which forms alpha from them and the
+    final (m, s): no per-edge array is built outside the two kernels.
+  * ``_stats_kernel`` reads logits already in the blocked layout: the
+    sharded executor's merged multi-relation stream
+    (``edge_softmax_stats_blocks``) and the flat-logit reference path
+    (``edge_softmax_stats`` over ``PackedEdges.scatter_blocks``).
 """
 from __future__ import annotations
 
@@ -93,6 +103,83 @@ def _stats_call(dst_tile, first, logits, dst_local, valid,
     return m.reshape(num_dst_tiles, td), s.reshape(num_dst_tiles, td)
 
 
+def _attn_stats_kernel(
+    band_ref, dtile_ref, first_ref,  # scalar-prefetch
+    srcl_ref, dstl_ref, valid_ref,  # (1, EB) rows of block i
+    es_ref,  # (BAND, 1): e_s over block i's source band
+    ed_ref,  # (TD, 1): e_d over block i's destination tile
+    m_ref, s_ref,  # (TD, 1) accumulators: one row per destination
+    logit_ref,  # (1, EB): block i's logits, for the aggregation kernel
+    *, eb: int, band: int, td: int, slope: float,
+):
+    i = pl.program_id(0)
+
+    @pl.when(first_ref[i] == 1)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, _NEG)
+        s_ref[...] = jnp.zeros_like(s_ref)
+
+    valid = valid_ref[...] > 0
+    scat = jax.lax.broadcasted_iota(jnp.int32, (td, eb), 0) == dstl_ref[...]
+    sel = jax.lax.broadcasted_iota(jnp.int32, (band, eb), 0) == srcl_ref[...]
+    # each slot's e_s[src] + e_d[dst] as masked sums over the one-hots:
+    # exact in f32 (one term each), where an MXU pass would round to bf16
+    pre = (jnp.sum(jnp.where(sel, es_ref[...], 0.0), axis=0, keepdims=True)
+           + jnp.sum(jnp.where(scat, ed_ref[...], 0.0), axis=0, keepdims=True))
+    logit = jnp.where(valid, jnp.where(pre >= 0, pre, slope * pre), _NEG)
+    logit_ref[...] = logit
+    eff = scat & valid  # (TD, EB): edge e lands on row t
+    blockmax = jnp.max(jnp.where(eff, logit, _NEG), axis=1, keepdims=True)
+    m_old = m_ref[...]
+    m_new = jnp.maximum(m_old, blockmax)
+    scale = jnp.where(m_old > _NEG / 2, jnp.exp(m_old - m_new), 0.0)
+    m_e = jnp.sum(jnp.where(eff, m_new, 0.0), axis=0, keepdims=True)  # (1, EB)
+    ex = jnp.where(valid, jnp.exp(logit - m_e), 0.0)
+    s_add = jnp.sum(jnp.where(eff, ex, 0.0), axis=1, keepdims=True)  # (TD, 1)
+    s_ref[...] = s_ref[...] * scale + s_add
+    m_ref[...] = m_new
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("num_dst_tiles", "src_band", "dst_tile_rows", "slope",
+                     "interpret"),
+)
+def attention_stats_call(band, dst_tile, first, src_local, dst_local, valid,
+                         e_s, e_d, num_dst_tiles, src_band, dst_tile_rows,
+                         slope, interpret):
+    """The attention path's stats kernel over a packing's block arrays
+    (``(nb, 1, EB)`` rows) and the ``(rows, 1)`` score columns ``e_s``
+    (``src_band`` rows per band) and ``e_d`` (``dst_tile_rows`` per
+    tile, any bias folded in).  Returns the ``(num_dst_tiles *
+    dst_tile_rows, 1)`` columns (m, s), whose rows of tiles no block
+    touches hold uninitialized memory, and the ``(nb, 1, EB)`` blocked
+    logits, ``-1e30`` on padding slots."""
+    nb, eb = src_local.shape[0], src_local.shape[-1]
+    td = dst_tile_rows
+    row = pl.BlockSpec((None, 1, eb), lambda i, b, t, f: (i, 0, 0))
+    col = pl.BlockSpec((td, 1), lambda i, b, t, f: (t[i], 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(nb,),
+        in_specs=[row, row, row,
+                  pl.BlockSpec((src_band, 1), lambda i, b, t, f: (b[i], 0)),
+                  col],
+        out_specs=[col, col, row],
+    )
+    kern = functools.partial(_attn_stats_kernel, eb=eb, band=src_band, td=td,
+                             slope=slope)
+    stats = jax.ShapeDtypeStruct((num_dst_tiles * td, 1), jnp.float32)
+    return pl.pallas_call(
+        kern,
+        grid_spec=grid_spec,
+        out_shape=[stats, stats,
+                   jax.ShapeDtypeStruct((nb, 1, eb), jnp.float32)],
+        interpret=interpret,
+        name="na_softmax_stats",
+    )(band, dst_tile, first, src_local, dst_local, valid, e_s, e_d)
+
+
 def edge_softmax_stats(
     packed: PackedEdges,
     logits_blocked: jax.Array,  # (nb, EB) f32 blocked layout (np or device)
@@ -101,15 +188,16 @@ def edge_softmax_stats(
     """Per-destination (m, s); rows never touched get m=-1e30, s=0.
 
     ``logits_blocked`` may be a device array built by
-    ``PackedEdges.scatter_blocks`` — the fused attention path never brings
-    per-layer logits back to the host.  (m, s) accumulate online across
-    every block of a destination tile, including non-consecutive revisits:
-    ``first_in_tile`` means first touch ever (see kernels/seg_sum.py).
+    ``PackedEdges.scatter_blocks``, as in the flat-logit reference path
+    the attention kernels are tested against.  (m, s) accumulate online
+    across every block of a destination tile, including non-consecutive
+    revisits: ``first_in_tile`` means first touch ever (see
+    kernels/seg_sum.py).
     """
     if interpret is None:
         interpret = use_interpret()
     td = packed.dst_tile_rows
-    num_dst_tiles = max(1, -(-packed.num_dst // td))
+    num_dst_tiles = packed.num_dst_tiles
     # count-derived validity, NOT the weights: zero-weight edges still
     # belong to their destination's softmax
     _, dtile, first, _, dstl = packed.device_blocked()

@@ -18,10 +18,11 @@ import numpy as np
 
 from repro.kernels import ref as _ref
 from repro.kernels.backend import use_interpret
-from repro.kernels.edge_softmax import edge_softmax_stats
+from repro.kernels.edge_softmax import attention_stats_call
 from repro.kernels.flash_attention import flash_attention as _fa
-from repro.kernels.seg_sum import (PackedEdges, gather_rows, pack_edge_blocks,
-                                   seg_sum_na)
+from repro.kernels.seg_sum import (PackedEdges, attention_seg_sum_call,
+                                   dst_rows, pack_edge_blocks, seg_sum_na,
+                                   src_rows)
 from repro.kernels.spgemm_bsr import compose_dense_blocked
 from repro.kernels.ssd_scan import ssd_scan as _ssd
 
@@ -172,129 +173,140 @@ def na_aggregate(
     return seg_sum_na(packed, h, interpret=use_interpret(backend))
 
 
-def _build_attention_packed_vjp(packed: PackedEdges, interpret: bool):
-    """``custom_vjp``-wrapped fused attention NA for one packing.
+def _build_attention_packed_vjp(packed: PackedEdges, interpret: bool,
+                                slope: float):
+    """``custom_vjp``-wrapped attention NA for one packing, over the
+    per-vertex score columns ``e_s`` and ``e_d`` and the features ``h``,
+    each padded to the rows the kernels index.
 
-    Forward is the kernel path (blocked logit scatter, online (m, s)
-    stats, alpha-weighted ``seg_sum_na``).  The backward pass reuses the
-    cached ``PackedEdges`` and the forward's online (m, s) stats to
-    recompute alpha, then scatters cotangents to both the features and
-    the logits (and through them the attention parameters) with jnp
-    segment-adds over the packing's device-resident flat edge map — no
-    host re-packing anywhere:
+    Forward is the two kernels and nothing per edge between them: the
+    stats kernel builds each slot's logit ``leaky_relu(e_s[src] +
+    e_d[dst])`` from the columns, folds it into the online (m, s) and
+    writes the blocked logits; the aggregation kernel forms alpha from
+    those and the final (m, s) and weighs the scatter with it.  The
+    backward pass reuses the packing's flat edge map (uploaded on first
+    use; the forward never reads it) and the forward's (m, s) to
+    recompute alpha, then scatters cotangents with jnp segment-adds:
 
-        grad_alpha_e = h[src_e] . g_out[dst_e] + g_alpha_e
+        grad_alpha_e = h[src_e] . g_out[dst_e]
         grad_logit_e = alpha_e (grad_alpha_e - t[dst_e]),
                        t[d] = sum_{e: dst_e=d} alpha_e grad_alpha_e
+        grad_pre_e   = grad_logit_e * leaky_relu'(pre_e)
+        grad_e_s[s]  = sum_{e: src_e=s} grad_pre_e   (grad_e_d likewise)
         grad_h[s]    = sum_{e: src_e=s} alpha_e g_out[dst_e]
     """
-    src_g, dst_g = packed.device_flat_edges()
-    num_dst = packed.num_dst
+    geometry = dict(num_dst_tiles=packed.num_dst_tiles, src_band=packed.src_band,
+                    dst_tile_rows=packed.dst_tile_rows, interpret=interpret)
 
-    def stats_alpha(logits):
-        lb = packed.scatter_blocks(logits, fill=-1e30)
-        m, s = edge_softmax_stats(packed, lb, interpret=interpret)
-        alpha = (jnp.exp(logits - gather_rows(m, dst_g))
-                 / jnp.maximum(gather_rows(s, dst_g), 1e-9))
-        return m, s, alpha
-
-    def primal(logits, h):
-        _, _, alpha = stats_alpha(logits)
-        out = seg_sum_na(
-            packed, h, interpret=interpret,
-            weights=packed.scatter_blocks(alpha, fill=0.0),
-        )
-        return out, alpha
+    def primal(e_s, e_d, h):
+        blocks = (*packed.device_blocked(), packed.device_valid())
+        m, s, logits = attention_stats_call(*blocks, e_s, e_d, slope=slope,
+                                            **geometry)
+        return attention_seg_sum_call(*blocks, logits, m, s, h, **geometry), (m, s)
 
     @jax.custom_vjp
-    def attention(logits, h):
-        return primal(logits, h)
+    def attention(e_s, e_d, h):
+        return primal(e_s, e_d, h)[0]
 
-    def fwd(logits, h):
-        m, s, alpha = stats_alpha(logits)
-        out = seg_sum_na(
-            packed, h, interpret=interpret,
-            weights=packed.scatter_blocks(alpha, fill=0.0),
-        )
-        return (out, alpha), (logits, m, s, h)
+    def fwd(e_s, e_d, h):
+        out, (m, s) = primal(e_s, e_d, h)
+        return out, (e_s, e_d, m, s, h)
 
-    def bwd(res, cots):
-        logits, m, s, h = res
-        g_out, g_alpha = cots
-        alpha = jnp.exp(logits - m[dst_g]) / jnp.maximum(s[dst_g], 1e-9)
+    def bwd(res, g_out):
+        e_s, e_d, m, s, h = res
+        src_g, dst_g = packed.device_flat_edges()
+        pre = e_s[src_g, 0] + e_d[dst_g, 0]
+        logit = jnp.where(pre >= 0, pre, slope * pre)
+        alpha = jnp.exp(logit - m[dst_g, 0]) / jnp.maximum(s[dst_g, 0], 1e-9)
         g_e = g_out[dst_g]  # (E, D)
         grad_alpha = jnp.sum(h[src_g].astype(jnp.float32) * g_e, axis=1)
-        grad_alpha = grad_alpha + g_alpha
-        t = jnp.zeros((num_dst,), jnp.float32).at[dst_g].add(alpha * grad_alpha)
-        grad_logits = alpha * (grad_alpha - t[dst_g])
+        t = jnp.zeros((g_out.shape[0],), jnp.float32).at[dst_g].add(
+            alpha * grad_alpha)
+        grad_pre = alpha * (grad_alpha - t[dst_g]) * jnp.where(pre >= 0, 1.0, slope)
+        grad_e_s = jnp.zeros_like(e_s).at[src_g, 0].add(grad_pre)
+        grad_e_d = jnp.zeros_like(e_d).at[dst_g, 0].add(grad_pre)
         grad_h = jnp.zeros_like(h).at[src_g].add(
             (alpha[:, None] * g_e).astype(h.dtype))
-        return grad_logits, grad_h
+        return grad_e_s, grad_e_d, grad_h
 
     attention.defvjp(fwd, bwd)
     return attention
 
 
-def attention_packed_vjp(packed: PackedEdges, interpret: bool):
+def attention_packed_vjp(packed: PackedEdges, interpret: bool, slope: float):
     """Memoized accessor — one custom-VJP function per (packing,
-    interpret), cached on the packing so jitted train steps retrace
-    nothing across steps (grad-safe ``BandedBatch`` reuse)."""
+    interpret, slope), cached on the packing so jitted train steps
+    retrace nothing across steps (grad-safe ``BandedBatch`` reuse)."""
     cache = getattr(packed, "_attn_vjp_fns", None)
     if cache is None:
         cache = {}
         packed._attn_vjp_fns = cache
-    fn = cache.get(interpret)
+    key = (interpret, slope)
+    fn = cache.get(key)
     if fn is None:
-        fn = _build_attention_packed_vjp(packed, interpret)
-        cache[interpret] = fn
+        fn = _build_attention_packed_vjp(packed, interpret, slope)
+        cache[key] = fn
     return fn
 
 
 def na_attention_packed(
     packed: PackedEdges,
-    edge_logits: jax.Array,  # (E,) logits in the packing's scheduled order
+    e_s: jax.Array,  # (N_src,) source scores h_src @ a_src, src numbering
+    e_d: jax.Array,  # (N_dst,) destination scores h_dst @ a_dst (+ bias)
     h: jax.Array,  # (N_src, D) features in the packing's src numbering
+    leaky_slope: float = 0.2,
     backend: Optional[str] = None,
-) -> Tuple[jax.Array, jax.Array]:
-    """Device-resident fused attention NA over a cached packing.
+) -> jax.Array:
+    """Edge-softmax attention NA over a cached packing; returns (N_dst, D).
 
-    Per-edge logits scatter into the blocked layout on device
-    (``PackedEdges.scatter_blocks``), the Pallas stats kernel folds them
-    into online per-destination (m, s), and the alpha-weighted aggregation
-    reuses the same blocks — no host re-packing or per-block Python loops
-    anywhere on the per-layer path.  Differentiable in ``edge_logits`` and
-    ``h`` (see ``_build_attention_packed_vjp``).  Kernel backends only
-    ("pallas" / "interpret"); the jnp oracle needs the flat edge list and
-    lives in ``na_attention_aggregate``.
+    Edge e's logit is ``leaky_relu(e_s[src_e] + e_d[dst_e])`` (a
+    per-metapath bias belongs in ``e_d``); alpha is its softmax over the
+    destination's in-edges, and ``out[d] = sum_e alpha_e h[src_e]``.  The
+    scores go to the kernels as ``(rows, 1)`` columns, and the logits,
+    the (m, s) stats and alpha are all made inside the two Pallas
+    kernels: nothing per edge is built around them.  Differentiable in
+    ``e_s``, ``e_d`` and ``h`` (see ``_build_attention_packed_vjp``).
+    Kernel backends only ("pallas" / "interpret"); the jnp oracle needs
+    the flat edge list and lives in ``na_attention_aggregate``.
     """
     assert backend != "jnp", "na_attention_packed is the kernel path"
-    fn = attention_packed_vjp(packed, use_interpret(backend))
-    return fn(jnp.asarray(edge_logits, jnp.float32), h)
+    if not packed.num_blocks:
+        return jnp.zeros((packed.num_dst, h.shape[1]), h.dtype)
+    fn = attention_packed_vjp(packed, use_interpret(backend), leaky_slope)
+    n_dst_pad = packed.num_dst_tiles * packed.dst_tile_rows
+    e_s = src_rows(packed, jnp.asarray(e_s, jnp.float32)[:, None])
+    e_d = jnp.asarray(e_d, jnp.float32)[:, None]
+    e_d = jnp.pad(e_d, ((0, n_dst_pad - e_d.shape[0]), (0, 0)))
+    return dst_rows(packed, fn(e_s, e_d, src_rows(packed, h)))
 
 
 def na_attention_aggregate(
     src: np.ndarray,
     dst: np.ndarray,
-    edge_logits: np.ndarray,
+    e_s: jax.Array,
+    e_d: jax.Array,
     h: jax.Array,
     num_dst: int,
+    leaky_slope: float = 0.2,
     backend: Optional[str] = None,
     packed: Optional[PackedEdges] = None,
-) -> Tuple[jax.Array, jax.Array]:
-    """Edge-softmax attention NA; returns (aggregated, alpha).
+) -> jax.Array:
+    """Edge-softmax attention NA with logits ``leaky_relu(e_s[src] +
+    e_d[dst])`` (see :func:`na_attention_packed`); returns (num_dst, D).
 
     ``packed`` supplies a cached packing of the (src, dst) stream (parity
     with ``na_aggregate``) — without it the stream is packed on the spot.
     """
     if backend == "jnp":
-        alpha = _ref.edge_softmax_ref(jnp.asarray(edge_logits), jnp.asarray(dst), num_dst)
-        # keep alpha on device: the jnp oracle stays differentiable end to
+        e_s, e_d = jnp.asarray(e_s, jnp.float32), jnp.asarray(e_d, jnp.float32)
+        logits = jax.nn.leaky_relu(e_s[src] + e_d[dst], leaky_slope)
+        alpha = _ref.edge_softmax_ref(logits, jnp.asarray(dst), num_dst)
+        # alpha stays on device: the jnp oracle is differentiable end to
         # end (the grad-parity tests differentiate through this path)
-        out = _ref.seg_sum_na_ref(src, dst, h, num_dst, weight=alpha)
-        return out, alpha
+        return _ref.seg_sum_na_ref(src, dst, h, num_dst, weight=alpha)
     if packed is None:
         packed = pack_edge_blocks(src, dst, int(h.shape[0]), num_dst)
-    return na_attention_packed(packed, edge_logits, h, backend=backend)
+    return na_attention_packed(packed, e_s, e_d, h, leaky_slope, backend=backend)
 
 
 def compose_boolean(

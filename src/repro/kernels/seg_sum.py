@@ -41,19 +41,25 @@ H_band`` at ``HIGHEST``.  That is the same f32 sum with exact zeros added;
 only its order changes.  ``seg_sum_na`` takes the dense format when the
 weights are static (``weights is None``: the packing's mask or host
 weights) and the packing's pairs number at most 1/``DENSE_BLOCKS_PER_TILE``
-of its blocks (``PackedEdges.dense_format``); traced weights (the
-attention path's alpha) and sparse packings stay on edge blocks, as do
-the raw block entries (``seg_sum_blocks``, ``_seg_sum_call``).  The dense
-kernel's ``pallas_call`` keeps the name ``na_seg_sum``: a packing takes one
-format or the other, so each aggregation is still one ``na_seg_sum`` call,
-and what reads the device trace by kernel name (its roofline share, the
+of its blocks (``PackedEdges.dense_format``); traced blocked weights
+and sparse packings stay on edge blocks, as do the raw block entries
+(``seg_sum_blocks``, ``_seg_sum_call``).  The dense kernel's
+``pallas_call`` keeps the name ``na_seg_sum``: a packing takes one format
+or the other, so each aggregation is still one ``na_seg_sum`` call, and
+what reads the device trace by kernel name (its roofline share, the
 kernel stage of the forward) reads either format.
+
+The attention path has an edge-block kernel of its own, also named
+``na_seg_sum`` (``_attn_na_kernel``, ``attention_seg_sum_call``): it
+reads the blocked logits and the (TD, 1) softmax stats columns of the
+block's tile that ``edge_softmax.attention_stats_call`` wrote, forms
+each slot's alpha and weighs the scatter one-hot's columns with it, so
+no per-edge weight array is built outside the kernels.
 
 ``seg_sum_na`` is differentiable: a ``jax.custom_vjp`` wraps the Pallas
 call, and the backward pass is a gather through the same cached
 edge -> (block, slot) map — ``grad_h[s] = sum_{e: src_e=s} w_e g[dst_e]``
-and (for traced blocked weights, the attention path) ``grad_w[b, k] =
-h[src] . g[dst]`` — composed in jnp over device-resident flat edge
+and (for traced blocked weights) ``grad_w[b, k] = h[src] . g[dst]`` — composed in jnp over device-resident flat edge
 indices derived once per packing.  No host re-packing happens on the
 backward path, so a cached ``BandedBatch`` serves training steps as-is.
 """
@@ -143,6 +149,11 @@ class PackedEdges:
         are stored bf16.
         """
         return self.num_blocks * self.src_band * d * elem_bytes
+
+    @property
+    def num_dst_tiles(self) -> int:
+        """Destination tiles of the kernels' output (at least 1)."""
+        return max(1, -(-self.num_dst // self.dst_tile_rows))
 
     @property
     def num_bands(self) -> int:
@@ -325,13 +336,12 @@ class PackedEdges:
         """Device-resident ``dense_tiles()`` (uploaded once)."""
         return self._device("_device_dense", self.dense_tiles)
 
-    def device_arrays(self, edge_maps: bool = True) -> Dict[str, object]:
+    def device_arrays(self) -> Dict[str, object]:
         """Every device array the NA kernels read from this packing, as one
         pytree: the block arrays, the valid mask, the weights of a
-        weighted packing, the dense tiles of a packing in the dense format
-        and, with ``edge_maps``, the edge map and the flat edges (the
-        attention path's scatters and every backward pass read those; the
-        mean path's forward does not).
+        weighted packing and the dense tiles of a packing in the dense
+        format.  No forward reads the edge map or the flat edges; a
+        backward pass uploads them on first use.
 
         A jitted function that takes this pytree as an argument and
         :meth:`bind` s it runs on the packing without holding its arrays as
@@ -341,8 +351,6 @@ class PackedEdges:
             names.append("weight")
         if self.dense_format:
             names.append("dense")
-        if edge_maps:
-            names += ["edge_map", "flat_edges"]
         return {n: getattr(self, _DEVICE[n][0])() for n in names}
 
     def bind(self, arrays: Dict[str, object]) -> "PackedEdges":
@@ -374,9 +382,7 @@ def gather_rows(x: jax.Array, idx: jax.Array) -> jax.Array:
 _DEVICE = {"blocked": ("device_blocked", "_device_blocked"),
            "valid": ("device_valid", "_device_valid"),
            "weight": ("device_weight", "_device_weight"),
-           "dense": ("device_dense", "_device_dense"),
-           "edge_map": ("device_edge_map", "_device_map"),
-           "flat_edges": ("device_flat_edges", "_device_flat_edges")}
+           "dense": ("device_dense", "_device_dense")}
 
 
 def _first_touch_flags(dt: np.ndarray) -> np.ndarray:
@@ -756,6 +762,37 @@ def _na_kernel(
     out_ref[...] += contrib.astype(out_ref.dtype)
 
 
+def _attn_na_kernel(
+    band_ref, dtile_ref, first_ref,  # scalar-prefetch (SMEM)
+    srcl_ref, dstl_ref, valid_ref, logit_ref,  # (1, EB) rows of block i
+    m_ref, s_ref,  # (TD, 1) final softmax stats of block i's tile
+    h_ref,  # (BAND, D) feature band
+    out_ref,  # (TD, D) output tile
+    *, eb: int, band: int, td: int,
+):
+    i = pl.program_id(0)
+
+    @pl.when(first_ref[i] == 1)
+    def _zero():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    srcl = srcl_ref[0, :]
+    scat = jax.lax.broadcasted_iota(jnp.int32, (td, eb), 0) == dstl_ref[...]
+    # m[dst], s[dst] per slot as masked sums over the one-hot (exact)
+    m_e = jnp.sum(jnp.where(scat, m_ref[...], 0.0), axis=0, keepdims=True)
+    s_e = jnp.sum(jnp.where(scat, s_ref[...], 0.0), axis=0, keepdims=True)
+    alpha = jnp.where(valid_ref[...] > 0,
+                      jnp.exp(logit_ref[...] - m_e) / jnp.maximum(s_e, 1e-9),
+                      0.0)  # (1, EB)
+    hi = jax.lax.Precision.HIGHEST
+    sel = srcl[:, None] == jax.lax.broadcasted_iota(jnp.int32, (eb, band), 1)
+    gathered = jnp.dot(sel.astype(jnp.float32), h_ref[...].astype(jnp.float32),
+                       precision=hi)
+    # alpha weights the scatter one-hot's columns: no row-to-column relayout
+    contrib = jnp.dot(jnp.where(scat, alpha, 0.0), gathered, precision=hi)
+    out_ref[...] += contrib.astype(out_ref.dtype)
+
+
 def _dense_kernel(
     band_ref, dtile_ref, first_ref,  # scalar-prefetch (SMEM)
     a_ref, h_ref,  # VMEM inputs: (TD, BAND) weight tile, (BAND, D) band
@@ -840,6 +877,42 @@ def _dense_call(band, dst_tile, first, tiles, h, num_dst_tiles, interpret):
     )(band, dst_tile, first, tiles, h)
 
 
+@functools.partial(
+    jax.jit, static_argnames=("num_dst_tiles", "src_band", "dst_tile_rows", "interpret")
+)
+def attention_seg_sum_call(
+    band, dst_tile, first, src_local, dst_local, valid, logits, m, s, h,
+    num_dst_tiles, src_band, dst_tile_rows, interpret,
+):
+    """The attention path's aggregation kernel: ``out[d] += alpha_e
+    h[src_e]`` over edge blocks, alpha formed per slot from the blocked
+    ``logits`` and the ``(rows, 1)`` stats columns (m, s) of
+    ``edge_softmax.attention_stats_call``.  Rows of tiles no block
+    touches hold uninitialized memory."""
+    nb, eb = src_local.shape[0], src_local.shape[-1]
+    d = h.shape[1]
+    td = dst_tile_rows
+    row = pl.BlockSpec((None, 1, eb), lambda i, b, t, f: (i, 0, 0))
+    col = pl.BlockSpec((td, 1), lambda i, b, t, f: (t[i], 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(nb,),
+        in_specs=[
+            row, row, row, row, col, col,
+            pl.BlockSpec((src_band, d), lambda i, b, t, f: (b[i], 0)),
+        ],
+        out_specs=pl.BlockSpec((td, d), lambda i, b, t, f: (t[i], 0)),
+    )
+    kern = functools.partial(_attn_na_kernel, eb=eb, band=src_band, td=td)
+    return pl.pallas_call(
+        kern,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((num_dst_tiles * td, d), h.dtype),
+        interpret=interpret,
+        name="na_seg_sum",
+    )(band, dst_tile, first, src_local, dst_local, valid, logits, m, s, h)
+
+
 def _build_banded_matvec(packed: PackedEdges, interpret: bool,
                          weight_grad: bool):
     """``custom_vjp``-wrapped banded matvec for one packing.
@@ -853,7 +926,7 @@ def _build_banded_matvec(packed: PackedEdges, interpret: bool,
     the (E, D) weight-cotangent product for constant weights (the mean-NA
     path, whose ones-mask never needs a gradient).
     """
-    num_dst_tiles = max(1, -(-packed.num_dst // packed.dst_tile_rows))
+    num_dst_tiles = packed.num_dst_tiles
     if not weight_grad and packed.dense_format:
         dense = packed.device_dense()
 
@@ -925,27 +998,37 @@ def seg_sum_na(
 
     ``weights`` optionally overrides ``packed.weight`` with an already
     device-resident (nb, EB) blocked array (see
-    ``PackedEdges.scatter_blocks``) — the attention path feeds per-layer
-    alpha this way without re-materializing host-side blocks; its
-    cotangent flows back through the blocked layout, and it always runs
-    on edge blocks.  Without it the weights are static and a packing in
+    ``PackedEdges.scatter_blocks``), as the flat-logit reference of the
+    attention kernels feeds alpha; its cotangent flows back through the
+    blocked layout, and it always runs on edge blocks.  Without it the weights are static and a packing in
     the dense format (``PackedEdges.dense_format``) is aggregated as
     dense tiles.  ``interpret=None`` runs the platform's kernel backend
     (``repro.kernels.backend``).
     """
     if interpret is None:
         interpret = use_interpret()
-    n_src_pad = max(packed.num_bands * packed.src_band, packed.num_src)
-    if h.shape[0] < n_src_pad:
-        h = jnp.concatenate(
-            [h, jnp.zeros((n_src_pad - h.shape[0], h.shape[1]), h.dtype)], axis=0
-        )
-    num_dst_tiles = max(1, -(-packed.num_dst // packed.dst_tile_rows))
     weight_grad = weights is not None
     w = packed.device_weight() if weights is None else jnp.asarray(weights)
-    out = banded_matvec_vjp(packed, interpret, weight_grad)(h, w)
-    # tiles never visited by any block hold uninitialized memory -> zero them
-    touched = np.zeros(num_dst_tiles, bool)
+    out = banded_matvec_vjp(packed, interpret, weight_grad)(src_rows(packed, h), w)
+    return dst_rows(packed, out)
+
+
+def src_rows(packed: PackedEdges, x: jax.Array) -> jax.Array:
+    """``x`` (one row per source) with zero rows appended to cover every
+    band a block of ``packed`` reads: the row count the kernels index."""
+    n_src_pad = max(packed.num_bands * packed.src_band, packed.num_src)
+    if x.shape[0] < n_src_pad:
+        x = jnp.concatenate(
+            [x, jnp.zeros((n_src_pad - x.shape[0],) + x.shape[1:], x.dtype)], axis=0
+        )
+    return x
+
+
+def dst_rows(packed: PackedEdges, out: jax.Array) -> jax.Array:
+    """The ``num_dst`` rows of a kernel's tile-padded output over
+    ``packed``: tiles never visited by any block hold uninitialized
+    memory, so they are zeroed."""
+    touched = np.zeros(packed.num_dst_tiles, bool)
     if packed.num_blocks:
         touched[np.asarray(packed.dst_tile)] = True
     if not touched.all():

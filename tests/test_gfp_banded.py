@@ -27,6 +27,14 @@ WORKLOADS = {
     "imdb_small": (["AMA", "MAM", "MDM"], "M"),
 }
 
+
+
+def _scores(ns, nd, scale=2.0):
+    """Random per-vertex attention scores (e_s, e_d): logits of both signs."""
+    return (jnp.asarray(RNG.standard_normal(ns) * scale, jnp.float32),
+            jnp.asarray(RNG.standard_normal(nd) * scale, jnp.float32))
+
+
 _PACKED_FIELDS = ("src_local", "dst_local", "band", "dst_tile",
                   "first_in_tile", "count")
 
@@ -267,15 +275,10 @@ def test_grouped_packing_with_revisit_matches_reference():
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref.seg_sum_na_ref(src, dst, h, nd)),
         atol=1e-5)
-    logits = (RNG.standard_normal(src.size) * 2).astype(np.float32)
-    out_a, alpha = ops.na_attention_packed(packed, logits, h,
-                                           backend="interpret")
-    want_a, alpha_ref = ops.na_attention_aggregate(src, dst, logits, h, nd,
-                                                   backend="jnp")
-    np.testing.assert_allclose(np.asarray(alpha), np.asarray(alpha_ref),
-                               atol=1e-5)
-    np.testing.assert_allclose(np.asarray(out_a)[:nd], np.asarray(want_a),
-                               atol=1e-4)
+    e_s, e_d = _scores(ns, nd)
+    out_a = ops.na_attention_packed(packed, e_s, e_d, h, backend="interpret")
+    want_a = ops.na_attention_aggregate(src, dst, e_s, e_d, h, nd, backend="jnp")
+    np.testing.assert_allclose(np.asarray(out_a), np.asarray(want_a), atol=1e-4)
 
 
 def test_restructured_imdb_blocks_count_tile_run_band_groups():
@@ -321,15 +324,10 @@ def test_first_in_tile_survives_nonconsecutive_revisit():
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-5)
 
     # the attention stats accumulate across the revisit too
-    logits = (RNG.standard_normal(src.size) * 2).astype(np.float32)
-    out_a, alpha = ops.na_attention_packed(packed, logits, h,
-                                           backend="interpret")
-    want_a, alpha_ref = ops.na_attention_aggregate(src, dst, logits, h, nd,
-                                                   backend="jnp")
-    np.testing.assert_allclose(np.asarray(alpha), np.asarray(alpha_ref),
-                               atol=1e-5)
-    np.testing.assert_allclose(np.asarray(out_a)[:nd], np.asarray(want_a),
-                               atol=1e-4)
+    e_s, e_d = _scores(ns, nd)
+    out_a = ops.na_attention_packed(packed, e_s, e_d, h, backend="interpret")
+    want_a = ops.na_attention_aggregate(src, dst, e_s, e_d, h, nd, backend="jnp")
+    np.testing.assert_allclose(np.asarray(out_a), np.asarray(want_a), atol=1e-4)
 
     # zeroing a revisited tile is exactly what the seed semantics did:
     # simulate it and confirm it would corrupt the result (guards against
@@ -361,17 +359,13 @@ def test_out_of_order_revisits_on_platform_backend():
     h = jnp.asarray(RNG.standard_normal((ns, 16)), jnp.float32)
     with jax.default_matmul_precision("highest"):
         out = seg_sum_na(packed, h)
-        logits = (RNG.standard_normal(ne) * 2).astype(np.float32)
-        out_a, alpha = ops.na_attention_packed(packed, logits, h)
+        e_s, e_d = _scores(ns, nd)
+        out_a = ops.na_attention_packed(packed, e_s, e_d, h)
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(ref.seg_sum_na_ref(src, dst, h, nd)),
                                atol=1e-4)
-    want_a, alpha_ref = ops.na_attention_aggregate(src, dst, logits, h, nd,
-                                                   backend="jnp")
-    np.testing.assert_allclose(np.asarray(alpha), np.asarray(alpha_ref),
-                               atol=1e-5)
-    np.testing.assert_allclose(np.asarray(out_a)[:nd], np.asarray(want_a),
-                               atol=1e-4)
+    want_a = ops.na_attention_aggregate(src, dst, e_s, e_d, h, nd, backend="jnp")
+    np.testing.assert_allclose(np.asarray(out_a), np.asarray(want_a), atol=1e-4)
 
 
 # ------------------------------------------------------- ops-level paths --
@@ -381,19 +375,17 @@ def test_na_attention_aggregate_accepts_cached_packed():
     dst = RNG.integers(0, nd, ne)
     o = np.lexsort((src, dst))
     src, dst = src[o], dst[o]
-    logits = RNG.standard_normal(ne).astype(np.float32)
+    e_s, e_d = _scores(ns, nd, scale=1.0)
     h = jnp.asarray(RNG.standard_normal((ns, 32)), jnp.float32)
     packed = pack_edge_blocks(src, dst, ns, nd)
-    out_cached, a_cached = ops.na_attention_aggregate(
-        src, dst, logits, h, nd, backend="interpret", packed=packed)
-    out_fresh, a_fresh = ops.na_attention_aggregate(
-        src, dst, logits, h, nd, backend="interpret")
-    out_ref, a_ref = ops.na_attention_aggregate(
-        src, dst, logits, h, nd, backend="jnp")
+    out_cached = ops.na_attention_aggregate(
+        src, dst, e_s, e_d, h, nd, backend="interpret", packed=packed)
+    out_fresh = ops.na_attention_aggregate(
+        src, dst, e_s, e_d, h, nd, backend="interpret")
+    out_ref = ops.na_attention_aggregate(
+        src, dst, e_s, e_d, h, nd, backend="jnp")
     np.testing.assert_allclose(np.asarray(out_cached), np.asarray(out_fresh),
                                atol=1e-6)
-    np.testing.assert_allclose(np.asarray(a_cached), np.asarray(a_ref),
-                               atol=1e-5)
     np.testing.assert_allclose(np.asarray(out_cached), np.asarray(out_ref),
                                atol=1e-4)
 
@@ -409,15 +401,13 @@ def test_weighted_packing_keeps_zero_weight_edges_in_softmax():
     src, dst = src[o], dst[o]
     w = RNG.random(ne).astype(np.float32)
     w[::3] = 0.0  # masked edges on valid slots
-    logits = RNG.standard_normal(ne).astype(np.float32)
+    e_s, e_d = _scores(ns, nd, scale=1.0)
     h = jnp.asarray(RNG.standard_normal((ns, 16)), jnp.float32)
     packed_w = pack_edge_blocks(src, dst, ns, nd, weight=w)
-    out_w, alpha_w = ops.na_attention_aggregate(
-        src, dst, logits, h, nd, backend="interpret", packed=packed_w)
-    out_ref, alpha_ref = ops.na_attention_aggregate(
-        src, dst, logits, h, nd, backend="jnp")
-    np.testing.assert_allclose(np.asarray(alpha_w), np.asarray(alpha_ref),
-                               atol=1e-5)
+    out_w = ops.na_attention_aggregate(
+        src, dst, e_s, e_d, h, nd, backend="interpret", packed=packed_w)
+    out_ref = ops.na_attention_aggregate(
+        src, dst, e_s, e_d, h, nd, backend="jnp")
     np.testing.assert_allclose(np.asarray(out_w), np.asarray(out_ref),
                                atol=1e-4)
     # and valid_mask is count-derived even when weights are zero

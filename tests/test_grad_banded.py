@@ -113,6 +113,37 @@ def test_attention_param_grads_nonzero(frontends):
         assert float(jnp.abs(lp["edge_emb"]).max()) > 0, li
 
 
+@pytest.mark.parametrize("ds", sorted(WORKLOADS))
+def test_shgn_attention_param_grads_match_jnp(frontends, ds):
+    """Simple-HGN's attention parameters reach the banded loss only through
+    the per-vertex score columns the kernels read (``a_src``, ``a_dst``)
+    and the bias folded into the destination's (``edge_emb``, ``a_edge``):
+    their banded gradients match the jnp executor's and are not zero."""
+    graph, res, target_type = frontends[ds]
+    targets = WORKLOADS[ds][0]
+    feats = {t: jnp.asarray(x) for t, x in graph.features.items()}
+    n = graph.num_vertices[target_type]
+    labels = jnp.asarray(RNG.integers(0, 3, n).astype(np.int32))
+    cfg = HGNNConfig(model="shgn", hidden=16, num_layers=2, num_classes=3,
+                     target_type=target_type)
+    m = HGNN(cfg, graph.feature_dims, graph.num_vertices, sorted(targets))
+    params = m.init(jax.random.key(4))
+
+    def grads(graphs):
+        return jax.grad(lambda p: m.execute_loss(p, feats, graphs, labels))(params)
+
+    g_j, g_b = grads(res.batches()), grads(res.banded_batches())
+    for li, (lj, lb) in enumerate(zip(g_j["layers"], g_b["layers"])):
+        pairs = [(lj[k], lb[k]) for k in ("edge_emb", "a_edge")]
+        pairs += [(lj["na"][mp][k], lb["na"][mp][k])
+                  for mp in targets for k in ("a_src", "a_dst")]
+        for want, got in pairs:
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       atol=1e-4)
+        assert float(jnp.abs(lb["a_edge"]).max()) > 0, li
+        assert float(jnp.abs(lb["edge_emb"]).max()) > 0, li
+
+
 # ------------------------------------------------------ op-level VJPs --
 def test_seg_sum_na_grad_matches_ref():
     """Banded matvec VJP == jnp oracle gradient wrt features and blocked
@@ -168,30 +199,31 @@ def test_seg_sum_na_vjp_finite_difference():
 
 
 def test_na_attention_packed_grads_match_ref():
-    """Fused attention VJP (logits + features, including the alpha output
-    cotangent) == differentiating the jnp oracle composite."""
+    """Attention VJP (per-vertex scores + features) == differentiating the
+    jnp oracle composite; the scores give logits of both signs, so both
+    branches of the leaky ReLU's derivative are taken."""
     ns, nd, ne = 250, 120, 900
     src, dst = _random_stream(ns, nd, ne, 9)
     packed = pack_edge_blocks(src, dst, ns, nd)
     h = jnp.asarray(RNG.standard_normal((ns, 8)), jnp.float32)
     r = jnp.asarray(RNG.standard_normal((nd, 8)), jnp.float32)
-    ra = jnp.asarray(RNG.standard_normal(ne), jnp.float32)
-    logits = jnp.asarray(RNG.standard_normal(ne), jnp.float32)
+    e_s = jnp.asarray(RNG.standard_normal(ns), jnp.float32)
+    e_d = jnp.asarray(RNG.standard_normal(nd), jnp.float32)
+    pre = np.asarray(e_s)[src] + np.asarray(e_d)[dst]
+    assert (pre < 0).any() and (pre > 0).any()
 
-    def f_banded(lg, x):
-        out, alpha = ops.na_attention_packed(packed, lg, x,
-                                             backend="interpret")
-        return jnp.sum(out * r) + jnp.sum(alpha * ra)
+    def f_banded(a, b, x):
+        return jnp.sum(ops.na_attention_packed(packed, a, b, x,
+                                               backend="interpret") * r)
 
-    def f_ref(lg, x):
-        out, alpha = ops.na_attention_aggregate(src, dst, lg, x, nd,
-                                                backend="jnp")
-        return jnp.sum(out * r) + jnp.sum(alpha * ra)
+    def f_ref(a, b, x):
+        return jnp.sum(ops.na_attention_aggregate(src, dst, a, b, x, nd,
+                                                  backend="jnp") * r)
 
-    gl_b, gh_b = jax.grad(f_banded, argnums=(0, 1))(logits, h)
-    gl_r, gh_r = jax.grad(f_ref, argnums=(0, 1))(logits, h)
-    np.testing.assert_allclose(np.asarray(gl_b), np.asarray(gl_r), atol=1e-5)
-    np.testing.assert_allclose(np.asarray(gh_b), np.asarray(gh_r), atol=1e-5)
+    g_b = jax.grad(f_banded, argnums=(0, 1, 2))(e_s, e_d, h)
+    g_r = jax.grad(f_ref, argnums=(0, 1, 2))(e_s, e_d, h)
+    for b, want in zip(g_b, g_r):
+        np.testing.assert_allclose(np.asarray(b), np.asarray(want), atol=1e-5)
 
 
 # -------------------------------------------------- train-step plumbing --

@@ -176,25 +176,26 @@ def test_attention_forward_equals_the_closed_over_one(imdb):
 
 
 def test_bound_batches_read_only_the_given_arrays(imdb_small):
-    """``graph_arrays`` leaves the edge maps out of a mean model's graph
-    and holds the dense tiles of a packing in the dense format;
-    ``bind_graphs`` gives batches whose every device array is the given
-    one, and whose packings share the host arrays but no cache."""
+    """``graph_arrays`` leaves the edge maps out of the graph (no forward,
+    mean or attention, reads them) and holds the dense tiles of a packing
+    in the dense format; ``bind_graphs`` gives batches whose every device
+    array is the given one, and whose packings share the host arrays but
+    no cache: a backward pass uploads the flat edges on first use."""
     c = Session(ExecutorSpec(na_executor="banded")).compile(
-        imdb_small, IMDB_METAPATHS, _cfg("rgcn", "M", 3))
-    mean = graph_arrays(c.graphs, "rgcn")
-    attention = graph_arrays(c.graphs, "shgn")
+        imdb_small, IMDB_METAPATHS, _cfg("shgn", "M", 3))
+    arrays = graph_arrays(c.graphs)
     dense = {"dense"} if c.graphs[0].packed.dense_format else set()
-    assert set(mean[0]["packed"]) == {"blocked", "valid"} | dense
-    assert set(attention[0]["packed"]) == {"blocked", "valid", "edge_map",
-                                           "flat_edges"} | dense
-    marked = jax.tree.map(lambda x: x + 0, attention)
+    assert set(arrays[0]["packed"]) == {"blocked", "valid"} | dense
+    assert set(arrays[0]) == {"packed", "src_gather", "dst_gather",
+                              "dst_scatter", "deg"}
+    marked = jax.tree.map(lambda x: x + 0, arrays)
     bound = bind_graphs(c.graphs, marked)
     for g, b, a in zip(c.graphs, bound, marked):
         assert b.src_gather is a["src_gather"] and b.deg is a["deg"]
         assert b.packed.device_blocked() is a["packed"]["blocked"]
         assert b.packed.device_weight() is a["packed"]["valid"]
-        assert b.packed.device_flat_edges() is a["packed"]["flat_edges"]
+        for x, y in zip(b.packed.device_flat_edges(), g.packed.flat_global_edges()):
+            assert np.array_equal(np.asarray(x), y)
         if g.packed.dense_format:
             assert b.packed.device_dense() is a["packed"]["dense"]
         assert b.packed.src_local is g.packed.src_local
@@ -205,7 +206,7 @@ def test_weighted_packing_passes_its_weights():
     src, dst = np.array([0, 1, 2, 2]), np.array([0, 0, 1, 3])
     w = np.array([0.5, 1.0, 2.0, 0.0], np.float32)
     packed = pack_edge_blocks(src, dst, 3, 4, weight=w)
-    arrays = packed.device_arrays(edge_maps=False)
+    arrays = packed.device_arrays()
     assert set(arrays) == {"blocked", "valid", "weight"}
     assert np.array_equal(np.asarray(arrays["weight"])[:, 0], packed.weight)
     assert np.asarray(arrays["valid"]).sum() == 4

@@ -159,8 +159,9 @@ def test_ops_na_backends_agree():
     a = ops.na_aggregate(src, dst, h, 150, backend="jnp")
     b = ops.na_aggregate(src, dst, h, 150, backend="interpret")
     np.testing.assert_allclose(a, b, atol=1e-4)
-    logits = RNG.standard_normal(800).astype(np.float32)
-    oa, _ = ops.na_attention_aggregate(src, dst, logits, h, 150, backend="jnp")
-    ob, _ = ops.na_attention_aggregate(src, dst, logits, h, 150,
-                                       backend="interpret")
+    e_s = jnp.asarray(RNG.standard_normal(200), jnp.float32)
+    e_d = jnp.asarray(RNG.standard_normal(150), jnp.float32)
+    oa = ops.na_attention_aggregate(src, dst, e_s, e_d, h, 150, backend="jnp")
+    ob = ops.na_attention_aggregate(src, dst, e_s, e_d, h, 150,
+                                    backend="interpret")
     np.testing.assert_allclose(oa, ob, atol=1e-4)

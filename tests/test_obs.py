@@ -209,7 +209,7 @@ def test_packing_counts_per_metapath(compiled):
     assert sorted(counts) == METAPATHS
     for g in c.graphs:
         n = counts[g.metapath]
-        assert n["edges"] == g.packed.num_edges == g.src_banded.shape[0]
+        assert n["edges"] == g.packed.num_edges == g.packed.edge_block_id.shape[0]
         assert n["slots"] == n["blocks"] * g.packed.edge_block
         assert n["fill"] == pytest.approx(n["edges"] / n["slots"])
 

@@ -2,7 +2,8 @@
 
 Interpret mode accepts block shapes and layouts that the TPU's compiler
 (Mosaic) refuses, so these tests lower the kernels the main path runs —
-the banded seg-sum, the edge-softmax stats and the SGB ``spgemm_bsr`` —
+the banded seg-sum, the edge-softmax stats, the attention path's stats
+and aggregation kernels and the SGB ``spgemm_bsr`` —
 for a ``v5e:2x2`` topology that is described, not attached, at the
 paper's full-scale shapes, and assert that each compiled program holds a
 ``tpu_custom_call``.  A small scoped Simple-HGN forward is compiled the
@@ -22,9 +23,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.edge_softmax import _stats_call
+from repro.kernels.edge_softmax import _stats_call, attention_stats_call
 from repro.kernels.seg_sum import (DST_TILE, EDGE_BLOCK, SRC_BAND, _dense_call,
-                                   _seg_sum_call)
+                                   _seg_sum_call, attention_seg_sum_call)
 from repro.kernels.spgemm_bsr import TILE, spgemm_bsr
 
 # The largest packing of each paper graph at scale=1.0 (restructured,
@@ -108,6 +109,41 @@ def test_edge_softmax_stats_kernel_compiles_for_v5e(one_chip, graph):
     _assert_kernel(
         lambda t, f, lg, d, v: _stats_call(t, f, lg, d, v, tiles, DST_TILE, False),
         *blocks, logits, dst_local, valid)
+
+
+def _attention_operands(one_chip, graph):
+    """The attention kernels' shared operands at ``graph``'s packing, as
+    the model passes them: three (nb,) block arrays, then the (nb, 1, EB)
+    src_local, dst_local (int32) and valid rows."""
+    nb, _, _ = PACKINGS[graph]
+    blocks = [_shape(one_chip, (nb,), jnp.int32)] * 3
+    rows = [_shape(one_chip, (nb, 1, EDGE_BLOCK), jnp.int32)] * 2
+    return blocks + rows + [_shape(one_chip, (nb, 1, EDGE_BLOCK), jnp.float32)]
+
+
+@pytest.mark.parametrize("graph", sorted(PACKINGS))
+def test_attention_stats_kernel_compiles_for_v5e(one_chip, graph):
+    """The stats kernel that builds each slot's logit from the (rows, 1)
+    score columns of its band and tile and writes the blocked logits."""
+    _, bands, tiles = PACKINGS[graph]
+    e_s = _shape(one_chip, (bands * SRC_BAND, 1), jnp.float32)
+    e_d = _shape(one_chip, (tiles * DST_TILE, 1), jnp.float32)
+    _assert_kernel(
+        lambda *a: attention_stats_call(*a, tiles, SRC_BAND, DST_TILE, 0.2, False),
+        *_attention_operands(one_chip, graph), e_s, e_d)
+
+
+@pytest.mark.parametrize("graph", sorted(PACKINGS))
+def test_attention_seg_sum_kernel_compiles_for_v5e(one_chip, graph):
+    """The aggregation kernel that forms alpha per slot from the blocked
+    logits and the (TD, 1) stats columns of its tile."""
+    nb, bands, tiles = PACKINGS[graph]
+    logits = _shape(one_chip, (nb, 1, EDGE_BLOCK), jnp.float32)
+    stats = [_shape(one_chip, (tiles * DST_TILE, 1), jnp.float32)] * 2
+    h = _shape(one_chip, (bands * SRC_BAND, HIDDEN), jnp.float32)
+    _assert_kernel(
+        lambda *a: attention_seg_sum_call(*a, tiles, SRC_BAND, DST_TILE, False),
+        *_attention_operands(one_chip, graph), logits, *stats, h)
 
 
 def test_spgemm_bsr_compiles_for_v5e(one_chip):
