@@ -25,6 +25,7 @@ import numpy as np
 
 from chipbench import check, graphs, params as seeded
 from chipbench.reference import Reference
+from chipbench.spec import load_model
 
 Fault = Callable[[str, object], object]
 
@@ -67,7 +68,8 @@ class Context:
                                num_classes=int(cfg["num_classes"]),
                                target_type=cfg["target_type"],
                                edge_emb_dim=int(cfg["edge_emb_dim"]),
-                               sf_att_dim=int(cfg["sf_att_dim"]))
+                               sf_att_dim=int(cfg["sf_att_dim"]),
+                               **load_model(cfg["model"]).program_config(cfg))
         self.compiled = self.session.compile(self.graph, cfg["metapaths"], self.hcfg)
         self.num_target = self.nv[cfg["target_type"]]
         self._semantic = None

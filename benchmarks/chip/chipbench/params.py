@@ -4,13 +4,17 @@ The benchmark makes the weights itself, so that the program and the
 reference get the same numbers and the reference takes nothing the
 program made.  The pytree has the program's parameter layout (the
 interface ``HGNN.execute`` reads); the values are this module's own
-draw.  Each is one jitted call.
+draw.  Each is one jitted call.  What a model adds to the layout (its
+NA parameters) comes from its file, ``models/<model>.py``, drawn from
+the same key stream in the same order.
 """
 from __future__ import annotations
 
 from typing import Dict
 
 import numpy as np
+
+from chipbench.spec import load_model
 
 
 def seed_key(seed: int, stream: int):
@@ -22,22 +26,31 @@ def seed_key(seed: int, stream: int):
     return jax.random.key(int(state[0]) >> 1)
 
 
+def dense(k, d_in, d_out):
+    """A ``(d_in, d_out)`` float32 weight, He-scaled."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.random.normal(k, (d_in, d_out), jnp.float32) * (2.0 / d_in) ** 0.5
+
+
+def small(k, *shape):
+    """A float32 array of ``shape`` at a tenth of unit scale."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.random.normal(k, shape, jnp.float32) * 0.1
+
+
 def init_params(cfg: Dict, key):
     """The program's parameter pytree for ``cfg``, drawn from ``key``."""
     import jax
-    import jax.numpy as jnp
 
     types = sorted(cfg["vertices"])
     mps = sorted(cfg["metapaths"])
     h, c = int(cfg["hidden"]), int(cfg["num_classes"])
-    att, emb = int(cfg["sf_att_dim"]), int(cfg["edge_emb_dim"])
-    model = cfg["model"]
-
-    def dense(k, d_in, d_out):
-        return jax.random.normal(k, (d_in, d_out), jnp.float32) * (2.0 / d_in) ** 0.5
-
-    def small(k, *shape):
-        return jax.random.normal(k, shape, jnp.float32) * 0.1
+    att = int(cfg["sf_att_dim"])
+    model = load_model(cfg["model"])
 
     @jax.jit
     def draw(key):
@@ -49,14 +62,8 @@ def init_params(cfg: Dict, key):
                 d_in = (int(cfg["features"][t]) or 1) if layer == 0 else h
                 lp["fp"][t] = {"w": dense(next(ks), d_in, h), "b": small(next(ks), h)}
             for mp in mps:
-                na = {"w_rel": dense(next(ks), h, h)}
-                if model in ("rgat", "shgn"):
-                    na["a_src"] = small(next(ks), h)
-                    na["a_dst"] = small(next(ks), h)
-                lp["na"][mp] = na
-            if model == "shgn":
-                lp["edge_emb"] = small(next(ks), len(mps), emb)
-                lp["a_edge"] = small(next(ks), emb)
+                lp["na"][mp] = model.draw_na(ks, cfg, h)
+            lp.update(model.draw_layer(ks, cfg, mps))
             for t in types:
                 lp["sf"][t] = {"w": dense(next(ks), h, att), "b": small(next(ks), att),
                                "q": small(next(ks), att), "w_self": dense(next(ks), h, h)}

@@ -1,9 +1,11 @@
-"""The plain reference: RGCN / RGAT / Simple-HGN in straightforward jnp.
+"""The plain reference: the configuration's HGNN in straightforward jnp.
 
 Written from the model's description (FP -> NA -> SF per layer, then the
 classifier head), over the benchmark's own semantic graphs as global
 ``(src, dst)`` edge lists, with ``jax.ops.segment_*`` for aggregation and
-the per-destination softmax.  It imports nothing of the program.
+the per-destination softmax.  FP, SF and the head are shared; the NA of
+one metapath is the model's own, ``na`` of ``models/<model>.py``.  It
+imports nothing of the program.
 
 Every matrix product goes through one ``dot``: ``"highest"`` is float32
 (``Precision.HIGHEST``), the precision the configurations state.
@@ -18,7 +20,20 @@ from typing import Callable, Dict, List, Tuple
 
 import numpy as np
 
-LEAKY_SLOPE = 0.2
+from chipbench.spec import load_model
+
+
+def edge_softmax(logit, dst, n_dst):
+    """Softmax of the per-edge ``logit`` over each destination's edges
+    (over the leading axis, so a trailing head axis is kept)."""
+    import jax
+    import jax.numpy as jnp
+
+    m = jax.ops.segment_max(logit, dst, num_segments=n_dst)
+    m = jnp.where(jnp.isfinite(m), m, 0.0)
+    ex = jnp.exp(logit - m[dst])
+    den = jax.ops.segment_sum(ex, dst, num_segments=n_dst)
+    return ex / den[dst]
 
 
 def make_dot(precision: str) -> Callable:
@@ -58,6 +73,7 @@ class Reference:
         import jax.numpy as jnp
 
         self.cfg = cfg
+        self.model = load_model(cfg["model"])
         self.nv = dict(nv)
         self.mps = sorted(cfg["metapaths"])
         self.edges = {mp: (jnp.asarray(semantic[mp][0]), jnp.asarray(semantic[mp][1]))
@@ -79,8 +95,7 @@ class Reference:
         import jax
         import jax.numpy as jnp
 
-        cfg, nv = self.cfg, self.nv
-        model = cfg["model"]
+        cfg, nv, model = self.cfg, self.nv, self.model
         h = {t: feats[t] if int(cfg["features"][t]) > 0 else jnp.ones((n, 1), jnp.float32)
              for t, n in nv.items()}
         for lp in params["layers"]:
@@ -90,29 +105,8 @@ class Reference:
             for i, mp in enumerate(self.mps):
                 src, dst = edges[mp]
                 s_t, d_t = mp[0], mp[-1]
-                n_dst = nv[d_t]
-                na = lp["na"][mp]
-                hs = dot(hp[s_t], na["w_rel"])
-                if model == "rgcn":
-                    summed = jax.ops.segment_sum(hs[src], dst, num_segments=n_dst)
-                    deg = jax.ops.segment_sum(jnp.ones(dst.shape, jnp.float32), dst,
-                                              num_segments=n_dst)
-                    z = summed / jnp.maximum(deg, 1.0)[:, None]
-                else:
-                    e_s = dot(hs, na["a_src"][:, None])[:, 0]
-                    e_d = dot(hp[d_t], na["a_dst"][:, None])[:, 0]
-                    logit = e_s[src] + e_d[dst]
-                    if model == "shgn":
-                        logit = logit + dot(lp["edge_emb"][i][None, :],
-                                            lp["a_edge"][:, None])[0, 0]
-                    logit = jnp.where(logit >= 0, logit, LEAKY_SLOPE * logit)
-                    m = jax.ops.segment_max(logit, dst, num_segments=n_dst)
-                    m = jnp.where(jnp.isfinite(m), m, 0.0)
-                    ex = jnp.exp(logit - m[dst])
-                    den = jax.ops.segment_sum(ex, dst, num_segments=n_dst)
-                    alpha = ex / den[dst]
-                    z = jax.ops.segment_sum(hs[src] * alpha[:, None], dst,
-                                            num_segments=n_dst)
+                hs = dot(hp[s_t], lp["na"][mp]["w_rel"])
+                z = model.na(dot, lp, i, mp, hs, hp[d_t], src, dst, nv[d_t])
                 incoming.setdefault(d_t, []).append(z)
             nxt = {}
             for t, x in hp.items():

@@ -9,14 +9,21 @@ directory, found by name:
 * ``traffic/<traffic>.json`` -- the traffic mix's parameters;
 * ``workloads/<cell>.json``  -- the cell's correctness limits, with the
   readings each was set from;
-* ``metrics/<metric>.py``    -- a per-layer metric's reader.
+* ``metrics/<metric>.py``    -- a per-layer metric's reader;
+* ``models/<model>.py``      -- what one HGNN model (a configuration's
+  ``model``) changes in the harness: the program's extra ``HGNNConfig``
+  arguments (``program_config``), its NA parameters drawn from the
+  seed's one key stream (``draw_na``, ``draw_layer``), its reference NA
+  of one metapath (``na``), and its NA work counts (``na_flops``,
+  ``na_kernel_calls``).
 
-A cell, mix, configuration or metric is added by adding files and
+A cell, mix, configuration, metric or model is added by adding files and
 ``BENCHMARK.json`` entries; no file here names one.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import re
@@ -43,16 +50,32 @@ def load_json(kind: str, name: str) -> Dict:
         return json.load(f)
 
 
-def load_metric(name: str) -> ModuleType:
-    """A per-layer metric's reader: a module with ``UNIT``, ``LAYER``,
-    ``MOVES`` and ``read(run) -> float | None``."""
+def _load_module(kind: str, name: str) -> ModuleType:
     if not NAME_RE.match(name):
-        raise ValueError(f"bad metric name {name!r}")
-    path = HERE / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"chipbench_metric_{name}", path)
+        raise ValueError(f"bad {kind} name {name!r}")
+    path = HERE / f"{kind}s" / f"{name}.py"
+    spec = importlib.util.spec_from_file_location(f"chipbench_{kind}_{name}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_metric(name: str) -> ModuleType:
+    """A per-layer metric's reader: a module with ``UNIT``, ``LAYER``,
+    ``MOVES`` and ``read(run) -> float | None``."""
+    return _load_module("metric", name)
+
+
+@functools.lru_cache(maxsize=None)
+def load_model(name: str) -> ModuleType:
+    """An HGNN model's file: a module with ``program_config(cfg)``,
+    ``draw_na(keys, cfg, h)``, ``draw_layer(keys, cfg, mps)``,
+    ``na(dot, lp, i, mp, hs, h_dst, src, dst, n_dst)``,
+    ``na_flops(n_src, n_dst, e, h)`` and
+    ``na_kernel_calls(e, u_src, u_dst, h)``.  Loaded once per process, so
+    every caller, and a model file that builds on another, shares one
+    module."""
+    return _load_module("model", name)
 
 
 def _in_cell(metric: Dict, cell: str) -> bool:

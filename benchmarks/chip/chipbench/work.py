@@ -17,6 +17,8 @@ from __future__ import annotations
 
 from typing import Dict, List, Set, Tuple
 
+from chipbench.spec import load_model
+
 F32 = 4
 IDX = 4
 
@@ -34,19 +36,10 @@ def live_types(cfg: Dict) -> List[Set[str]]:
     return out[::-1]
 
 
-def _na_flops(model: str, n_src: int, n_dst: int, e: int, h: int) -> int:
-    f = 2 * n_src * h * h  # relation projection
-    if model == "rgcn":
-        return f + e * h
-    # source and destination logit dots, per-edge logit add/bias/leaky
-    # (3), softmax max/subtract/exp/sum/divide (5), weighted sum (2 h)
-    return f + 2 * n_src * h + 2 * n_dst * h + 8 * e + 2 * e * h
-
-
 def forward_flops(cfg: Dict, nv: Dict[str, int], edges: Dict[str, int]) -> int:
     """Useful FLOPs of one full-graph forward."""
     h, c, att = int(cfg["hidden"]), int(cfg["num_classes"]), int(cfg["sf_att_dim"])
-    model = cfg["model"]
+    model = load_model(cfg["model"])
     total = 0
     for layer, live in enumerate(live_types(cfg)):
         for t in live:
@@ -57,7 +50,7 @@ def forward_flops(cfg: Dict, nv: Dict[str, int], edges: Dict[str, int]) -> int:
         for t in nxt:
             mps = [mp for mp in cfg["metapaths"] if mp[-1] == t]
             for mp in mps:
-                total += _na_flops(model, nv[mp[0]], nv[t], edges[mp], h)
+                total += model.na_flops(nv[mp[0]], nv[t], edges[mp], h)
             total += 2 * nv[t] * h * h  # self path
             if mps:
                 p1 = len(mps) + 1
@@ -71,23 +64,15 @@ def na_kernel_work(cfg: Dict, nv: Dict[str, int],
                    shapes: Dict[str, Tuple[int, int, int]]) -> Dict[str, List[Tuple[int, int]]]:
     """``{kernel: [(flops, bytes), ...]}``, one entry per call in one
     forward.  ``shapes[mp] = (edges, distinct sources, distinct
-    destinations)``; the NA kernels run for every metapath in every
-    layer."""
+    destinations)``; the model's NA kernels (``na_kernel_calls`` of its
+    file) run for every metapath in every layer."""
     h = int(cfg["hidden"])
-    attention = cfg["model"] in ("rgat", "shgn")
-    calls: Dict[str, List[Tuple[int, int]]] = {"na_seg_sum": []}
-    if attention:
-        calls["na_softmax_stats"] = []
+    model = load_model(cfg["model"])
+    calls: Dict[str, List[Tuple[int, int]]] = {}
     for _ in range(int(cfg["num_layers"])):
         for mp in sorted(cfg["metapaths"]):
-            e, u_src, u_dst = shapes[mp]
-            per_edge = 2 * IDX + (F32 if attention else 0)
-            calls["na_seg_sum"].append(
-                (2 * e * h, F32 * h * (u_src + u_dst) + per_edge * e))
-            if attention:
-                # max, subtract, exp, add per edge; m and s per destination
-                calls["na_softmax_stats"].append(
-                    (4 * e, (F32 + IDX) * e + 2 * F32 * u_dst))
+            for kernel, call in model.na_kernel_calls(*shapes[mp], h).items():
+                calls.setdefault(kernel, []).append(call)
     return calls
 
 
