@@ -272,7 +272,8 @@ class CompiledHGNN:
     def _graph(self) -> List[Dict]:
         """The bound graphs' device arrays as one pytree (built once)."""
         if self._graph_arrays is None:
-            self._graph_arrays = graph_arrays(self.graphs)
+            self._graph_arrays = graph_arrays(
+                self.graphs, dense=static_na_weights(self.cfg.model))
         return self._graph_arrays
 
     def _jit_on_graph(self, method):
@@ -707,6 +708,11 @@ class Session:
             raise ValueError(
                 "devices= requires a sharded spec (ExecutorSpec.shard is "
                 "'none'): an unsharded compile has no mesh to pin")
+        if self.spec.shard != "none" and cfg.num_heads > 1:
+            raise ValueError(
+                f"the sharded executor (ExecutorSpec.shard={self.spec.shard!r}) "
+                f"runs one attention head; {cfg.model} asks for "
+                f"num_heads={cfg.num_heads}: compile it unsharded")
         fp = graph.fingerprint()
         devs = self._resolve_devices(devices)
         devkey = (None if devs is None

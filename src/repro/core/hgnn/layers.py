@@ -69,26 +69,46 @@ def edge_softmax_weights(
     return ex / jnp.maximum(s[dst], 1e-9)
 
 
+def head_scores(h: jax.Array, a: jax.Array) -> jax.Array:
+    """Per-vertex attention scores of ``h`` (N, D) against the attention
+    vector ``a``: ``h @ a`` (N,) for a ``(D,)`` vector; for ``(H, D/H)``
+    vectors one column per head, ``(N, H)``, head k's from columns
+    ``k*D/H`` to ``(k+1)*D/H`` (one product with the block-diagonal
+    ``(D, H)`` matrix of the heads' vectors)."""
+    if a.ndim == 1:
+        return h @ a
+    heads, dh = a.shape
+    blocks = a[:, :, None] * jnp.eye(heads, dtype=a.dtype)[:, None, :]  # (H, D/H, H)
+    return h @ blocks.reshape(heads * dh, heads)
+
+
 def na_attention(
     h_src: jax.Array,  # (N_src, D)
     h_dst: jax.Array,  # (N_dst, D) destination-side features for logits
     src: jax.Array,
     dst: jax.Array,
     num_dst: int,
-    a_src: jax.Array,  # (D,) attention vector, source side
-    a_dst: jax.Array,  # (D,) attention vector, destination side
+    a_src: jax.Array,  # (D,) or (H, D/H) attention vectors, source side
+    a_dst: jax.Array,  # (D,) or (H, D/H) attention vectors, destination side
     edge_bias: Optional[jax.Array] = None,  # scalar or (E,) edge-type term (Simple-HGN)
     leaky_slope: float = 0.2,
 ) -> jax.Array:
-    """GAT-style NA (RGAT / Simple-HGN): weighted sum with edge softmax."""
-    e_s = h_src @ a_src  # (N_src,)
-    e_d = h_dst @ a_dst  # (N_dst,)
+    """GAT-style NA (RGAT / Simple-HGN / HAN): weighted sum with edge
+    softmax.  With ``(H, D/H)`` attention vectors each head has its own
+    logits and softmax, and head k's alpha weighs columns ``k*D/H`` to
+    ``(k+1)*D/H``: the heads' outputs concatenated."""
+    e_s = head_scores(h_src, a_src)  # (N_src,) or (N_src, H)
+    e_d = head_scores(h_dst, a_dst)
     logits = e_s[src] + e_d[dst]
     if edge_bias is not None:
         logits = logits + edge_bias
     logits = jax.nn.leaky_relu(logits, leaky_slope)
     alpha = edge_softmax_weights(logits, dst, num_dst)
-    weighted = h_src[src] * alpha[:, None]
+    if alpha.ndim == 1:
+        weighted = h_src[src] * alpha[:, None]
+    else:
+        weighted = h_src[src] * jnp.repeat(alpha, h_src.shape[1] // alpha.shape[1],
+                                           axis=1)
     return jax.ops.segment_sum(weighted, dst, num_segments=num_dst)
 
 
@@ -115,17 +135,18 @@ def na_attention_banded(
 ) -> jax.Array:
     """GAT-style NA on the attention kernels (``kernels.ops.na_attention_packed``).
 
-    Same math as ``na_attention``, with only per-vertex work outside the
-    kernels: the two score columns ``h_src @ a_src`` and ``h_dst @ a_dst``,
-    the scalar bias folded into the destination's.  The logits, the
-    online (m, s) stats and alpha are made per edge slot inside the
-    kernels, from the packing's blocks.
+    Same math as ``na_attention``, heads included, with only per-vertex
+    work outside the kernels: the two score columns (:func:`head_scores`
+    of ``h_src`` and ``h_dst``, one column per head), the scalar bias
+    folded into the destination's.  The logits, the online (m, s) stats
+    and alpha are made per edge slot inside the kernels, from the
+    packing's blocks.
     """
-    e_d = h_dst @ a_dst
+    e_d = head_scores(h_dst, a_dst)
     if edge_bias is not None:
         e_d = e_d + edge_bias
-    return na_attention_packed(packed, h_src @ a_src, e_d, h_src, leaky_slope,
-                               backend=backend)
+    return na_attention_packed(packed, head_scores(h_src, a_src), e_d, h_src,
+                               leaky_slope, backend=backend)
 
 
 def semantic_fusion_beta(

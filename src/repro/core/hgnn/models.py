@@ -1,11 +1,13 @@
-"""RGCN / RGAT / Simple-HGN on semantic graphs — the paper's GFP workload.
+"""RGCN / RGAT / Simple-HGN / HAN on semantic graphs — the paper's GFP
+workload.
 
 The model consumes the output of the SGB stage: a list of semantic graphs
 (directed bipartite edge sets between vertex types).  Per layer:
 
   FP  — per-vertex-type dense projection,
   NA  — per-semantic-graph aggregation (mean for RGCN, edge-softmax
-        attention for RGAT / Simple-HGN with an edge-type embedding term),
+        attention for RGAT / Simple-HGN with an edge-type embedding term,
+        and HAN's multi-head node-level attention),
   SF  — HAN-style semantic attention fusing all semantic graphs that end at
         the same destination type (plus a self/residual path).
 
@@ -15,8 +17,9 @@ lists for ``SemanticGraphBatch`` es, the banded Pallas kernels for
 ``BandedBatch`` es (each in a full-graph and a dependency-subset form), and
 the merged multi-relation step of the sharded executor
 (``repro.distributed.hgnn``).  Every NA step takes its inputs from
-:func:`na_inputs`; :func:`static_na_weights` is the one place on the
-forward path that reads the model's name.
+:func:`na_inputs` and ends in :func:`na_output`; :data:`NA_FORMS`, the
+table of what each model's NA computes, is the one place on the forward
+path that reads the model's name.
 
 Paper §5.3 configuration: hidden 64, layers {3: RGAT, 3: RGCN, 2: S-HGN}.
 """
@@ -92,7 +95,7 @@ class SemanticGraphBatch:
             edge_type_id=edge_type_id,
         )
 
-    def device_arrays(self) -> Dict:
+    def device_arrays(self, dense: bool = True) -> Dict:
         """The batch's device arrays as one pytree: its edge list."""
         return {"src": self.src, "dst": self.dst}
 
@@ -151,10 +154,10 @@ class BandedBatch:
             deg=jnp.asarray(deg),
         )
 
-    def device_arrays(self) -> Dict:
+    def device_arrays(self, dense: bool = True) -> Dict:
         """The batch's device arrays as one pytree: the packing's
         (``PackedEdges.device_arrays``) and the permutations and degrees."""
-        return {"packed": self.packed.device_arrays(),
+        return {"packed": self.packed.device_arrays(dense),
                 **{f: getattr(self, f) for f in _BANDED_ARRAYS}}
 
     def bind(self, arrays: Dict) -> "BandedBatch":
@@ -167,13 +170,15 @@ class BandedBatch:
 _BANDED_ARRAYS = ("src_gather", "dst_gather", "dst_scatter", "deg")
 
 
-def graph_arrays(graphs: List) -> List[Dict]:
+def graph_arrays(graphs: List, dense: bool = True) -> List[Dict]:
     """The device arrays of ``graphs`` as one pytree, for a jitted function
     that takes the graph as an argument and binds it (:func:`bind_graphs`):
     the graph is then data of the compiled program, not constants baked
     into it.  No forward reads a packing's edge maps, so the pytree leaves
-    them out (a backward pass uploads them on first use)."""
-    return [g.device_arrays() for g in graphs]
+    them out (a backward pass uploads them on first use); ``dense=False``
+    leaves out the dense tiles too, which only static weights read
+    (:func:`static_na_weights`)."""
+    return [g.device_arrays(dense) for g in graphs]
 
 
 def bind_graphs(graphs: List, arrays: List[Dict]) -> List:
@@ -183,18 +188,48 @@ def bind_graphs(graphs: List, arrays: List[Dict]) -> List:
     return [g.bind(a) for g, a in zip(graphs, arrays)]
 
 
+class NAForm(NamedTuple):
+    """What one model's NA step computes (:data:`NA_FORMS`)."""
+
+    attention: bool  # edge-softmax attention, else the degree-normalised mean
+    edge_bias: bool = False  # Simple-HGN's per-metapath edge-type term
+    project_dst: bool = False  # logits on the w_rel-projected destination (HAN)
+    elu: bool = False  # ELU on the heads' concatenated output (HAN)
+
+
+# Per model: RGCN (Schlichtkrull et al. 2018), RGAT, Simple-HGN (Lv et al.
+# 2021) and HAN (Wang et al. 2019, arXiv:1903.07293), whose node-level
+# attention scores W_k h_i against W_k h_j and passes each head's sum
+# through an ELU.
+NA_FORMS: Dict[str, NAForm] = {
+    "rgcn": NAForm(attention=False),
+    "rgat": NAForm(attention=True),
+    "shgn": NAForm(attention=True, edge_bias=True),
+    "han": NAForm(attention=True, project_dst=True, elu=True),
+}
+
+
 @dataclasses.dataclass(frozen=True)
 class HGNNConfig:
-    model: str  # "rgcn" | "rgat" | "shgn"
+    model: str  # a key of NA_FORMS: "rgcn" | "rgat" | "shgn" | "han"
     hidden: int = 64
     num_layers: int = 3
     num_classes: int = 3
     target_type: str = "P"
     edge_emb_dim: int = 16  # Simple-HGN edge-type embedding
     sf_att_dim: int = 64
+    num_heads: int = 1  # attention heads, each over hidden // num_heads columns
 
     def __post_init__(self):
-        assert self.model in ("rgcn", "rgat", "shgn"), self.model
+        if self.model not in NA_FORMS:
+            raise ValueError(f"unknown model {self.model!r} (expected one of "
+                             f"{sorted(NA_FORMS)})")
+        if self.num_heads < 1 or self.hidden % self.num_heads:
+            raise ValueError(f"num_heads {self.num_heads} must divide hidden "
+                             f"{self.hidden}")
+        if self.num_heads > 1 and not NA_FORMS[self.model].attention:
+            raise ValueError(f"{self.model} has no attention to split into "
+                             f"{self.num_heads} heads")
 
 
 def _dense_init(key, d_in, d_out, scale=None):
@@ -213,6 +248,7 @@ def init_params(
     dim (0 = featureless type: gets a learned embedding-like projection of a
     one-hot degree bucket; we give it a single learned vector)."""
     h = hidden_override or cfg.hidden
+    form, heads = NA_FORMS[cfg.model], cfg.num_heads
     params: Dict = {"layers": []}
     types = sorted(feature_dims)
     for layer in range(cfg.num_layers):
@@ -233,11 +269,12 @@ def init_params(
                 }
         for mp in metapaths:
             na: Dict = {"w_rel": _dense_init(next(ki), h, h)}
-            if cfg.model in ("rgat", "shgn"):
-                na["a_src"] = jax.random.normal(next(ki), (h,)) * 0.1
-                na["a_dst"] = jax.random.normal(next(ki), (h,)) * 0.1
+            if form.attention:  # (h,) for one head, else (heads, h / heads)
+                shape = (h,) if heads == 1 else (heads, h // heads)
+                na["a_src"] = jax.random.normal(next(ki), shape) * 0.1
+                na["a_dst"] = jax.random.normal(next(ki), shape) * 0.1
             lp["na"][mp] = na
-        if cfg.model == "shgn":
+        if form.edge_bias:
             lp["edge_emb"] = jax.random.normal(next(ki), (len(metapaths), cfg.edge_emb_dim)) * 0.1
             lp["a_edge"] = jax.random.normal(next(ki), (cfg.edge_emb_dim,)) * 0.1
         for t in types:
@@ -258,9 +295,8 @@ def init_params(
 
 def static_na_weights(model: str) -> bool:
     """Whether ``model``'s NA aggregates with the packing's static weights
-    (the mean model; attention weights are traced).  The one place on the
-    forward path that reads the model's name."""
-    return model == "rgcn"
+    (the mean model; attention weights are traced)."""
+    return not NA_FORMS[model].attention
 
 
 class NAInputs(NamedTuple):
@@ -282,16 +318,29 @@ class NAInputs(NamedTuple):
 
 def na_inputs(cfg: HGNNConfig, lp: Dict, g, hp: Dict[str, jax.Array]) -> NAInputs:
     """The NA inputs of batch ``g`` in a layer with params ``lp`` and FP
-    outputs ``hp``; every NA step takes its inputs from here.  Simple-HGN's
-    edge-type term is there where the layer holds an edge-type embedding."""
+    outputs ``hp``; every NA step takes its inputs from here, and its
+    output goes through :func:`na_output`.  Simple-HGN's edge-type term is
+    there where the layer holds an edge-type embedding; HAN's logits read
+    the destination projected by ``w_rel`` (the sources' own rows where
+    the metapath starts and ends at one type)."""
     na_p = lp["na"][g.metapath]
     h_src = hp[g.src_type] @ na_p["w_rel"]
-    if static_na_weights(cfg.model):
+    form = NA_FORMS[cfg.model]
+    if not form.attention:
         return NAInputs(h_src)
     bias = None
     if "edge_emb" in lp:
         bias = lp["edge_emb"][g.edge_type_id] @ lp["a_edge"]  # one scalar per metapath
-    return NAInputs(h_src, hp[g.dst_type], na_p["a_src"], na_p["a_dst"], bias)
+    h_dst = hp[g.dst_type]
+    if form.project_dst:
+        h_dst = h_src if g.dst_type == g.src_type else h_dst @ na_p["w_rel"]
+    return NAInputs(h_src, h_dst, na_p["a_src"], na_p["a_dst"], bias)
+
+
+def na_output(cfg: HGNNConfig, z: jax.Array) -> jax.Array:
+    """The last op of every NA step: HAN's ELU over the heads' outputs
+    (elementwise, so per head), else ``z`` as it is."""
+    return jax.nn.elu(z) if NA_FORMS[cfg.model].elu else z
 
 
 def _na_edges(x: NAInputs, src: jax.Array, dst: jax.Array, num_dst: int) -> jax.Array:
@@ -414,7 +463,7 @@ class HGNN:
             zs = []
             for g in graphs:
                 with obs.scope(obs.na_scope(li, g.metapath)):
-                    zs.append(step(g, na_inputs(cfg, lp, g, hp)))
+                    zs.append(na_output(cfg, step(g, na_inputs(cfg, lp, g, hp))))
             return zs
 
         return aggregate

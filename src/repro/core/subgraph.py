@@ -458,33 +458,52 @@ def na_subset_banded(packed, dg: Dict, x, leaky_slope: float = 0.2,
     alpha into the blocked layout: pad edges are masked to ``-1e30``
     before the stats and their alpha is zeroed, and the scatter *adds* so
     pad slots (all aliased to (pad block, 0)) can never clobber a real
-    weight.
+    weight.  With H heads each head's alpha weighs its own ``D/H``
+    columns, one kernel call per head.
     """
+    from repro.core.hgnn.layers import head_scores
+
     td, sb = packed.dst_tile_rows, packed.src_band
     hb = x.h_src[dg["src_rows"]]
     num_tiles = dg["dst_rows"].shape[0] // td
     num_rows = num_tiles * td
-    weight = dg["weight"]
-    if x.attention:
+    interpret = use_interpret(backend)
+
+    def aggregate(weight, h):
+        return _seg_sum_call(
+            dg["band"], dg["dtile"], dg["first"], dg["srcl"], dg["dstl"],
+            weight, h, num_dst_tiles=num_tiles, src_band=sb,
+            dst_tile_rows=td, interpret=interpret)
+
+    if not x.attention:
+        out = aggregate(dg["weight"], hb)
+        deg = jnp.zeros((num_rows,), jnp.float32).at[dg["e_dst"]].add(
+            dg["e_valid"])
+        out = out / jnp.maximum(deg, 1.0)[:, None]
+    else:
         hd = x.h_dst[dg["dst_rows"]]
-        logits = (hb @ x.a_src)[dg["e_src"]] + (hd @ x.a_dst)[dg["e_dst"]]
+        logits = (head_scores(hb, x.a_src)[dg["e_src"]]
+                  + head_scores(hd, x.a_dst)[dg["e_dst"]])  # (E,) or (E, H)
         if x.bias is not None:
             logits = logits + x.bias
         logits = jax.nn.leaky_relu(logits, leaky_slope)
-        logits = jnp.where(dg["e_valid"] > 0, logits, -1e30)
+        e_valid = dg["e_valid"] if logits.ndim == 1 else dg["e_valid"][:, None]
+        logits = jnp.where(e_valid > 0, logits, -1e30)
         m = jax.ops.segment_max(logits, dg["e_dst"], num_segments=num_rows)
         m = jnp.where(jnp.isfinite(m), m, 0.0)
         ex = jnp.exp(logits - m[dg["e_dst"]])
         s = jax.ops.segment_sum(ex, dg["e_dst"], num_segments=num_rows)
-        alpha = ex / jnp.maximum(s[dg["e_dst"]], 1e-9) * dg["e_valid"]
-        weight = jnp.zeros(dg["srcl"].shape, jnp.float32).at[
-            dg["e_blk"], dg["e_slot"]].add(alpha)
-    out = _seg_sum_call(
-        dg["band"], dg["dtile"], dg["first"], dg["srcl"], dg["dstl"],
-        weight, hb, num_dst_tiles=num_tiles, src_band=sb,
-        dst_tile_rows=td, interpret=use_interpret(backend))
-    if not x.attention:
-        deg = jnp.zeros((num_rows,), jnp.float32).at[dg["e_dst"]].add(
-            dg["e_valid"])
-        out = out / jnp.maximum(deg, 1.0)[:, None]
+        alpha = ex / jnp.maximum(s[dg["e_dst"]], 1e-9) * e_valid
+
+        def blocked(a):
+            return jnp.zeros(dg["srcl"].shape, jnp.float32).at[
+                dg["e_blk"], dg["e_slot"]].add(a)
+
+        if alpha.ndim == 1:
+            out = aggregate(blocked(alpha), hb)
+        else:
+            dh = hb.shape[1] // alpha.shape[1]
+            out = jnp.concatenate(
+                [aggregate(blocked(alpha[:, k]), hb[:, k * dh:(k + 1) * dh])
+                 for k in range(alpha.shape[1])], axis=1)
     return out[dg["dst_pick"]] * dg["pick_valid"][:, None]

@@ -52,7 +52,7 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro import obs
-from repro.core.hgnn.models import HGNN, BandedBatch, na_inputs
+from repro.core.hgnn.models import HGNN, BandedBatch, na_inputs, na_output
 from repro.kernels.backend import use_interpret
 from repro.kernels.edge_softmax import edge_softmax_stats_blocks
 from repro.kernels.seg_sum import seg_sum_blocks, shard_blocked
@@ -478,7 +478,7 @@ class ShardedHGNNExecutor:
                 zb = z_all[lo : lo + g.num_dst]
                 if not attention:
                     zb = zb / jnp.maximum(g.deg, 1.0)[:, None]
-                zs.append(zb[g.dst_scatter])
+                zs.append(na_output(cfg, zb[g.dst_scatter]))
         return zs
 
     def _build_forward(self):

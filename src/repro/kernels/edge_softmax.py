@@ -16,8 +16,11 @@ Two kernels, one name (``na_softmax_stats``):
     slot's logit itself, ``leaky_relu(e_s[src] + e_d[dst])``, from the
     per-vertex score columns of the block's source band and destination
     tile, and writes the blocked logits out for the aggregation kernel
-    (``seg_sum._attn_na_kernel``), which forms alpha from them and the
-    final (m, s): no per-edge array is built outside the two kernels.
+    (``seg_sum.attention_seg_sum_call``), which forms alpha from them and
+    the final (m, s): no per-edge array is built outside the two kernels.
+    With H heads (HAN's eight) the columns, (m, s) and logits carry one
+    column or row per head, and ``_attn_stats_heads_kernel`` does the
+    one-hot selections as MXU products (``attention_stats_call`` picks).
   * ``_stats_kernel`` reads logits already in the blocked layout: the
     sharded executor's merged multi-relation stream
     (``edge_softmax_stats_blocks``) and the flat-logit reference path
@@ -140,6 +143,57 @@ def _attn_stats_kernel(
     m_ref[...] = m_new
 
 
+def _attn_stats_heads_kernel(
+    band_ref, dtile_ref, first_ref,  # scalar-prefetch
+    srcl_ref, dstl_ref, valid_ref,  # (1, EB) rows of block i
+    es_ref,  # (BAND, H): e_s over block i's source band, a column per head
+    ed_ref,  # (TD, H): e_d over block i's destination tile
+    m_ref, s_ref,  # (TD, H) accumulators: one row per destination
+    logit_ref,  # (H, EB): block i's logits, a row per head
+    *, eb: int, band: int, td: int, slope: float,
+):
+    """``_attn_stats_kernel`` for H > 1 heads, every head's statistics in
+    one step.  The one-hot selections run on the MXU at ``HIGHEST``, each
+    a single term per output and so exact in f32: ``e_s[src]`` and
+    ``e_d[dst]`` of every head, ``m[dst]`` and each destination's sum of
+    ``exp``.  Only the per-destination max stays a masked reduction, one
+    per head."""
+    i = pl.program_id(0)
+    heads = es_ref.shape[1]
+
+    @pl.when(first_ref[i] == 1)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, _NEG)
+        s_ref[...] = jnp.zeros_like(s_ref)
+
+    hi = jax.lax.Precision.HIGHEST
+    tn = (((0,), (0,)), ((), ()))  # contract the leading dims: a^T @ b
+    nt = (((1,), (1,)), ((), ()))  # contract the trailing dims: a @ b^T
+    valid = valid_ref[...] > 0  # (1, EB)
+    scat = jax.lax.broadcasted_iota(jnp.int32, (td, eb), 0) == dstl_ref[...]
+    sel = jax.lax.broadcasted_iota(jnp.int32, (band, eb), 0) == srcl_ref[...]
+    pre = (jax.lax.dot_general(es_ref[...], sel.astype(jnp.float32), tn, precision=hi)
+           + jax.lax.dot_general(ed_ref[...], scat.astype(jnp.float32), tn,
+                                 precision=hi))  # (H, EB)
+    logit = jnp.where(valid, jnp.where(pre >= 0, pre, slope * pre), _NEG)
+    logit_ref[...] = logit
+    eff = scat & valid  # (TD, EB): edge e lands on row t
+    head = jax.lax.broadcasted_iota(jnp.int32, (td, heads), 1)
+    blockmax = jnp.full((td, heads), _NEG, jnp.float32)
+    for k in range(heads):
+        col = jnp.max(jnp.where(eff, logit[k:k + 1, :], _NEG), axis=1, keepdims=True)
+        blockmax = jnp.where(head == k, col, blockmax)
+    m_old = m_ref[...]
+    m_new = jnp.maximum(m_old, blockmax)
+    scale = jnp.where(m_old > _NEG / 2, jnp.exp(m_old - m_new), 0.0)
+    onehot = eff.astype(jnp.float32)
+    m_e = jax.lax.dot_general(m_new, onehot, tn, precision=hi)  # (H, EB)
+    ex = jnp.where(valid, jnp.exp(logit - m_e), 0.0)
+    s_add = jax.lax.dot_general(onehot, ex, nt, precision=hi)  # (TD, H)
+    s_ref[...] = s_ref[...] * scale + s_add
+    m_ref[...] = m_new
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("num_dst_tiles", "src_band", "dst_tile_rows", "slope",
@@ -149,32 +203,35 @@ def attention_stats_call(band, dst_tile, first, src_local, dst_local, valid,
                          e_s, e_d, num_dst_tiles, src_band, dst_tile_rows,
                          slope, interpret):
     """The attention path's stats kernel over a packing's block arrays
-    (``(nb, 1, EB)`` rows) and the ``(rows, 1)`` score columns ``e_s``
+    (``(nb, 1, EB)`` rows) and the ``(rows, H)`` score columns ``e_s``
     (``src_band`` rows per band) and ``e_d`` (``dst_tile_rows`` per
-    tile, any bias folded in).  Returns the ``(num_dst_tiles *
-    dst_tile_rows, 1)`` columns (m, s), whose rows of tiles no block
-    touches hold uninitialized memory, and the ``(nb, 1, EB)`` blocked
-    logits, ``-1e30`` on padding slots."""
+    tile, any bias folded in), one column per head.  Returns the
+    ``(num_dst_tiles * dst_tile_rows, H)`` columns (m, s), whose rows of
+    tiles no block touches hold uninitialized memory, and the ``(nb, H,
+    EB)`` blocked logits, ``-1e30`` on padding slots.  One head runs
+    ``_attn_stats_kernel``, more ``_attn_stats_heads_kernel``."""
     nb, eb = src_local.shape[0], src_local.shape[-1]
     td = dst_tile_rows
+    heads = e_s.shape[1]
     row = pl.BlockSpec((None, 1, eb), lambda i, b, t, f: (i, 0, 0))
-    col = pl.BlockSpec((td, 1), lambda i, b, t, f: (t[i], 0))
+    col = pl.BlockSpec((td, heads), lambda i, b, t, f: (t[i], 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(nb,),
         in_specs=[row, row, row,
-                  pl.BlockSpec((src_band, 1), lambda i, b, t, f: (b[i], 0)),
+                  pl.BlockSpec((src_band, heads), lambda i, b, t, f: (b[i], 0)),
                   col],
-        out_specs=[col, col, row],
+        out_specs=[col, col,
+                   pl.BlockSpec((None, heads, eb), lambda i, b, t, f: (i, 0, 0))],
     )
-    kern = functools.partial(_attn_stats_kernel, eb=eb, band=src_band, td=td,
-                             slope=slope)
-    stats = jax.ShapeDtypeStruct((num_dst_tiles * td, 1), jnp.float32)
+    body = _attn_stats_kernel if heads == 1 else _attn_stats_heads_kernel
+    kern = functools.partial(body, eb=eb, band=src_band, td=td, slope=slope)
+    stats = jax.ShapeDtypeStruct((num_dst_tiles * td, heads), jnp.float32)
     return pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=[stats, stats,
-                   jax.ShapeDtypeStruct((nb, 1, eb), jnp.float32)],
+                   jax.ShapeDtypeStruct((nb, heads, eb), jnp.float32)],
         interpret=interpret,
         name="na_softmax_stats",
     )(band, dst_tile, first, src_local, dst_local, valid, e_s, e_d)

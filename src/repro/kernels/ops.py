@@ -176,24 +176,26 @@ def na_aggregate(
 def _build_attention_packed_vjp(packed: PackedEdges, interpret: bool,
                                 slope: float):
     """``custom_vjp``-wrapped attention NA for one packing, over the
-    per-vertex score columns ``e_s`` and ``e_d`` and the features ``h``,
-    each padded to the rows the kernels index.
+    per-vertex score columns ``e_s`` and ``e_d`` (``(rows, H)``, a column
+    per head) and the features ``h``, each padded to the rows the kernels
+    index.
 
     Forward is the two kernels and nothing per edge between them: the
     stats kernel builds each slot's logit ``leaky_relu(e_s[src] +
-    e_d[dst])`` from the columns, folds it into the online (m, s) and
-    writes the blocked logits; the aggregation kernel forms alpha from
-    those and the final (m, s) and weighs the scatter with it.  The
-    backward pass reuses the packing's flat edge map (uploaded on first
-    use; the forward never reads it) and the forward's (m, s) to
-    recompute alpha, then scatters cotangents with jnp segment-adds:
+    e_d[dst])`` per head from the columns, folds it into the online (m,
+    s) and writes the blocked logits; the aggregation kernel forms alpha
+    from those and the final (m, s) and weighs the scatter with it, head
+    k's alpha on head k's ``D/H`` columns.  The backward pass reuses the
+    packing's flat edge map (uploaded on first use; the forward never
+    reads it) and the forward's (m, s) to recompute alpha, then scatters
+    cotangents with jnp segment-adds, per head k and its columns c(k):
 
-        grad_alpha_e = h[src_e] . g_out[dst_e]
-        grad_logit_e = alpha_e (grad_alpha_e - t[dst_e]),
-                       t[d] = sum_{e: dst_e=d} alpha_e grad_alpha_e
-        grad_pre_e   = grad_logit_e * leaky_relu'(pre_e)
-        grad_e_s[s]  = sum_{e: src_e=s} grad_pre_e   (grad_e_d likewise)
-        grad_h[s]    = sum_{e: src_e=s} alpha_e g_out[dst_e]
+        grad_alpha_ek = h[src_e, c(k)] . g_out[dst_e, c(k)]
+        grad_logit_ek = alpha_ek (grad_alpha_ek - t[dst_e, k]),
+                        t[d, k] = sum_{e: dst_e=d} alpha_ek grad_alpha_ek
+        grad_pre_ek   = grad_logit_ek * leaky_relu'(pre_ek)
+        grad_e_s[s, k] = sum_{e: src_e=s} grad_pre_ek   (grad_e_d likewise)
+        grad_h[s, c(k)] = sum_{e: src_e=s} alpha_ek g_out[dst_e, c(k)]
     """
     geometry = dict(num_dst_tiles=packed.num_dst_tiles, src_band=packed.src_band,
                     dst_tile_rows=packed.dst_tile_rows, interpret=interpret)
@@ -214,19 +216,21 @@ def _build_attention_packed_vjp(packed: PackedEdges, interpret: bool,
 
     def bwd(res, g_out):
         e_s, e_d, m, s, h = res
+        heads = e_s.shape[1]
         src_g, dst_g = packed.device_flat_edges()
-        pre = e_s[src_g, 0] + e_d[dst_g, 0]
+        pre = e_s[src_g] + e_d[dst_g]  # (E, H)
         logit = jnp.where(pre >= 0, pre, slope * pre)
-        alpha = jnp.exp(logit - m[dst_g, 0]) / jnp.maximum(s[dst_g, 0], 1e-9)
+        alpha = jnp.exp(logit - m[dst_g]) / jnp.maximum(s[dst_g], 1e-9)
         g_e = g_out[dst_g]  # (E, D)
-        grad_alpha = jnp.sum(h[src_g].astype(jnp.float32) * g_e, axis=1)
-        t = jnp.zeros((g_out.shape[0],), jnp.float32).at[dst_g].add(
-            alpha * grad_alpha)
+        n_e, d = g_e.shape
+        grad_alpha = jnp.sum((h[src_g].astype(jnp.float32) * g_e).reshape(
+            n_e, heads, d // heads), axis=2)
+        t = jnp.zeros(m.shape, jnp.float32).at[dst_g].add(alpha * grad_alpha)
         grad_pre = alpha * (grad_alpha - t[dst_g]) * jnp.where(pre >= 0, 1.0, slope)
-        grad_e_s = jnp.zeros_like(e_s).at[src_g, 0].add(grad_pre)
-        grad_e_d = jnp.zeros_like(e_d).at[dst_g, 0].add(grad_pre)
-        grad_h = jnp.zeros_like(h).at[src_g].add(
-            (alpha[:, None] * g_e).astype(h.dtype))
+        grad_e_s = jnp.zeros_like(e_s).at[src_g].add(grad_pre)
+        grad_e_d = jnp.zeros_like(e_d).at[dst_g].add(grad_pre)
+        alpha_cols = jnp.repeat(alpha, d // heads, axis=1)  # (E, D)
+        grad_h = jnp.zeros_like(h).at[src_g].add((alpha_cols * g_e).astype(h.dtype))
         return grad_e_s, grad_e_d, grad_h
 
     attention.defvjp(fwd, bwd)
@@ -251,8 +255,8 @@ def attention_packed_vjp(packed: PackedEdges, interpret: bool, slope: float):
 
 def na_attention_packed(
     packed: PackedEdges,
-    e_s: jax.Array,  # (N_src,) source scores h_src @ a_src, src numbering
-    e_d: jax.Array,  # (N_dst,) destination scores h_dst @ a_dst (+ bias)
+    e_s: jax.Array,  # (N_src,) or (N_src, H) source scores, src numbering
+    e_d: jax.Array,  # (N_dst,) or (N_dst, H) destination scores (+ bias)
     h: jax.Array,  # (N_src, D) features in the packing's src numbering
     leaky_slope: float = 0.2,
     backend: Optional[str] = None,
@@ -261,21 +265,29 @@ def na_attention_packed(
 
     Edge e's logit is ``leaky_relu(e_s[src_e] + e_d[dst_e])`` (a
     per-metapath bias belongs in ``e_d``); alpha is its softmax over the
-    destination's in-edges, and ``out[d] = sum_e alpha_e h[src_e]``.  The
-    scores go to the kernels as ``(rows, 1)`` columns, and the logits,
-    the (m, s) stats and alpha are all made inside the two Pallas
-    kernels: nothing per edge is built around them.  Differentiable in
-    ``e_s``, ``e_d`` and ``h`` (see ``_build_attention_packed_vjp``).
-    Kernel backends only ("pallas" / "interpret"); the jnp oracle needs
-    the flat edge list and lives in ``na_attention_aggregate``.
+    destination's in-edges, and ``out[d] = sum_e alpha_e h[src_e]``.  With
+    ``(N, H)`` scores each of the H heads has its own logits and softmax,
+    and head k's alpha weighs columns ``k*D/H`` to ``(k+1)*D/H``.  The
+    scores go to the kernels as ``(rows, H)`` columns (``(rows, 1)`` for
+    one head), and the logits, the (m, s) stats and alpha are all made
+    inside the two Pallas kernels: nothing per edge is built around them.
+    Differentiable in ``e_s``, ``e_d`` and ``h`` (see
+    ``_build_attention_packed_vjp``).  Kernel backends only ("pallas" /
+    "interpret"); the jnp oracle needs the flat edge list and lives in
+    ``na_attention_aggregate``.
     """
     assert backend != "jnp", "na_attention_packed is the kernel path"
     if not packed.num_blocks:
         return jnp.zeros((packed.num_dst, h.shape[1]), h.dtype)
     fn = attention_packed_vjp(packed, use_interpret(backend), leaky_slope)
     n_dst_pad = packed.num_dst_tiles * packed.dst_tile_rows
-    e_s = src_rows(packed, jnp.asarray(e_s, jnp.float32)[:, None])
-    e_d = jnp.asarray(e_d, jnp.float32)[:, None]
+
+    def columns(x):  # (rows, H); one head's (rows,) scores as (rows, 1)
+        x = jnp.asarray(x, jnp.float32)
+        return x if x.ndim == 2 else x[:, None]
+
+    e_s = src_rows(packed, columns(e_s))
+    e_d = columns(e_d)
     e_d = jnp.pad(e_d, ((0, n_dst_pad - e_d.shape[0]), (0, 0)))
     return dst_rows(packed, fn(e_s, e_d, src_rows(packed, h)))
 
@@ -292,7 +304,8 @@ def na_attention_aggregate(
     packed: Optional[PackedEdges] = None,
 ) -> jax.Array:
     """Edge-softmax attention NA with logits ``leaky_relu(e_s[src] +
-    e_d[dst])`` (see :func:`na_attention_packed`); returns (num_dst, D).
+    e_d[dst])`` (see :func:`na_attention_packed`, and for ``(N, H)``
+    scores its heads); returns (num_dst, D).
 
     ``packed`` supplies a cached packing of the (src, dst) stream (parity
     with ``na_aggregate``) — without it the stream is packed on the spot.

@@ -15,9 +15,12 @@ def seg_sum_na_ref(
     num_dst: int,
     weight: Optional[np.ndarray] = None,
 ) -> jax.Array:
-    """Weighted gather + segment-sum (the NA aggregation oracle)."""
+    """Weighted gather + segment-sum (the NA aggregation oracle).  A
+    ``(E, H)`` ``weight`` holds one weight per head: head k's on columns
+    ``k*D/H`` to ``(k+1)*D/H``."""
     w = jnp.ones((src.shape[0],), h.dtype) if weight is None else jnp.asarray(weight, h.dtype)
-    gathered = h[jnp.asarray(src)] * w[:, None]
+    w = w[:, None] if w.ndim == 1 else jnp.repeat(w, h.shape[1] // w.shape[1], axis=1)
+    gathered = h[jnp.asarray(src)] * w
     return jax.ops.segment_sum(gathered, jnp.asarray(dst), num_segments=num_dst)
 
 

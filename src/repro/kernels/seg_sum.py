@@ -50,11 +50,14 @@ what reads the device trace by kernel name (its roofline share, the
 kernel stage of the forward) reads either format.
 
 The attention path has an edge-block kernel of its own, also named
-``na_seg_sum`` (``_attn_na_kernel``, ``attention_seg_sum_call``): it
-reads the blocked logits and the (TD, 1) softmax stats columns of the
-block's tile that ``edge_softmax.attention_stats_call`` wrote, forms
-each slot's alpha and weighs the scatter one-hot's columns with it, so
-no per-edge weight array is built outside the kernels.
+``na_seg_sum`` (``attention_seg_sum_call``): it reads the blocked logits
+and the (TD, H) softmax stats columns of the block's tile that
+``edge_softmax.attention_stats_call`` wrote, one per head, forms each
+slot's alpha and weighs the scatter with it, so no per-edge weight array
+is built outside the kernels.  One head runs ``_attn_na_kernel``, which
+weighs the scatter one-hot's columns; H heads run
+``_attn_na_heads_kernel``, which weighs each gathered column by the
+alpha of its own head.
 
 ``seg_sum_na`` is differentiable: a ``jax.custom_vjp`` wraps the Pallas
 call, and the backward pass is a gather through the same cached
@@ -336,12 +339,12 @@ class PackedEdges:
         """Device-resident ``dense_tiles()`` (uploaded once)."""
         return self._device("_device_dense", self.dense_tiles)
 
-    def device_arrays(self) -> Dict[str, object]:
+    def device_arrays(self, dense: bool = True) -> Dict[str, object]:
         """Every device array the NA kernels read from this packing, as one
         pytree: the block arrays, the valid mask, the weights of a
-        weighted packing and the dense tiles of a packing in the dense
-        format.  No forward reads the edge map or the flat edges; a
-        backward pass uploads them on first use.
+        weighted packing and, unless ``dense`` is false, the dense tiles
+        of a packing in the dense format.  No forward reads the edge map
+        or the flat edges; a backward pass uploads them on first use.
 
         A jitted function that takes this pytree as an argument and
         :meth:`bind` s it runs on the packing without holding its arrays as
@@ -349,7 +352,7 @@ class PackedEdges:
         names = ["blocked", "valid"]
         if self.weight is not None:
             names.append("weight")
-        if self.dense_format:
+        if dense and self.dense_format:
             names.append("dense")
         return {n: getattr(self, _DEVICE[n][0])() for n in names}
 
@@ -793,6 +796,48 @@ def _attn_na_kernel(
     out_ref[...] += contrib.astype(out_ref.dtype)
 
 
+def _attn_na_heads_kernel(
+    band_ref, dtile_ref, first_ref,  # scalar-prefetch (SMEM)
+    srcl_ref, dstl_ref, valid_ref,  # (1, EB) rows of block i
+    logit_ref,  # (H, EB) logits of block i, a row per head
+    m_ref, s_ref,  # (TD, H) final softmax stats of block i's tile
+    h_ref,  # (BAND, D) feature band; head k owns columns k*D/H:(k+1)*D/H
+    out_ref,  # (TD, D) output tile
+    *, eb: int, band: int, td: int,
+):
+    """``_attn_na_kernel`` for H > 1 heads: each output column weighed by
+    the alpha of its own head.  ``m[dst]``, ``s[dst]`` and each column's
+    alpha are one-hot products on the MXU at ``HIGHEST`` (one term each,
+    so exact in f32); the weighted rows then scatter as in the mean
+    kernel."""
+    i = pl.program_id(0)
+    heads, d = m_ref.shape[1], out_ref.shape[1]
+
+    @pl.when(first_ref[i] == 1)
+    def _zero():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    hi = jax.lax.Precision.HIGHEST
+    tn = (((0,), (0,)), ((), ()))  # contract the leading dims: a^T @ b
+    scat = (jax.lax.broadcasted_iota(jnp.int32, (td, eb), 0)
+            == dstl_ref[...]).astype(jnp.float32)
+    m_e = jax.lax.dot_general(m_ref[...], scat, tn, precision=hi)  # (H, EB)
+    s_e = jax.lax.dot_general(s_ref[...], scat, tn, precision=hi)
+    alpha = jnp.where(valid_ref[...] > 0,
+                      jnp.exp(logit_ref[...] - m_e) / jnp.maximum(s_e, 1e-9),
+                      0.0)  # (H, EB)
+    own = (jax.lax.broadcasted_iota(jnp.int32, (heads, d), 1) // (d // heads)
+           == jax.lax.broadcasted_iota(jnp.int32, (heads, d), 0))
+    alpha_cols = jax.lax.dot_general(alpha, own.astype(jnp.float32), tn,
+                                     precision=hi)  # (EB, D)
+    srcl = srcl_ref[0, :]
+    sel = srcl[:, None] == jax.lax.broadcasted_iota(jnp.int32, (eb, band), 1)
+    gathered = jnp.dot(sel.astype(jnp.float32), h_ref[...].astype(jnp.float32),
+                       precision=hi)
+    contrib = jnp.dot(scat, gathered * alpha_cols, precision=hi)
+    out_ref[...] += contrib.astype(out_ref.dtype)
+
+
 def _dense_kernel(
     band_ref, dtile_ref, first_ref,  # scalar-prefetch (SMEM)
     a_ref, h_ref,  # VMEM inputs: (TD, BAND) weight tile, (BAND, D) band
@@ -885,25 +930,31 @@ def attention_seg_sum_call(
     num_dst_tiles, src_band, dst_tile_rows, interpret,
 ):
     """The attention path's aggregation kernel: ``out[d] += alpha_e
-    h[src_e]`` over edge blocks, alpha formed per slot from the blocked
-    ``logits`` and the ``(rows, 1)`` stats columns (m, s) of
-    ``edge_softmax.attention_stats_call``.  Rows of tiles no block
-    touches hold uninitialized memory."""
+    h[src_e]`` over edge blocks, alpha formed per slot from the ``(nb, H,
+    EB)`` blocked ``logits`` and the ``(rows, H)`` stats columns (m, s) of
+    ``edge_softmax.attention_stats_call``; with H heads, head k's alpha
+    weighs columns ``k*D/H`` to ``(k+1)*D/H``.  Rows of tiles no block
+    touches hold uninitialized memory.  One head runs ``_attn_na_kernel``,
+    more ``_attn_na_heads_kernel``."""
     nb, eb = src_local.shape[0], src_local.shape[-1]
     d = h.shape[1]
     td = dst_tile_rows
+    heads = m.shape[1]
     row = pl.BlockSpec((None, 1, eb), lambda i, b, t, f: (i, 0, 0))
-    col = pl.BlockSpec((td, 1), lambda i, b, t, f: (t[i], 0))
+    col = pl.BlockSpec((td, heads), lambda i, b, t, f: (t[i], 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(nb,),
         in_specs=[
-            row, row, row, row, col, col,
+            row, row, row,
+            pl.BlockSpec((None, heads, eb), lambda i, b, t, f: (i, 0, 0)),
+            col, col,
             pl.BlockSpec((src_band, d), lambda i, b, t, f: (b[i], 0)),
         ],
         out_specs=pl.BlockSpec((td, d), lambda i, b, t, f: (t[i], 0)),
     )
-    kern = functools.partial(_attn_na_kernel, eb=eb, band=src_band, td=td)
+    body = _attn_na_kernel if heads == 1 else _attn_na_heads_kernel
+    kern = functools.partial(body, eb=eb, band=src_band, td=td)
     return pl.pallas_call(
         kern,
         grid_spec=grid_spec,

@@ -275,6 +275,34 @@ def test_compiled_loss_fit_evaluate(sessions):
     np.testing.assert_allclose(full, ones, rtol=1e-6)
 
 
+def test_banded_han_fit_trains(sessions):
+    """``fit`` through the attention kernels' per-head VJP: HAN with eight
+    heads on the banded executor lowers its loss."""
+    from repro.train import propagated_feature_labels, semi_supervised_masks
+
+    graph = sessions["graphs"]["acm_small"]
+    targets, target_type = WORKLOADS["acm_small"]
+    c = sessions["banded"].compile(graph, targets, _cfg(
+        "han", target_type, num_heads=8, num_layers=1))
+    feats = device_features(graph)
+    labels = propagated_feature_labels(c.semantic, targets, graph.features,
+                                       c.num_target)
+    out = c.fit(feats, labels, semi_supervised_masks(c.num_target, seed=0),
+                epochs=4)
+    assert out["losses"][-1] < out["losses"][0]
+
+
+@pytest.mark.parametrize("kw, match", [
+    (dict(model="gat"), "unknown model 'gat'"),
+    (dict(model="han", hidden=64, num_heads=3), "must divide hidden 64"),
+    (dict(model="han", num_heads=0), "must divide"),
+    (dict(model="rgcn", num_heads=2), "no attention to split"),
+])
+def test_config_rejects_bad_models_and_heads(kw, match):
+    with pytest.raises(ValueError, match=match):
+        HGNNConfig(**kw)
+
+
 # --------------------------------------------------------- serve engine --
 @pytest.fixture()
 def engine(sessions):

@@ -8,14 +8,16 @@ builds the logits per edge in stream order, scatters them into the blocked
 layout (``PackedEdges.scatter_blocks``), runs the flat-logit stats kernel
 (``edge_softmax_stats``), gathers ``m[dst]``/``s[dst]`` per edge and
 scatters alpha back into the blocks for ``seg_sum_na(weights=...)``.  Both
-must give the same blocked logits, (m, s), alpha and outputs.
+must give the same blocked logits, (m, s), alpha and outputs.  With H
+heads (``(rows, H)`` score columns, HAN's eight) each head's logits, (m,
+s) and columns are checked against the jnp oracle over the edge list.
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.hgnn.layers import na_attention, na_attention_banded
+from repro.core.hgnn.layers import head_scores, na_attention, na_attention_banded
 from repro.kernels import ops, ref
 from repro.kernels.edge_softmax import attention_stats_call, edge_softmax_stats
 from repro.kernels.seg_sum import (attention_seg_sum_call, dst_rows,
@@ -162,4 +164,94 @@ def test_banded_layer_matches_jnp_layer(bias):
                             a_src, a_dst, edge_bias=edge_bias)
     pre = np.asarray(h_src @ a_src)[src] + np.asarray(h_dst @ a_dst)[dst]
     assert (pre < 0).any() and (pre > 0).any()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4)
+
+
+@pytest.mark.parametrize("heads", [1, 8])
+@pytest.mark.parametrize("case", ["random", "untouched"])
+def test_head_kernels_match_the_jnp_path(case, heads):
+    """``(rows, H)`` score columns through both kernels: per head the
+    blocked logits, (m, s) and the aggregation against the jnp oracle over
+    the edge list (``na_attention_aggregate(backend="jnp")``), head k's
+    alpha on columns ``2k`` to ``2k + 2``.  Tiles no block touches and
+    destinations with no in-edge come out zero."""
+    src, dst, ns, nd, _ = _stream(case, 5)
+    packed = pack_edge_blocks(src, dst, ns, nd)
+    rng = np.random.default_rng(11)
+    e_s = jnp.asarray(rng.standard_normal((ns, heads)) * 2, jnp.float32)
+    e_d = jnp.asarray(rng.standard_normal((nd, heads)) * 2, jnp.float32)
+    h = jnp.asarray(rng.standard_normal((ns, 16)), jnp.float32)
+    no_edge = np.bincount(dst, minlength=nd) == 0
+    assert no_edge.any()
+    src_g, dst_g = packed.flat_global_edges()
+    pre = np.asarray(e_s)[src_g] + np.asarray(e_d)[dst_g]  # (E, H)
+    assert (pre < 0).any() and (pre > 0).any()
+
+    geometry = dict(num_dst_tiles=packed.num_dst_tiles, src_band=packed.src_band,
+                    dst_tile_rows=packed.dst_tile_rows, interpret=True)
+    blocks = (*packed.device_blocked(), packed.device_valid())
+    n_dst_pad = packed.num_dst_tiles * packed.dst_tile_rows
+    cols = (src_rows(packed, e_s), jnp.pad(e_d, ((0, n_dst_pad - nd), (0, 0))))
+    m, s, logits = attention_stats_call(*blocks, *cols, slope=SLOPE, **geometry)
+    assert m.shape == s.shape == (n_dst_pad, heads)
+    assert logits.shape == (packed.num_blocks, heads, packed.edge_block)
+    blk, slot = packed.edge_map()
+    want = np.where(pre >= 0, pre, SLOPE * pre)
+    np.testing.assert_allclose(np.asarray(logits)[blk, :, slot], want, rtol=1e-6, atol=1e-6)
+    pad = np.ones(np.asarray(logits).shape, bool)
+    pad[blk, :, slot] = False
+    assert (np.asarray(logits)[pad] == -1e30).all()
+    m_want = np.asarray(jax.ops.segment_max(jnp.asarray(want), jnp.asarray(dst_g),
+                                            num_segments=nd))
+    s_want = np.asarray(jax.ops.segment_sum(jnp.exp(jnp.asarray(want) - m_want[dst_g]),
+                                            jnp.asarray(dst_g), num_segments=nd))
+    has_edge = ~no_edge
+    np.testing.assert_allclose(np.asarray(m)[:nd][has_edge], m_want[has_edge],
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(s)[:nd][has_edge], s_want[has_edge], rtol=1e-5)
+
+    oracle = ops.na_attention_aggregate(src, dst, e_s, e_d, h, nd, SLOPE, backend="jnp")
+    out = attention_seg_sum_call(*blocks, logits, m, s, src_rows(packed, h), **geometry)
+    np.testing.assert_allclose(np.asarray(dst_rows(packed, out)), np.asarray(oracle),
+                               atol=1e-5)
+    entry = ops.na_attention_packed(packed, e_s, e_d, h, SLOPE, backend="interpret")
+    np.testing.assert_allclose(np.asarray(entry), np.asarray(oracle), atol=1e-5)
+    assert not np.asarray(entry)[no_edge].any()
+    if case == "untouched":
+        assert not np.asarray(entry)[128:384].any()
+
+
+def test_head_outputs_are_the_heads_concatenated():
+    """Eight heads at once == each head alone on its own columns."""
+    src, dst, ns, nd, _ = _stream("revisits", 6)
+    packed = pack_edge_blocks(src, dst, ns, nd)
+    rng = np.random.default_rng(12)
+    e_s = jnp.asarray(rng.standard_normal((ns, 8)), jnp.float32)
+    e_d = jnp.asarray(rng.standard_normal((nd, 8)), jnp.float32)
+    h = jnp.asarray(rng.standard_normal((ns, 16)), jnp.float32)
+    both = ops.na_attention_packed(packed, e_s, e_d, h, backend="interpret")
+    each = [ops.na_attention_packed(packed, e_s[:, k], e_d[:, k], h[:, 2 * k:2 * k + 2],
+                                    backend="interpret") for k in range(8)]
+    np.testing.assert_allclose(np.asarray(both), np.asarray(jnp.concatenate(each, axis=1)),
+                               atol=1e-6)
+
+
+def test_banded_han_layer_matches_jnp_layer():
+    """``na_attention_banded`` with ``(H, D/H)`` attention vectors ==
+    ``na_attention``: per-head scores from ``head_scores`` on both."""
+    src, dst, ns, nd, _ = _stream("revisits", 4)
+    packed = pack_edge_blocks(src, dst, ns, nd)
+    rng = np.random.default_rng(4)
+    h_src = jnp.asarray(rng.standard_normal((ns, 16)), jnp.float32)
+    h_dst = jnp.asarray(rng.standard_normal((nd, 16)), jnp.float32)
+    a_src = jnp.asarray(rng.standard_normal((8, 2)), jnp.float32)
+    a_dst = jnp.asarray(rng.standard_normal((8, 2)), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        got = na_attention_banded(packed, h_src, h_dst, a_src, a_dst, backend="interpret")
+        want = na_attention(h_src, h_dst, jnp.asarray(src), jnp.asarray(dst), nd,
+                            a_src, a_dst)
+        scores = head_scores(h_src, a_src)
+    np.testing.assert_allclose(np.asarray(scores), np.einsum(
+        "nkd,kd->nk", np.asarray(h_src).reshape(ns, 8, 2), np.asarray(a_src)), rtol=1e-5,
+        atol=1e-6)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4)

@@ -226,6 +226,86 @@ def test_na_attention_packed_grads_match_ref():
         np.testing.assert_allclose(np.asarray(b), np.asarray(want), atol=1e-5)
 
 
+def test_na_attention_packed_head_grads_match_ref():
+    """The per-head VJP: eight heads of two columns, gradients of the
+    ``(N, 8)`` score columns and the features against differentiating the
+    jnp oracle, each head's softmax and columns its own; both leaky ReLU
+    branches are taken."""
+    ns, nd, ne = 250, 120, 900
+    src, dst = _random_stream(ns, nd, ne, 10)
+    packed = pack_edge_blocks(src, dst, ns, nd)
+    h = jnp.asarray(RNG.standard_normal((ns, 16)), jnp.float32)
+    r = jnp.asarray(RNG.standard_normal((nd, 16)), jnp.float32)
+    e_s = jnp.asarray(RNG.standard_normal((ns, 8)), jnp.float32)
+    e_d = jnp.asarray(RNG.standard_normal((nd, 8)), jnp.float32)
+    pre = np.asarray(e_s)[src] + np.asarray(e_d)[dst]
+    assert (pre < 0).any() and (pre > 0).any()
+
+    def f_banded(a, b, x):
+        return jnp.sum(ops.na_attention_packed(packed, a, b, x,
+                                               backend="interpret") * r)
+
+    def f_ref(a, b, x):
+        return jnp.sum(ops.na_attention_aggregate(src, dst, a, b, x, nd,
+                                                  backend="jnp") * r)
+
+    g_b = jax.grad(f_banded, argnums=(0, 1, 2))(e_s, e_d, h)
+    g_r = jax.grad(f_ref, argnums=(0, 1, 2))(e_s, e_d, h)
+    for b, want in zip(g_b, g_r):
+        assert b.shape == want.shape
+        np.testing.assert_allclose(np.asarray(b), np.asarray(want), atol=1e-5)
+        assert float(jnp.abs(want).max()) > 0
+
+
+@pytest.fixture(scope="module")
+def dblp_han(dblp_small):
+    """HAN (eight heads of two columns, one layer) on DBLP at test scale,
+    compiled on both executors, with seeded random weights."""
+    from repro.api import ExecutorSpec, Session
+
+    cfg = HGNNConfig(model="han", hidden=16, num_layers=1, num_classes=4,
+                     target_type="A", num_heads=8, sf_att_dim=32)
+    mps = ["APA", "APTPA", "APVPA"]
+    banded = Session(ExecutorSpec(na_executor="banded")).compile(dblp_small, mps, cfg)
+    ref_ = Session(ExecutorSpec(na_executor="jnp")).compile(dblp_small, mps, cfg)
+    feats = {t: jnp.asarray(x) for t, x in dblp_small.features.items()}
+    return banded, ref_, banded.init(5), feats
+
+
+def test_han_forward_matches_jnp(dblp_han):
+    """The banded HAN forward == the jnp HAN (``na_attention`` over edge
+    lists) at ``highest``: both sum the same f32 terms in other orders, so
+    the logits agree to 1e-5 of their scale."""
+    banded, ref_, params, feats = dblp_han
+    with jax.default_matmul_precision("highest"):
+        got = np.asarray(banded.forward(params, feats))
+        want = np.asarray(ref_.forward(params, feats))
+    assert np.abs(want).max() > 0
+    np.testing.assert_allclose(got, want, atol=1e-5 * np.abs(want).max())
+
+
+def test_han_loss_grads_match_jnp(dblp_han):
+    """jax.grad of the HAN loss through the banded kernels' per-head VJP ==
+    the jnp executor's, every parameter (the (8, 2) attention vectors
+    included) to 1e-4, the tolerance of the single-head models above."""
+    banded, ref_, params, feats = dblp_han
+    n = banded.num_target
+    labels = jnp.asarray(RNG.integers(0, 4, n).astype(np.int32))
+    with jax.default_matmul_precision("highest"):
+        g_b = jax.grad(lambda p: banded.model.execute_loss(
+            p, feats, banded.graphs, labels))(params)
+        g_j = jax.grad(lambda p: ref_.model.execute_loss(
+            p, feats, ref_.graphs, labels))(params)
+    for mp in ("APA", "APTPA", "APVPA"):
+        assert g_b["layers"][0]["na"][mp]["a_src"].shape == (8, 2)
+        assert float(jnp.abs(g_b["layers"][0]["na"][mp]["a_src"]).max()) > 0, mp
+    flat_j, tree_j = jax.tree.flatten(g_j)
+    flat_b, tree_b = jax.tree.flatten(g_b)
+    assert tree_j == tree_b
+    for a, b in zip(flat_j, flat_b):
+        np.testing.assert_allclose(np.asarray(b), np.asarray(a), atol=1e-4)
+
+
 # -------------------------------------------------- train-step plumbing --
 def test_train_step_banded_reuses_packing(frontends):
     """A jitted banded train step runs multiple steps on one cached

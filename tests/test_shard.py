@@ -24,7 +24,7 @@ WORKLOADS = {
     "acm_small": (["APA", "PAP", "PSP"], "P"),
     "imdb_small": (["AMA", "MAM", "MDM"], "M"),
 }
-MODELS = ("rgcn", "rgat", "shgn")
+MODELS = ("rgcn", "rgat", "shgn", "han")  # han with one head: the sharded form
 
 
 def _cfg(model, target_type, **kw):
@@ -219,6 +219,19 @@ def test_unsharded_compile_rejects_devices(sessions):
     with pytest.raises(ValueError, match="requires a sharded spec"):
         sessions["banded"].compile(sessions["graphs"]["acm_small"],
                                    targets, _cfg("rgcn", tt), devices=[0])
+
+
+@pytest.mark.parametrize("mode", ["relation", "edge_block"])
+def test_sharded_compile_refuses_heads(sessions, mode):
+    """The sharded executor's merged NA runs one attention head: a model
+    with more is refused at compile, before any frontend work, with a
+    message that says so; the unsharded banded executor takes it."""
+    targets, tt = WORKLOADS["acm_small"]
+    graph = sessions["graphs"]["acm_small"]
+    cfg = _cfg("han", tt, num_heads=8)
+    with pytest.raises(ValueError, match="runs one attention head.*num_heads=8"):
+        sessions[mode].compile(graph, targets, cfg)
+    assert sessions["banded"].compile(graph, targets, cfg).shard_plan is None
 
 
 def test_old_lm_exports_raise_with_pointer():

@@ -106,6 +106,26 @@ def test_dependency_forward_matches_full_rows(sessions, executor, ds, model):
         np.testing.assert_allclose(dep, full[ids], atol=1e-4)
 
 
+@pytest.mark.parametrize("executor", ["jnp", "banded"])
+def test_dependency_forward_takes_heads(sessions, executor):
+    """HAN with eight heads: the dependency executor's per-head softmax and
+    one kernel call per head give the full forward's rows, as does the
+    head-gathering subset forward."""
+    graph = sessions["graphs"]["acm_small"]
+    targets, target_type = WORKLOADS["acm_small"]
+    c = sessions[executor].compile(graph, targets, _cfg(
+        "han", target_type, num_heads=8, num_layers=1))
+    params = c.init(0)
+    assert params["layers"][0]["na"]["PAP"]["a_src"].shape == (8, 2)
+    feats = device_features(graph)
+    full = np.asarray(c.forward(params, feats))
+    ids = np.unique(np.random.default_rng(8).integers(0, c.num_target, size=13))
+    dep = np.asarray(c.forward_subset(params, feats, ids, mode="dependency"))
+    np.testing.assert_allclose(dep, full[ids], atol=1e-4)
+    head = np.asarray(c.forward_subset(params, feats, ids))
+    np.testing.assert_array_equal(head, full[ids])
+
+
 def test_dependency_forward_restores_caller_order(sessions):
     """Unsorted / duplicated ids come back in the caller's order."""
     graph, c = _compiled(sessions, "jnp", "acm_small", "rgcn")

@@ -3,7 +3,8 @@
 Interpret mode accepts block shapes and layouts that the TPU's compiler
 (Mosaic) refuses, so these tests lower the kernels the main path runs —
 the banded seg-sum, the edge-softmax stats, the attention path's stats
-and aggregation kernels and the SGB ``spgemm_bsr`` —
+and aggregation kernels (with one head and with HAN's eight) and the SGB
+``spgemm_bsr`` —
 for a ``v5e:2x2`` topology that is described, not attached, at the
 paper's full-scale shapes, and assert that each compiled program holds a
 ``tpu_custom_call``.  A small scoped Simple-HGN forward is compiled the
@@ -144,6 +145,42 @@ def test_attention_seg_sum_kernel_compiles_for_v5e(one_chip, graph):
     _assert_kernel(
         lambda *a: attention_seg_sum_call(*a, tiles, SRC_BAND, DST_TILE, False),
         *_attention_operands(one_chip, graph), logits, *stats, h)
+
+
+# DBLP APVPA in the dblp-han.forward cell: (edge blocks, src bands, dst
+# tiles), and HAN's eight heads
+HAN_APVPA = (23704, 8, 32)
+HEADS = 8
+
+
+def test_head_stats_kernel_compiles_for_v5e(one_chip):
+    """The stats kernel for eight heads: (rows, 8) score columns and (m, s),
+    (nb, 8, EB) blocked logits, the one-hot selections as ``HIGHEST``
+    products with a transposed operand."""
+    nb, bands, tiles = HAN_APVPA
+    blocks = [_shape(one_chip, (nb,), jnp.int32)] * 3
+    rows = [_shape(one_chip, (nb, 1, EDGE_BLOCK), jnp.int32)] * 2
+    valid = _shape(one_chip, (nb, 1, EDGE_BLOCK), jnp.float32)
+    e_s = _shape(one_chip, (bands * SRC_BAND, HEADS), jnp.float32)
+    e_d = _shape(one_chip, (tiles * DST_TILE, HEADS), jnp.float32)
+    _assert_kernel(
+        lambda *a: attention_stats_call(*a, tiles, SRC_BAND, DST_TILE, 0.2, False),
+        *blocks, *rows, valid, e_s, e_d)
+
+
+def test_head_seg_sum_kernel_compiles_for_v5e(one_chip):
+    """The aggregation kernel for eight heads, each weighing its own 8 of
+    the 64 feature columns."""
+    nb, bands, tiles = HAN_APVPA
+    blocks = [_shape(one_chip, (nb,), jnp.int32)] * 3
+    rows = [_shape(one_chip, (nb, 1, EDGE_BLOCK), jnp.int32)] * 2
+    valid = _shape(one_chip, (nb, 1, EDGE_BLOCK), jnp.float32)
+    logits = _shape(one_chip, (nb, HEADS, EDGE_BLOCK), jnp.float32)
+    stats = [_shape(one_chip, (tiles * DST_TILE, HEADS), jnp.float32)] * 2
+    h = _shape(one_chip, (bands * SRC_BAND, HIDDEN), jnp.float32)
+    _assert_kernel(
+        lambda *a: attention_seg_sum_call(*a, tiles, SRC_BAND, DST_TILE, False),
+        *blocks, *rows, valid, logits, *stats, h)
 
 
 def test_spgemm_bsr_compiles_for_v5e(one_chip):
